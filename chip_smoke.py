@@ -1,0 +1,457 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+Run from the repo root on a machine with a TPU, one process per chip::
+
+    python chip_smoke.py
+
+It drives the main path once at full width, in this one process:
+
+1. Deformable R-FCN, ResNet-101, 608x1024, 80 classes, batch 8, bf16 — the
+   step ``bench.py`` measures (``examples/deformable_rfcn/train_fused.py``:
+   ``build_net`` -> ``make_rfcn_train_step`` -> ``jax.jit(step,
+   donate_argnums=(0,))``): compiled once, >= 3 chained steps on donated
+   state, loss finite and different on every step, and the COMPILED module
+   holds the Mosaic custom calls for dconv forward, dconv backward and NMS.
+2. Every Pallas kernel that is default-on for TPU, non-interpreted, against
+   the repo's XLA / jnp formulation of the same operator.
+3. ``Module.fit`` on the symbolic ResNet-50 (224x224, 1000 classes): Symbol
+   -> Executor -> ``FusedStepper`` -> optimizer, with the recipe's
+   ``context=mx.current_context()``; the fused step must engage and the
+   parameters must live on the TPU afterwards.
+4. With >= 4 chips visible: ``dryrun_multichip(4)`` and leg 1 data-parallel
+   over a dp=4 mesh at global batch 32.  Never on virtual devices.
+
+Weights are random from a seed; nothing is read outside the tracked tree.
+Any failed check raises: there is no skipped phase that still exits 0.  With
+no TPU it exits non-zero and prints no result line.  The last line of stdout
+is ``{"ok": true, "device": {"platform", "kind", "count"}}``.
+
+The legs are importable functions with their sizes as arguments so
+``tests/test_chip_smoke.py`` can drive them at toy size on the CPU with
+interpret-mode kernels; ``__main__`` is always full width and always
+requires the chip.
+"""
+from __future__ import annotations
+
+import gc
+import json
+import os
+import re
+import sys
+import time
+
+import numpy as np
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, REPO)
+
+# longest first: "quantize_int8_pallas" is a substring of the dequantize name
+KERNELS = ("dequantize_int8_pallas", "quantize_int8_pallas",
+           "dconv_col_pallas_fwd", "dconv_col_pallas_bwd", "nms_alive_pallas")
+STEP_KERNELS = ("dconv_col_pallas_fwd", "dconv_col_pallas_bwd",
+                "nms_alive_pallas")
+
+
+def say(msg):
+    print("[chip_smoke] " + msg, flush=True)
+
+
+def require_tpu():
+    """-> {"platform", "kind", "count"} as JAX reports it, or exit non-zero
+    naming what was found.  Prints the installation."""
+    import jax
+    import jaxlib
+
+    try:
+        import libtpu
+        libtpu_version = getattr(libtpu, "__version__", "unknown")
+    except ImportError:
+        libtpu_version = "not installed"
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    say("jax %s jaxlib %s libtpu %s python %s"
+        % (jax.__version__, jaxlib.__version__, libtpu_version,
+           sys.version.split()[0]))
+    say("device %s" % json.dumps(device))
+    if device["platform"] != "tpu":
+        raise SystemExit(
+            "chip_smoke.py: needs a TPU, found platform=%r device_kind=%r "
+            "count=%d (JAX_PLATFORMS=%r)"
+            % (device["platform"], device["kind"], device["count"],
+               os.environ.get("JAX_PLATFORMS")))
+    return device
+
+
+def native_lib_status():
+    """Build-or-degrade of the native data plane is silent; say which."""
+    from mxnet_tpu import _native
+
+    prebuilt = os.path.exists(_native._SO_PATH)
+    if _native.lib() is None:
+        return "degraded to pure Python"
+    return "prebuilt .so loaded" if prebuilt else "built from src/ and loaded"
+
+
+def mosaic_calls(hlo_text):
+    """One ``{"kernel", "count", "result"}`` per distinct Mosaic custom call
+    in a COMPILED module's text.  The kernel is recognised by the ``name=``
+    its pallas_call carries into the instruction's ``op_name`` metadata; the
+    result type is the per-device shape after SPMD partitioning (layouts
+    dropped; compiled text prints no operand types)."""
+    found = {}
+    for line in hlo_text.splitlines():
+        call = re.search(r"= (.*?) custom-call\(.*"
+                         r'custom_call_target="tpu_custom_call"', line)
+        if not call:
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        op_name = op_name.group(1) if op_name else ""
+        kernel = next((k for k in KERNELS if k in op_name),
+                      "unnamed:" + op_name[-80:])
+        key = (kernel, re.sub(r"\{[^}]*\}", "", call.group(1)))
+        found[key] = found.get(key, 0) + 1
+    return [{"kernel": k, "count": n, "result": r}
+            for (k, r), n in found.items()]
+
+
+def _load_rfcn_recipe():
+    from mxnet_tpu.test_utils import load_module_by_path
+
+    return load_module_by_path(
+        os.path.join(REPO, "examples", "deformable_rfcn", "train_fused.py"),
+        "_chip_smoke_rfcn_train_fused")
+
+
+def rfcn_leg(resnet101=True, batch=8, image_shape=None, steps=3,
+             dtype="bfloat16", dp=1):
+    """The north-star train step; ``batch`` is per device, ``dp`` > 1 runs it
+    data-parallel over a ``{"dp": dp}`` mesh of the first ``dp`` devices
+    (params replicated, batch sharded).  -> facts dict."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import parallel
+
+    tf = _load_rfcn_recipe()
+    mx.random.seed(0)
+    rng = np.random.RandomState(0)
+    net, shape, classes = tf.build_net(resnet101, image_shape)
+    gbatch = batch * dp
+    data, im_info, gt = tf.synthetic_coco(rng, gbatch, shape, classes,
+                                          net.max_gts)
+    step, state = tf.make_rfcn_train_step(
+        net, gbatch, learning_rate=5e-4, momentum=0.9, compute_dtype=dtype)
+    devs = jax.devices()[:dp]
+    mesh = parallel.make_mesh({"dp": dp}, devices=devs)
+    if dp > 1:
+        repl = NamedSharding(mesh, P())
+        state = jax.tree_util.tree_map(
+            lambda v: jax.device_put(v, repl), state)
+        sharded = NamedSharding(mesh, P("dp"))
+        batch_arrays = [jax.device_put(a, sharded)
+                        for a in (data, im_info, gt)]
+    else:
+        batch_arrays = [jax.device_put(a) for a in (data, im_info, gt)]
+    key = jax.random.PRNGKey(0)
+
+    jstep = jax.jit(step, donate_argnums=(0,))
+    t0 = time.perf_counter()
+    # traced under set_mesh so the Pallas calls can see the dp axis and run
+    # per shard: GSPMD cannot partition a Mosaic kernel
+    with jax.set_mesh(mesh):
+        lowered = jstep.lower(state, *batch_arrays, key)
+    t1 = time.perf_counter()
+    compiled = lowered.compile()
+    t2 = time.perf_counter()
+    hlo = compiled.as_text()
+    calls = mosaic_calls(hlo)
+
+    losses = []
+    for s in range(steps):
+        state, loss, _parts = compiled(state, *batch_arrays,
+                                       jax.random.fold_in(key, s))
+        losses.append(loss)
+    jax.block_until_ready(state)
+    t3 = time.perf_counter()
+    losses = [float(l) for l in losses]
+    if not all(np.isfinite(losses)):
+        raise AssertionError("non-finite loss: %r" % (losses,))
+    if len(set(losses)) != len(losses):
+        raise AssertionError("loss did not change between steps: %r"
+                             % (losses,))
+
+    state_devices = sorted({d.id for leaf in jax.tree_util.tree_leaves(state)
+                            for d in leaf.devices()})
+    batch_devices = [sh.device.id
+                     for sh in batch_arrays[0].addressable_shards]
+    if state_devices != [d.id for d in devs] \
+            or sorted(batch_devices) != [d.id for d in devs]:
+        raise AssertionError(
+            "placement: state on devices %r, batch shards on %r, wanted %r"
+            % (state_devices, batch_devices, [d.id for d in devs]))
+    facts = {
+        "model": "rfcn_%s" % ("r101" if resnet101 else "toy"),
+        "image_shape": list(shape), "batch_per_device": batch, "dp": dp,
+        "dtype": str(dtype), "params_m": round(
+            sum(int(np.prod(v.shape)) for v in state[0]) / 1e6, 1),
+        "trace_lower_s": round(t1 - t0, 1), "compile_s": round(t2 - t1, 1),
+        "steps": steps, "steps_wall_s": round(t3 - t2, 2), "losses": losses,
+        "mosaic_calls": calls,
+        "collectives": {op: len(re.findall(
+            r"= [^=\n]* %s(?:-start)?\(" % op, hlo))
+            for op in ("all-reduce", "all-gather", "all-to-all",
+                       "collective-permute")},
+        # memory_stats()'s peak did not move with the step's activations on
+        # the v5e runtime (PR 21 run), so the executable's own figure too
+        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+        "bytes_in_use": [(d.memory_stats() or {}).get("bytes_in_use")
+                         for d in devs],
+    }
+    del state, compiled, lowered, batch_arrays, net
+    gc.collect()
+    return facts
+
+
+def check_step_kernels(facts):
+    """The compiled step must hold every STEP_KERNELS Mosaic call — neither
+    ``dconv_fits_vmem`` nor the NMS ``N >= 1024`` gate may have quietly
+    taken the XLA formulation at these shapes."""
+    names = [c["kernel"] for c in facts["mosaic_calls"]]
+    missing = [k for k in STEP_KERNELS if k not in names]
+    if missing:
+        raise AssertionError("compiled step lacks Mosaic calls %r; found %r"
+                             % (missing, facts["mosaic_calls"]))
+
+
+def check_dp_facts(facts, single):
+    """Four-chip checks that need no judgement: a gradient all-reduce
+    exists, every chip holds about the same bytes (not everything on chip
+    0), and each chip runs the Pallas kernels at the single-chip shapes
+    (``single``: the dp=1 facts at the same per-device batch) — its own
+    shard, not the gathered global batch."""
+    if facts["collectives"]["all-reduce"] < 1:
+        raise AssertionError("dp step compiled without an all-reduce")
+    used = facts["bytes_in_use"]
+    if None in used or max(used) > 1.5 * min(used):
+        raise AssertionError("uneven per-device memory: %r" % (used,))
+    if facts["mosaic_calls"] != single["mosaic_calls"]:
+        raise AssertionError(
+            "per-device Pallas calls differ from the single-chip step: %r "
+            "vs %r" % (facts["mosaic_calls"], single["mosaic_calls"]))
+
+
+def quant_leg(shape=(256, 1024), interpret=False):
+    """``quantize_int8_pallas`` / ``dequantize_int8_pallas`` at one
+    tile-aligned shape against the jnp formula of ops/quantization.py."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    x = jnp.asarray(np.random.RandomState(0).randn(*shape).astype(np.float32))
+    if not pk.supported(x.shape, x.dtype):
+        raise AssertionError("shape %r is not tile-aligned" % (shape,))
+    r = jnp.max(jnp.abs(x))
+    q = pk.quantize_int8_pallas(x, r, interpret=interpret)
+    q_ref = (jnp.sign(x) * jnp.minimum(jnp.abs(x) * (127.0 / r) + 0.5, 127.0)
+             ).astype(jnp.int8)
+    dq = pk.dequantize_int8_pallas(q, r, interpret=interpret)
+    dq_ref = q.astype(jnp.float32) * (r / 127.0)
+    facts = {"quantize_mismatches": int(jnp.sum(q != q_ref)),
+             "dequantize_max_err": float(jnp.max(jnp.abs(dq - dq_ref)))}
+    if facts["quantize_mismatches"] or facts["dequantize_max_err"] > 1e-6:
+        raise AssertionError("int8 kernels disagree with jnp: %r" % facts)
+    return facts
+
+
+def nms_leg(boxes=6000, batch=2, interpret=False):
+    """``nms_alive_pallas`` (vmapped onto its batched grid, as MultiProposal
+    calls it) against ``_nms_alive_blocked_xla``: identical survivors."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import detection, pallas_kernels as pk
+
+    rng = np.random.RandomState(0)
+    ctr = rng.rand(batch, boxes, 2) * np.array([1024.0, 608.0])
+    wh = rng.rand(batch, boxes, 2) * 200.0 + 8.0
+    b = jnp.asarray(np.concatenate([ctr - wh / 2, ctr + wh / 2], -1)
+                    .astype(np.float32))
+    valid = jnp.ones(b.shape[:2], bool)
+    alive = jax.jit(jax.vmap(lambda b, v: pk.nms_alive_pallas(
+        b, v, None, thresh=0.7, interpret=interpret)))(b, valid)
+    alive_ref = jax.jit(jax.vmap(lambda b, v: detection._nms_alive_blocked_xla(
+        b, 0.7, 256, 1.0, v, None, True)))(b, valid)
+    facts = {"nms_survivors": [int(n) for n in np.asarray(alive).sum(1)],
+             "nms_mismatches": int(jnp.sum(alive != alive_ref))}
+    if facts["nms_mismatches"]:
+        raise AssertionError("NMS kernel disagrees with XLA: %r" % facts)
+    return facts
+
+
+def dconv_leg(bg=1, channels=128, hw=(38, 64), interpret=False):
+    """``dconv_col_pallas`` forward and all four gradients, bf16 features,
+    against the dense one-hot matmul it replaces (9 taps per position; the
+    defaults are one (image, group) of north-star res5)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.RandomState(0)
+    H, W = hw
+    N = 9 * H * W
+    sy = jnp.asarray(rng.uniform(0, H - 1, (bg, N)).astype(np.float32))
+    sx = jnp.asarray(rng.uniform(0, W - 1, (bg, N)).astype(np.float32))
+    y0 = jnp.floor(sy).astype(jnp.int32)
+    x0 = jnp.floor(sx).astype(jnp.int32)
+    y1 = jnp.minimum(y0 + 1, H - 1)
+    x1 = jnp.minimum(x0 + 1, W - 1)
+    ly, lx = sy - y0, sx - x0
+    lf = jnp.asarray((rng.rand(bg, N) > 0.1).astype(np.float32))
+    ft = jnp.asarray(rng.randn(bg, H * W, channels).astype(np.float32)
+                     ).astype(jnp.bfloat16)
+    cot = jnp.cos(jnp.arange(bg * N * channels, dtype=jnp.float32)
+                  ).reshape(bg, N, channels)
+
+    def dense(ly, lx, lf, ft):
+        hh = jnp.arange(H * W, dtype=jnp.int32) // W
+        ww = jnp.arange(H * W, dtype=jnp.int32) % W
+        a = (((1 - ly)[..., None] * (hh == y0[..., None])
+              + ly[..., None] * (hh == y1[..., None]))
+             * ((1 - lx)[..., None] * (ww == x0[..., None])
+                + lx[..., None] * (ww == x1[..., None]))
+             * lf[..., None])
+        return jnp.einsum("bnp,bpc->bnc", a.astype(ft.dtype), ft,
+                          preferred_element_type=jnp.float32).astype(ft.dtype)
+
+    def fused(ly, lx, lf, ft):
+        return pk.dconv_col_pallas(y0, y1, x0, x1, ly, lx, lf, ft, (H, W),
+                                   interpret)
+
+    def run(fn):
+        def loss(*a):
+            return jnp.sum(fn(*a).astype(jnp.float32) * cot)
+        out = jax.jit(fn)(ly, lx, lf, ft)
+        grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(ly, lx, lf, ft)
+        return [np.asarray(v.astype(jnp.float32)) for v in (out,) + grads]
+
+    errs = {}
+    for name, got, want in zip(("col", "d_ly", "d_lx", "d_lf", "d_ft"),
+                               run(fused), run(dense)):
+        scale = max(float(np.abs(want).max()), 1e-6)
+        errs[name] = float("%.3g" % (float(np.abs(got - want).max()) / scale))
+    # bf16 operands, f32 accumulation on both sides, but the dense path's AD
+    # rounds dA to bf16 where the kernel keeps it f32: allow 4 bf16 ulps
+    # (2^-6) of the largest element
+    if max(errs.values()) > 2.0 ** -6:
+        raise AssertionError("dconv kernel disagrees with the dense "
+                             "formulation: %r" % (errs,))
+    return {"dconv_rel_err": errs}
+
+
+def module_fit_leg(num_layers=50, image=224, classes=1000, batch=32,
+                   batches=4):
+    """``Module.fit`` on the symbolic ResNet through the image-classification
+    recipe's pieces (``symbols/resnet.py``, ``SyntheticDataIter``, and
+    ``common/fit.py``'s ``context=mx.current_context()``)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.test_utils import load_module_by_path
+
+    exdir = os.path.join(REPO, "examples", "image-classification")
+    resnet = load_module_by_path(
+        os.path.join(exdir, "symbols", "resnet.py"), "_chip_smoke_resnet_sym")
+    data = load_module_by_path(
+        os.path.join(exdir, "common", "data.py"), "_chip_smoke_ic_data")
+    mx.random.seed(0)
+    sym = resnet.get_symbol(num_classes=classes, num_layers=num_layers,
+                            image_shape="3,%d,%d" % (image, image))
+    train = data.SyntheticDataIter(classes, (batch, 3, image, image), batches,
+                                   "float32")
+    ctx = mx.current_context()
+    mod = mx.mod.Module(symbol=sym, context=ctx)
+    ce = []
+    t0 = time.perf_counter()
+    mod.fit(train, num_epoch=1, optimizer="sgd",
+            optimizer_params={"learning_rate": 0.05, "momentum": 0.9,
+                              "wd": 1e-4},
+            initializer=mx.init.Xavier(rnd_type="gaussian",
+                                       factor_type="in", magnitude=2),
+            eval_metric="ce",
+            batch_end_callback=lambda p: ce.append(
+                float(p.eval_metric.get()[1])))
+    wall = time.perf_counter() - t0
+    if mod._fused is None:
+        raise AssertionError("Module.fit did not engage the fused step")
+    if len(ce) != batches or not all(np.isfinite(ce)):
+        raise AssertionError("cross-entropy per batch: %r" % (ce,))
+    arg_params, aux_params = mod.get_params()
+    platforms = sorted({d.platform
+                        for v in list(mod._exec.arg_dict.values())
+                        + list(mod._exec.aux_dict.values())
+                        for d in v._data.devices()})
+    facts = {"model": "resnet%d_symbolic" % num_layers, "image": image,
+             "batch": batch, "batches": batches,
+             "default_context": str(ctx), "fused": True,
+             "param_platforms": platforms,
+             "params_m": round(sum(v.size for v in arg_params.values())
+                               / 1e6, 1),
+             "fit_wall_s": round(wall, 1), "running_cross_entropy": ce}
+    del mod, train, arg_params, aux_params
+    gc.collect()
+    return facts
+
+
+def cache_facts():
+    import jax
+
+    from mxnet_tpu import compile_cache
+
+    d = jax.config.jax_compilation_cache_dir
+    st = compile_cache.stats()
+    return {"dir": d, "from_env": bool(os.environ.get(
+                "JAX_COMPILATION_CACHE_DIR", "").strip()),
+            "entries": len(os.listdir(d)) if d and os.path.isdir(d) else 0,
+            "hits": st["xla_hits"], "misses": st["xla_misses"]}
+
+
+def main():
+    t_start = time.perf_counter()
+    import mxnet_tpu  # noqa: F401 — places the compile cache at import
+
+    device = require_tpu()
+    say("compile cache at start: %s" % json.dumps(cache_facts()))
+    say("native data plane: %s" % native_lib_status())
+
+    single = rfcn_leg()
+    say("rfcn 1 chip: %s" % json.dumps(single))
+    check_step_kernels(single)
+
+    for leg in (quant_leg, nms_leg, dconv_leg):
+        say("%s vs XLA/jnp: %s" % (leg.__name__, json.dumps(leg())))
+
+    facts = module_fit_leg()
+    say("Module.fit: %s" % json.dumps(facts))
+    if facts["param_platforms"] != ["tpu"]:
+        raise AssertionError("Module.fit parameters live on %r, not the TPU"
+                             % (facts["param_platforms"],))
+
+    if device["count"] >= 4:
+        from __graft_entry__ import dryrun_multichip
+
+        dryrun_multichip(4)
+        facts = rfcn_leg(dp=4)
+        say("rfcn dp=4: %s" % json.dumps(facts))
+        check_step_kernels(facts)
+        check_dp_facts(facts, single)
+    else:
+        say("dp=4 leg: not run, %d chip(s) visible" % device["count"])
+
+    say("compile cache at end: %s" % json.dumps(cache_facts()))
+    say("total %.0f s" % (time.perf_counter() - t_start))
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

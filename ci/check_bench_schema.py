@@ -1,12 +1,12 @@
 #!/usr/bin/env python
 """Schema lint for bench.py's JSON line (ISSUE 1 CI satellite).
 
-BENCH_r*.json (the driver's per-round capture) and the live ``python
+A driver capture of a bench run and the live ``python
 bench.py`` output must stay machine-parseable: one JSON object with exactly
 the known keys, including the optional ``telemetry`` block added by
 MXNET_TELEMETRY.  Run from ci/run_tests.sh unit tier::
 
-    python ci/check_bench_schema.py --self-test BENCH_r*.json
+    python ci/check_bench_schema.py --self-test [capture.json ...]
     python bench.py | python ci/check_bench_schema.py -   # lint a live line
 
 Driver captures are validated through their ``parsed`` field; raw files
@@ -444,7 +444,7 @@ def validate_serve_line(obj, where="<line>"):
 
 
 def validate_capture(path):
-    """Validate a BENCH_r*.json driver capture (or a raw bench line file)."""
+    """Validate a driver capture (or a raw bench line file)."""
     with open(path, encoding="utf-8") as f:
         obj = json.load(f)
     if isinstance(obj, dict) and "parsed" in obj:

@@ -18,19 +18,17 @@ from . import ndarray as nd
 from . import autograd
 from . import random
 
-# seeded lazily to avoid importing jax at package import when unused
 seed = random.seed
 
-# AOT persistent executable cache (compile_cache.py, ISSUE 6): jax's
-# persistent compilation cache latches its directory at the FIRST XLA
-# compile in the process, so MXNET_AOT_CACHE must be applied at import,
-# before anything can compile.  Unset ⇒ no-op, and jax is not imported.
-import os as _os
+# jax's persistent compilation cache latches its directory at the FIRST XLA
+# compile in the process, so where it lives (compile_cache.place_jax_cache:
+# JAX_COMPILATION_CACHE_DIR if set, else <checkout>/.jax_cache on an
+# accelerator, else off) and the MXNET_AOT_CACHE tiers must be applied at
+# import, before anything can compile.
+from . import compile_cache as _compile_cache
 
-if _os.environ.get("MXNET_AOT_CACHE", "").strip():
-    from . import compile_cache as _compile_cache
-
-    _compile_cache.activate()
+_compile_cache.place_jax_cache()
+_compile_cache.activate()
 
 
 def __getattr__(name):

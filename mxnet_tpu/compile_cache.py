@@ -8,8 +8,8 @@ workload already specializes to a FINITE signature set (bucket ladder,
 fused-step shape signatures), so the executables can be built once and
 persisted.
 
-Two tiers, both gated on ``MXNET_AOT_CACHE=<dir>`` (unset ⇒ every helper is
-inert and the jit paths are byte-identical to a build without this module):
+The ``MXNET_AOT_CACHE=<dir>`` tiers (unset ⇒ tier 1 is inert and the jit
+paths are byte-identical to a build without it):
 
 * **tier 1 — explicit executable cache.**  :class:`CachedFunction` wraps an
   already-jitted callable.  Per argument-shape signature it splits the AOT
@@ -23,29 +23,42 @@ inert and the jit paths are byte-identical to a build without this module):
   logical key; any mismatch, truncated file, or deserialize failure is a
   SILENT miss — counted in ``aot_cache_errors_total{reason}`` — and the
   entry is recompiled and overwritten, never a crash.
-* **tier 2 — JAX's persistent compilation cache** pointed at ``<dir>/xla``,
-  so jits *outside* the wired hot spots also skip the XLA backend compile
-  on restart (trace + lower still paid).  Its hit/miss events are forwarded
-  into the same counters under ``tier="xla"``.  Best-effort: a jax build
-  without the knobs simply runs tier 1 alone.
+* **tier 2 — JAX's persistent compilation cache, in whatever directory JAX
+  is already using** (next paragraph), with the min-compile-time /
+  min-entry-size floors dropped so even fast compiles persist: jits
+  *outside* the wired hot spots also skip the XLA backend compile on
+  restart (trace + lower still paid).
 
-**The CPU-backend donation hazard.**  Empirically (jax 0.4.37 / XLA:CPU,
-reproduced under concurrent process load and bisected against controls):
+**Where JAX's persistent cache lives** is decided in ONE place,
+:func:`place_jax_cache`, at ``import mxnet_tpu`` — on every run, not only
+under ``MXNET_AOT_CACHE``.  ``JAX_COMPILATION_CACHE_DIR`` set ⇒ JAX reads it
+itself and this program sets NO directory in code.  Unset, and the
+configured platform is an accelerator ⇒ the fixed ``<checkout>/.jax_cache``
+next to this package (the path is part of what keys an entry, so it never
+comes from ``tempfile``, a pid or the clock).  Unset on CPU ⇒ no persistent
+cache (the donation caution below).  Its hit/miss events are counted under
+``tier="xla"`` (:func:`stats` ``xla_hits`` / ``xla_misses``) on every run.
+
+**The CPU-backend donation caution.**  Observed on an earlier XLA:CPU
+(reproduced under concurrent process load and bisected against controls;
+not re-examined on the installed jax):
 an executable *restored from a cache* — either tier — and dispatched with
-**donated** arguments intermittently computes a consistently-wrong
+**donated** arguments intermittently computed a consistently-wrong
 trajectory (a small discrete set of wrong results, load-dependent trial to
-trial), while freshly compiled executables are bit-exact and stable across
+trial), while freshly compiled executables were bit-exact and stable across
 hundreds of trials under the same load.  Serializing every dispatch with
-``block_until_ready`` does NOT close it, so this is not a cross-dispatch
-overlap race — the restored executable itself mishandles its donation
+``block_until_ready`` did NOT close it, so this was not a cross-dispatch
+overlap race — the restored executable itself mishandled its donation
 aliasing.  Non-donated restored executables (the inference path) showed no
 deviation under the same protocol.  Two consequences, both encoded here:
 
-* tier 2 is enabled only on non-CPU backends — it restores executables for
-  *every* jit in the process, including donated ones this module cannot
-  see (e.g. ``gluon.functional.make_train_step``), so on CPU it cannot be
-  made safe selectively.  (On TPU, persistent-cache + donated train steps
-  is the standard production workflow.)
+* this program never turns JAX's persistent cache on for the CPU platform
+  — it restores executables for *every* jit in the process, including
+  donated ones this module cannot see (e.g.
+  ``gluon.functional.make_train_step``), so on CPU it cannot be made safe
+  selectively.  (On TPU, persistent-cache + donated train steps is the
+  standard production workflow.)  A user who exports
+  ``JAX_COMPILATION_CACHE_DIR`` on CPU has made that choice themselves.
 * ``donated=True`` callables skip tier 1's disk entries on the CPU backend
   (in-memory AOT lower/compile split only — a CPU restart re-pays the
   fused-step compile; the serving ladder, non-donated, still restores).
@@ -151,69 +164,66 @@ def _exec_dir():
 
 
 def _platform_hint():
-    """Best-effort platform guess WITHOUT initializing the jax backend.
-    ``activate()`` runs at ``import mxnet_tpu``, which must stay legal
-    before ``jax.distributed.initialize()`` / late ``jax.config`` updates
-    on multi-host pods — ``jax.default_backend()`` would latch the backend
-    right there.  Reads the *configured* platform list (JAX_PLATFORMS /
-    ``jax_platforms``); when that is unset (auto-detect), probes for local
-    TPU chips the way jax itself does (a PCI sysfs scan, no backend).
-    Returns a platform name, or None for "unknown"."""
-    p = ""
-    try:
-        import jax
+    """The platform this process will run on, WITHOUT initializing the jax
+    backend.  :func:`place_jax_cache` runs at ``import mxnet_tpu``, which
+    must stay legal before ``jax.distributed.initialize()`` / late
+    ``jax.config`` updates on multi-host pods — ``jax.default_backend()``
+    would latch the backend right there.  Reads the *configured* platform
+    list (JAX_PLATFORMS / ``jax_platforms``); when that is unset
+    (auto-detect), probes for local TPU chips the way jax itself does (a
+    PCI sysfs scan, no backend).  Returns a platform name, or None for
+    "unknown"."""
+    import jax
 
-        p = jax.config.jax_platforms or ""
-    except Exception:
-        pass
-    p = (p or os.environ.get("JAX_PLATFORMS", "")).split(",")[0]
-    p = p.strip().lower()
+    p = jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS", "")
+    p = p.split(",")[0].strip().lower()
     if p:
         return p
-    try:
-        from jax._src import hardware_utils
+    from jax._src import hardware_utils
 
-        if hardware_utils.num_available_tpu_chips_and_device_id()[0] > 0:
-            return "tpu"
-    except Exception:
-        pass
+    if hardware_utils.num_available_tpu_chips_and_device_id()[0] > 0:
+        return "tpu"
     return None
 
 
+def place_jax_cache():
+    """Decide where JAX's persistent compilation cache lives (module
+    docstring) and count its hit/miss events.  MUST run before the first
+    XLA compile — jax latches the cache directory at first use
+    (``mxnet_tpu/__init__.py`` calls this at import) — and must itself not
+    trigger backend init, hence :func:`_platform_hint`.  Idempotent."""
+    global _listener_registered
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip() \
+            and not jax.config.jax_compilation_cache_dir \
+            and _platform_hint() not in (None, "cpu"):
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(checkout, ".jax_cache"))
+    if not _listener_registered:
+        from jax._src import monitoring
+
+        monitoring.register_event_listener(_on_jax_event)
+        _listener_registered = True
+
+
 def activate():
-    """Idempotent per-directory setup: create ``<dir>/exec`` and, on
-    non-CPU backends, point JAX's persistent compilation cache (tier 2) at
-    ``<dir>/xla`` with the min-compile-time / min-entry-size floors dropped
-    so even fast compiles persist.  MUST run before the first XLA compile —
-    jax latches the cache directory at first use (mxnet_tpu/__init__.py
-    applies it at import when MXNET_AOT_CACHE is set) — and must itself not
-    trigger backend init, hence :func:`_platform_hint`.  Tier 2 needs a
-    positively known non-CPU platform: on CPU restored executables race
-    donated buffers (module docstring), and "unknown" resolves to CPU
-    whenever no accelerator shows up.  Best-effort on the jax knobs —
-    tier 1 works alone."""
-    global _activated_dir, _listener_registered
+    """Idempotent per-directory ``MXNET_AOT_CACHE`` setup: create
+    ``<dir>/exec`` (tier 1) and, when :func:`place_jax_cache` left JAX's
+    persistent cache on (tier 2), drop its min-compile-time /
+    min-entry-size floors so even fast compiles persist.  Same
+    before-first-compile rule as :func:`place_jax_cache`."""
+    global _activated_dir
     d = cache_dir()
     if d is None or d == _activated_dir:
         return
     os.makedirs(_exec_dir(), exist_ok=True)
-    try:
-        import jax
+    import jax
 
-        hint = _platform_hint()
-        if hint is not None and hint != "cpu":
-            jax.config.update("jax_compilation_cache_dir",
-                              os.path.join(d, "xla"))
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        if not _listener_registered:
-            from jax._src import monitoring
-
-            monitoring.register_event_listener(_on_jax_event)
-            _listener_registered = True
-    except Exception:
-        pass
+    if jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _activated_dir = d
 
 

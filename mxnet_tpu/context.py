@@ -4,6 +4,23 @@ In the reference a ``Context(dev_type, dev_id)`` names a CPU/GPU device and a
 thread-local default-context stack scopes imperative ops onto it.  Here a
 Context maps onto a concrete ``jax.Device``.  ``gpu(i)`` is kept as an alias
 for the i-th accelerator so reference scripts run unchanged on TPU.
+
+Two placement rules, stated because both decide where arrays live:
+
+* The thread-local DEFAULT context is the JAX default device — ``tpu(0)``
+  on a host with a chip, ``cpu(0)`` without one.  Reference MXNet defaults
+  to ``cpu(0)`` because its accelerator is opt-in.  Here arrays made
+  without a ``ctx`` already land on the JAX default device, so a default of
+  ``cpu(0)`` on a TPU host named a device nothing else used: ``Module``
+  parameters ended up on the chip regardless (the initializer rebinds them;
+  chip run, PR 21), while an array made with an explicit
+  ``ctx=mx.current_context()`` was committed to the HOST device.
+* In a process with NO accelerator (the CPU test tier), ``tpu(i)`` /
+  ``gpu(i)`` alias the host devices so reference scripts and the tests run
+  unchanged.  That alias is deliberate and CPU-only; whatever must not
+  mistake a CPU for the chip (``chip_smoke.py``, ``bench.py``) asserts
+  ``jax.devices()[0].platform`` and array placement itself.  With an
+  accelerator present, a ``device_id`` past the last chip raises.
 """
 from __future__ import annotations
 
@@ -56,9 +73,7 @@ class Context:
         return self.__str__()
 
     def __enter__(self):
-        if not hasattr(Context._default_ctx, "value"):
-            Context._default_ctx.value = Context("cpu", 0)
-        self._old_ctx = Context._default_ctx.value
+        self._old_ctx = current_context()
         Context._default_ctx.value = self
         return self
 
@@ -74,10 +89,17 @@ class Context:
         if self.device_type in ("cpu", "cpu_pinned", "cpu_shared"):
             return jax.devices("cpu")[self.device_id]
         # 'gpu' and 'tpu' both mean "the platform accelerator".
-        accel = _accelerator_devices()
+        accel = [d for d in jax.devices() if d.platform != "cpu"]
         if not accel:
-            return jax.devices()[min(self.device_id, len(jax.devices()) - 1)]
-        return accel[self.device_id % len(accel)]
+            # CPU-only process: the documented alias (module docstring)
+            devs = jax.devices()
+            return devs[self.device_id % len(devs)]
+        if self.device_id >= len(accel):
+            from .base import MXNetError
+
+            raise MXNetError("%s: this process sees %d accelerator device(s)"
+                             % (self, len(accel)))
+        return accel[self.device_id]
 
     def empty_cache(self):
         """Release pooled device memory (reference ctx.empty_cache)."""
@@ -86,16 +108,6 @@ class Context:
         import gc
 
         gc.collect()
-
-
-def _accelerator_devices():
-    import jax
-
-    try:
-        devs = jax.devices()
-    except RuntimeError:
-        return []
-    return [d for d in devs if d.platform != "cpu"] or devs
 
 
 def cpu(device_id=0):
@@ -127,7 +139,12 @@ num_tpus = num_gpus
 
 
 def current_context():
-    """The thread-local default context (reference context.py current_context)."""
+    """The thread-local default context (reference context.py
+    current_context); outside any ``with ctx:`` scope, the context of the JAX
+    default device (module docstring)."""
     if not hasattr(Context._default_ctx, "value"):
-        Context._default_ctx.value = Context("cpu", 0)
+        import jax
+
+        on_cpu = jax.local_devices()[0].platform == "cpu"
+        Context._default_ctx.value = Context("cpu" if on_cpu else "tpu", 0)
     return Context._default_ctx.value

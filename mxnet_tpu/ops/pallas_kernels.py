@@ -216,10 +216,6 @@ def cost_dconv_col_bwd(bg, n, hw, c, ft_itemsize=4):
 
 def supported(shape, dtype):
     """Tile-aligned 2D-reshapeable arrays of a pallas-kernel dtype on TPU."""
-    try:
-        import jax.experimental.pallas  # noqa: F401
-    except ImportError:  # pragma: no cover
-        return False
     sub = _MIN_SUBLANES.get(jnp.dtype(dtype))
     if sub is None:
         return False
@@ -227,6 +223,31 @@ def supported(shape, dtype):
     for s in shape:
         n *= int(s)
     return n >= sub * _LANE and n % (sub * _LANE) == 0
+
+
+# Mosaic kernels cannot be partitioned by GSPMD: under a jit whose arrays are
+# sharded over several chips the lowering raises "Mosaic kernels cannot be
+# automatically partitioned. Please wrap the call in a shard_map" (chip run,
+# PR 21: the detection phase of dryrun_multichip(4)).  The batched kernels
+# below are independent per leading-axis row, so where the trace can see a
+# mesh with the data-parallel axis (``with jax.set_mesh(mesh):`` around the
+# jit) they run per shard of it.  Without a visible mesh the call stays
+# plain: fine on one chip, and on several it fails with jax's message.
+_BATCH_AXIS = "dp"
+
+
+def _per_batch_shard(fn, *args):
+    """``fn(*args)``; every array argument and result has the same leading
+    batch axis."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh = jax.sharding.get_abstract_mesh()
+    n = mesh.shape.get(_BATCH_AXIS, 1)
+    if n == 1 or _BATCH_AXIS in mesh.manual_axes or args[0].shape[0] % n:
+        return fn(*args)
+    spec = P(_BATCH_AXIS)
+    return jax.shard_map(fn, in_specs=spec, out_specs=spec,
+                         check_vma=False)(*args)
 
 
 def _q_kernel(x_ref, scale_ref, out_ref):
@@ -286,7 +307,8 @@ def _quant_block(kernel, rows, in_itemsize, out_itemsize):
 def _tiled_elementwise(kernel, x, scale, out_dtype, interpret, name=None):
     """Shared scaffolding: flatten to (rows, 128) tiles, grid over row
     blocks, scalar in SMEM — the template for further elementwise kernels.
-    ``name`` keys the autotuned row-block lookup (None = the constant)."""
+    ``name`` keys the autotuned row-block lookup (None = the constant) and
+    names the kernel in the compiled module and the device trace."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -309,6 +331,7 @@ def _tiled_elementwise(kernel, x, scale, out_dtype, interpret, name=None):
         ],
         out_specs=pl.BlockSpec((block, _LANE), lambda i: (i, 0)),
         interpret=interpret,
+        name=name,
     )(flat, scale)
     return out.reshape(shape)
 
@@ -480,6 +503,12 @@ def _nms_kernel_factory(nb, thresh, plus_one, use_ids, tile=_NMS_TILE):
 def _nms_pallas_batched(boxes, valid, idv, thresh, plus_one, use_ids,
                         interpret):
     """boxes (B,N,4) f32, valid (B,N) bool, idv (B,N) f32 -> alive (B,N)."""
+    return _per_batch_shard(
+        lambda b, v, i: _nms_call(b, v, i, thresh, plus_one, use_ids,
+                                  interpret), boxes, valid, idv)
+
+
+def _nms_call(boxes, valid, idv, thresh, plus_one, use_ids, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -512,6 +541,7 @@ def _nms_pallas_batched(boxes, valid, idv, thresh, plus_one, use_ids,
         out_specs=pl.BlockSpec((1, 1, Np), lambda b, k: (b, 0, 0),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="nms_alive_pallas",
     )(cols, colst)
     return alive[:, 0, :N] > 0.0
 
@@ -756,14 +786,17 @@ psroi_abuild_pallas.defvjp(_abuild_fwd, _abuild_bwd)
 
 _DCONV_NBLK = 128
 
-# Mosaic hard-fails when one grid step's working set exceeds VMEM.  The
-# estimate below intentionally OVERCOUNTS (it sums all six factor planes
-# as if simultaneously resident; Mosaic fuses several), so the limit is
-# calibrated against measured shapes rather than the 16 MiB hardware
-# figure: north-star res5 (HW=2432, cpg=512) scores 15.8 MB bf16 /
-# 18.3 MB f32 and compiles+runs (round-5 PERF_NOTES), while conv4-scale
-# maps (HW~9728) score 35+ MB and hard-fail.  24 MB splits them with
-# margin on both sides.
+# Mosaic hard-fails when one grid step's working set exceeds its scoped VMEM
+# limit.  The estimate below intentionally OVERCOUNTS (it sums all six
+# factor planes as if simultaneously resident; Mosaic fuses several), and
+# the limit is a number that worked, not one the compiler is given: no
+# pallas_call here passes ``vmem_limit_bytes``.  On jaxlib 0.9.0 / libtpu
+# 0.0.34, TPU v5 lite (chip run, PR 21), north-star res5 (HW=2432, cpg=128:
+# 10.2 MB bf16) and cpg=512 (15.8 MB) compile and run — and so did a
+# conv4-scale map (HW=9728, cpg=64) scoring 36.9 MB.  So 24 MB is
+# conservative here: shapes between it and the real limit take the XLA scan
+# though the kernel would build (ROADMAP A2 ties guard and compiler to one
+# number).
 _DCONV_VMEM_LIMIT = 24 << 20
 
 
@@ -773,8 +806,8 @@ def dconv_bwd_vmem_bytes(HW, C, itemsize, nblk=_DCONV_NBLK):
     (f32, (nblk, HW) each), the ft block and the f32 dft accumulator
     ((HW, C)), and the g block ((nblk, C)).  Drives the auto-branch guard in
     ``detection.py deformable_convolution`` — above ``_DCONV_VMEM_LIMIT``
-    (override: MXNET_DCONV_VMEM_MB) large feature maps fall back to the XLA
-    scan instead of hard-failing Mosaic compilation (ADVICE round 5)."""
+    (override: MXNET_DCONV_VMEM_MB) large feature maps take the XLA scan
+    instead of risking a hard Mosaic failure (ADVICE round 5)."""
     return (7 * 4 * nblk * HW          # dA + 6 factor planes, f32
             + HW * C * (itemsize + 4)  # ft block + f32 dft accumulator
             + nblk * C * (itemsize + 4))  # g block + col block
@@ -926,6 +959,12 @@ def _dconv_grid(N, HW=None, C=None, itemsize=4):
 
 
 def _dconv_impl(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret):
+    return _per_batch_shard(
+        lambda *a: _dconv_fwd_call(*a, hw, interpret),
+        y0, y1, x0, x1, ly, lx, lf, ft)
+
+
+def _dconv_fwd_call(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret):
     from jax.experimental import pallas as pl
 
     H, W = hw
@@ -948,6 +987,7 @@ def _dconv_impl(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret):
             pl.BlockSpec((1, HW, C), lambda bg, i: (bg, 0, 0))],
         out_specs=pl.BlockSpec((1, nblk, C), lambda bg, i: (bg, i, 0)),
         interpret=interpret,
+        name="dconv_col_pallas_fwd",
     )(*ints, *flts, ft)
     return out[:, :N]
 
@@ -958,9 +998,17 @@ def _dconv_fwd(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret):
 
 
 def _dconv_bwd(hw, interpret, res, g):
+    import numpy as _np
+
+    dly, dlx, dlf, dft = _per_batch_shard(
+        lambda *a: _dconv_bwd_call(*a, hw, interpret), *res, g)
+    f0 = lambda a: _np.zeros(a.shape, jax.dtypes.float0)
+    return (*(f0(a) for a in res[:4]), dly, dlx, dlf, dft)
+
+
+def _dconv_bwd_call(y0, y1, x0, x1, ly, lx, lf, ft, g, hw, interpret):
     from jax.experimental import pallas as pl
 
-    y0, y1, x0, x1, ly, lx, lf, ft = res
     H, W = hw
     BG, N = y0.shape
     HW, C = ft.shape[1], ft.shape[2]
@@ -986,12 +1034,9 @@ def _dconv_bwd(hw, interpret, res, g):
         out_specs=(fac_spec, fac_spec, fac_spec,
                    pl.BlockSpec((1, HW, C), lambda bg, i: (bg, 0, 0))),
         interpret=interpret,
+        name="dconv_col_pallas_bwd",
     )(*ints, *flts, ft, gp)
-    import numpy as _np
-
-    f0 = lambda a: _np.zeros(a.shape, jax.dtypes.float0)
-    return (f0(y0), f0(y1), f0(x0), f0(x1),
-            dly[:, 0, :N], dlx[:, 0, :N], dlf[:, 0, :N],
+    return (dly[:, 0, :N], dlx[:, 0, :N], dlf[:, 0, :N],
             dft.astype(ft.dtype))
 
 

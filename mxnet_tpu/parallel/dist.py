@@ -80,10 +80,7 @@ def init(coordinator_address=None, num_processes=None, process_id=None,
     plats = (os.environ.get("JAX_PLATFORMS")
              or os.environ.get("JAX_PLATFORM_NAME") or "")
     if "cpu" in plats.split(","):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # older jax without the option: single-host tests only
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
@@ -112,35 +109,22 @@ def is_coordinator():
     return rank() == 0
 
 
-def kv_prefix_ranks(client, prefix, size):
+def kv_prefix_ranks(client, prefix):
     """{rank: value string} for every ``<prefix><rank>`` key published in
-    the coordination-service KV store — ONE ``key_value_dir_get`` (carried
-    by every jaxlib 0.4+ client), falling back to per-rank
-    ``key_value_try_get`` (which only newer clients have; the pinned
-    0.4.37 does NOT — discovered in ISSUE 12, where the try_get-only scan
-    made the dead-node check misreport every rank as dead).  The ONE
+    the coordination-service KV store — ONE ``key_value_dir_get``.  The ONE
     implementation behind both :func:`barrier`'s arrival marks and the
-    trainhealth heartbeat exchange; every failure degrades to
-    absent-key."""
-    out = {}
+    trainhealth heartbeat exchange; a failed RPC degrades to no keys (every
+    rank absent)."""
     try:
         pairs = client.key_value_dir_get(prefix)
     except Exception:
-        pairs = None
-    if pairs is not None:
-        for k, v in pairs:
-            try:
-                out[int(str(k).rsplit("/", 1)[-1])] = str(v)
-            except ValueError:
-                pass
-        return out
-    for r in range(size):
+        return {}
+    out = {}
+    for k, v in pairs:
         try:
-            v = client.key_value_try_get(prefix + str(r))
-        except Exception:
-            v = None
-        if v:
-            out[r] = str(v)
+            out[int(str(k).rsplit("/", 1)[-1])] = str(v)
+        except ValueError:
+            pass
     return out
 
 
@@ -191,10 +175,8 @@ def barrier(name="mxnet_barrier", timeout_ms=None):
         client.wait_at_barrier("%s_%d" % (name, _barrier_seq), int(timeout_ms))
     except Exception as exc:
         # who never arrived?  One shared KV prefix scan over the
-        # arrival marks (kv_prefix_ranks — the ISSUE 12 fix: the old
-        # try_get-only loop misreported EVERY rank as dead on clients
-        # without that method, e.g. the pinned jaxlib 0.4.37)
-        arrived = kv_prefix_ranks(client, mark + "/", jax.process_count())
+        # arrival marks
+        arrived = kv_prefix_ranks(client, mark + "/")
         missing = [r for r in range(jax.process_count())
                    if r not in arrived]
         if missing:

@@ -43,9 +43,8 @@ def moe_ffn(x, gate_w, expert_params, expert_fn, *, mesh, axis="ep",
     """
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from .shard_map_compat import shard_map
 
     E = mesh.shape[axis]
     for leaf in jax.tree_util.tree_leaves(expert_params):

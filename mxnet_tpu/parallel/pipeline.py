@@ -56,9 +56,8 @@ def gpipe(stage_fn, stacked_params, microbatches, *, mesh, axis="pp"):
     """
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from .shard_map_compat import shard_map, pvary
 
     S = mesh.shape[axis]
     M = microbatches.shape[0]
@@ -75,10 +74,10 @@ def gpipe(stage_fn, stacked_params, microbatches, *, mesh, axis="pp"):
         # p_stacked leaves: (1, ...) — this device's stage slice
         p = jax.tree_util.tree_map(lambda a: a[0], p_stacked)
         d = jax.lax.axis_index(axis)
-        # pvary: the carries differ per stage — mark them axis-varying so
-        # the fori_loop carry types line up under shard_map
-        state = pvary(jnp.zeros_like(xs[0]), (axis,))
-        outs = pvary(jnp.zeros_like(xs), (axis,))
+        # the carries differ per stage — mark them axis-varying so the
+        # fori_loop carry types line up under shard_map
+        state = jax.lax.pcast(jnp.zeros_like(xs[0]), (axis,), to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
 
         def tick(t, carry):
             state, outs = carry

@@ -73,12 +73,9 @@ def ring_attention(q, k, v, axis_name="sp", causal=False, scale=None):
     m0 = jnp.full((B, H, Sq, 1), neg_inf, jnp.float32)
     l0 = jnp.zeros((B, H, Sq, 1), jnp.float32)
     # mark accumulators device-varying so the scan carry type matches
-    # (shard_map VMA checking, jax ≥0.8)
-    try:
-        from .shard_map_compat import pvary
-        o0, m0, l0 = (pvary(x, (axis_name,)) for x in (o0, m0, l0))
-    except AttributeError:
-        pass
+    # (shard_map VMA checking)
+    o0, m0, l0 = (jax.lax.pcast(x, (axis_name,), to="varying")
+                  for x in (o0, m0, l0))
     # scan n-1 rotate-steps, then consume the final block without rotating —
     # otherwise the last ppermute ships a full K+V block nobody reads
     if n > 1:
@@ -95,10 +92,10 @@ def ring_attention(q, k, v, axis_name="sp", causal=False, scale=None):
 def ring_self_attention(q, k, v, mesh=None, axis_name="sp", causal=False, scale=None):
     """Standalone ring attention: shards the sequence axis of [B, H, S, D]
     inputs over ``axis_name`` of ``mesh`` and runs :func:`ring_attention`."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .mesh import current_mesh
-    from .shard_map_compat import shard_map
 
     mesh = mesh or current_mesh()
     if axis_name not in mesh.axis_names:

@@ -265,15 +265,14 @@ def _publish_heartbeat(client, rank, drains):
         pass
 
 
-def _read_heartbeats(client, size):
+def _read_heartbeats(client):
     """{rank: (drain count, unix_ts)} for every rank that has published —
     one shared KV prefix scan (``parallel.dist.kv_prefix_ranks``, the same
-    dir_get-with-try_get-fallback the dead-node check uses: one
-    implementation of the jaxlib-version-sensitive client dance)."""
+    one the dead-node check uses)."""
     from ..parallel.dist import kv_prefix_ranks
 
     out = {}
-    for rk, value in kv_prefix_ranks(client, _HB_PREFIX, size).items():
+    for rk, value in kv_prefix_ranks(client, _HB_PREFIX).items():
         try:
             s, ts = str(value).split(":", 1)
             out[rk] = (int(s), float(ts))
@@ -464,7 +463,7 @@ class HealthPlane:
         from . import instrument
 
         now = time.time()
-        hbs = _read_heartbeats(client, size)
+        hbs = _read_heartbeats(client)
         r = instrument.registry() if instrument.enabled() else None
         agg = {}
         for rk in range(size):

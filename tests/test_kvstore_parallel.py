@@ -157,10 +157,9 @@ class TestMesh:
 
 class TestCollectives:
     def test_allreduce_in_shard_map(self):
-        import jax
         import jax.numpy as jnp
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-        from mxnet_tpu.parallel.shard_map_compat import shard_map
 
         mesh = parallel.make_mesh(dp=8)
 
@@ -174,8 +173,8 @@ class TestCollectives:
 
     def test_pmean_and_reduce_scatter(self):
         import jax.numpy as jnp
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-        from mxnet_tpu.parallel.shard_map_compat import shard_map
 
         mesh = parallel.make_mesh(dp=8)
         x = jnp.arange(16.0).reshape(8, 2)

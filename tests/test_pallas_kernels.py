@@ -158,3 +158,60 @@ def test_dconv_vmem_guard(monkeypatch):
     assert dconv_fits_vmem(76 * 128, 512, 2)
     monkeypatch.setenv("MXNET_DCONV_VMEM_MB", "1")
     assert not dconv_fits_vmem(38 * 64, 64, 2)
+
+
+def test_batched_kernels_run_per_dp_shard_under_a_visible_mesh():
+    """GSPMD cannot partition a Mosaic kernel (on chips the lowering raises
+    and asks for a shard_map — PR 21's four-chip run).  Traced under
+    ``jax.set_mesh`` with a dp axis, the batched NMS and dconv kernels wrap
+    themselves in a shard_map over it; results equal the plain call and
+    stay dp-sharded.  With no visible mesh the call is plain."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    mesh = parallel.make_mesh({"dp": 8})
+    shard = lambda a: jax.device_put(a, NamedSharding(mesh, P("dp")))
+    rng = np.random.RandomState(0)
+
+    B, N = 8, 300
+    ctr, wh = rng.rand(B, N, 2) * 500, rng.rand(B, N, 2) * 100 + 8
+    boxes = np.concatenate([ctr - wh / 2, ctr + wh / 2], -1).astype(np.float32)
+    valid = np.ones((B, N), bool)
+    nms = jax.vmap(lambda b, v: pk.nms_alive_pallas(
+        b, v, None, thresh=0.7, interpret=True))
+    assert "shard_map" not in str(jax.make_jaxpr(nms)(boxes, valid))
+    want = jax.jit(nms)(boxes, valid)
+    with jax.set_mesh(mesh):
+        assert "shard_map" in str(jax.make_jaxpr(nms)(boxes, valid))
+        got = jax.jit(nms)(shard(boxes), shard(valid))
+    assert got.sharding.spec == P("dp")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    BG, C, H, W = 8, 8, 5, 7
+    n = 9 * H * W
+    sy = rng.uniform(0, H - 1, (BG, n)).astype(np.float32)
+    sx = rng.uniform(0, W - 1, (BG, n)).astype(np.float32)
+    y0, x0 = np.floor(sy).astype(np.int32), np.floor(sx).astype(np.int32)
+    y1, x1 = np.minimum(y0 + 1, H - 1), np.minimum(x0 + 1, W - 1)
+    lf = (rng.rand(BG, n) > 0.1).astype(np.float32)
+    ft = rng.randn(BG, H * W, C).astype(np.float32)
+
+    def loss(ly, lx, lf, ft):
+        col = pk.dconv_col_pallas(y0, y1, x0, x1, ly, lx, lf, ft, (H, W),
+                                  True)
+        return jnp.sum(col * jnp.cos(jnp.arange(
+            col.size, dtype=jnp.float32)).reshape(col.shape))
+
+    grad = jax.grad(loss, argnums=(0, 1, 2, 3))
+    args = (sy - y0, sx - x0, lf, ft)
+    want = jax.jit(grad)(*args)
+    with jax.set_mesh(mesh):
+        # forward and backward kernel, one shard_map each
+        assert str(jax.make_jaxpr(grad)(*args)).count("shard_map") == 2
+        got = jax.jit(grad)(*map(shard, args))
+    for g, w in zip(got, want):
+        assert g.sharding.spec == P("dp")
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

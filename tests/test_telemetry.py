@@ -466,8 +466,20 @@ GOLDEN_TRACE = {
 class TestTraceSummary:
     def _run(self, *argv):
         return subprocess.run(
-            [sys.executable, os.path.join(REPO, "tools", "trace_summary.py")]
+            [sys.executable, os.path.join(REPO, "tools", "trace_summary.py"),
+             "--device-kind", "TPU v5 lite"]
             + list(argv), capture_output=True, text=True, timeout=300)
+
+    def test_peaks_have_no_default(self, tmp_path):
+        """A trace does not name its chip: no peaks given, or an unknown
+        device kind, is an error — never v5e's numbers by default."""
+        trace = tmp_path / "trace.json"
+        trace.write_text(json.dumps(GOLDEN_TRACE))
+        tool = os.path.join(REPO, "tools", "trace_summary.py")
+        for extra in ([], ["--device-kind", "TPU v9"]):
+            res = subprocess.run([sys.executable, tool, str(trace)] + extra,
+                                 capture_output=True, text=True, timeout=300)
+            assert res.returncode == 2 and "peak" in res.stderr, res.stderr
 
     def test_golden_table(self, tmp_path):
         trace = tmp_path / "trace.json"
@@ -545,12 +557,16 @@ class TestTraceSummary:
 
 # -- bench schema lint -------------------------------------------------------
 class TestBenchSchema:
-    def test_self_test_and_captures(self):
-        import glob
-
+    def test_self_test_and_captures(self, tmp_path):
+        # no capture is checked in; one written here keeps the driver-capture
+        # reader (the ``parsed`` field) under the lint
+        cap = tmp_path / "BENCH_capture.json"
+        cap.write_text(json.dumps(
+            {"rc": 0, "parsed": {"metric": "m", "value": 1.0,
+                                 "unit": "img/s", "vs_baseline": None}}))
         res = subprocess.run(
             [sys.executable, os.path.join(REPO, "ci", "check_bench_schema.py"),
-             "--self-test"] + sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json"))),
+             "--self-test", str(cap)],
             capture_output=True, text=True, timeout=300)
         assert res.returncode == 0, res.stdout + res.stderr
 

@@ -196,7 +196,8 @@ def test_trace_summary_accepts_per_rank_files(tmp_path, capsys):
     f0 = _rank_trace(tmp_path, 0, "op_shared", "clock_sync")
     f1 = _rank_trace(tmp_path, 1, "op_shared", "filename")
     # merged accounting: one row with both ranks' calls
-    assert ts.main([f0, f1]) == 0
+    kind = ["--device-kind", "TPU v5 lite"]
+    assert ts.main([f0, f1] + kind) == 0
     out = capsys.readouterr().out
     assert "ranks 0,1 over 2 file(s)" in out
     import re
@@ -204,7 +205,24 @@ def test_trace_summary_accepts_per_rank_files(tmp_path, capsys):
     row = [l for l in out.splitlines() if l.startswith("op_shared")]
     assert row and re.search(r"\s2\s", row[0]), row  # 2 calls merged
     # --per-rank keeps them apart
-    assert ts.main([f0, f1, "--per-rank"]) == 0
+    assert ts.main([f0, f1, "--per-rank"] + kind) == 0
     out = capsys.readouterr().out
     assert any(l.startswith("r0/op_shared") for l in out.splitlines())
     assert any(l.startswith("r1/op_shared") for l in out.splitlines())
+
+
+def test_local_launcher_refuses_to_share_tpu_chips(monkeypatch):
+    """A chip belongs to one process: on a host with TPU device nodes the
+    local launcher refuses -n > 1 unless JAX_PLATFORMS keeps the children
+    off the TPU (the CPU fake cluster)."""
+    launch = _load("launch.py")
+    monkeypatch.setattr(launch.glob, "glob",
+                        lambda pat: ["/dev/vfio/0"] if "vfio" in pat else [])
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert not launch._children_would_share_tpu()
+    for platforms in ("", "tpu,cpu"):
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        assert launch._children_would_share_tpu()
+        with pytest.raises(SystemExit, match="a chip belongs to one process"):
+            launch.launch_local(2, [sys.executable, "-c", "pass"])
+    assert "jax" not in launch.__dict__  # the parent stays off JAX

@@ -44,15 +44,8 @@ def _parse_size(s):
 def measure(sizes, iters=20, dtype="float32", warmup=3):
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    try:
-        from mxnet_tpu.parallel.shard_map_compat import shard_map
-    except ImportError:  # standalone use outside the repo
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
 
     devs = np.array(jax.devices())
     n = len(devs)

@@ -10,7 +10,8 @@ Usage (mirrors the reference CLI):
 
     # N local processes, a fake cluster on one host (the reference's
     # `--launcher local` nightly-test pattern, ci/runtime_functions.sh:673)
-    python tools/launch.py -n 4 --launcher local python train.py ...
+    JAX_PLATFORMS=cpu python tools/launch.py -n 4 --launcher local \
+        python train.py ...
 
     # ssh to a host list; each host runs one process
     python tools/launch.py -n 4 -H hostfile --launcher ssh python train.py ...
@@ -23,10 +24,20 @@ Observability env (MXNET_TELEMETRY / MXNET_TRACE / MXNET_FLIGHTREC_DIR /
 MXNET_POD_METRICS*) set on the launcher is propagated to every worker, and
 each worker's stdout/stderr is line-prefixed with ``[rank N]`` so pod logs
 stay attributable (ISSUE 19 satellite).
+
+**One process per chip.**  A TPU chip belongs to one process at a time, and
+every child of the local launcher would see — and claim — every chip of the
+host.  The local launcher does not pin children to chips: on a host with TPU
+chips it REFUSES ``-n`` above 1 unless ``JAX_PLATFORMS`` keeps the children
+off the TPU (the CPU fake cluster above).  One process drives all the chips
+of a host through a mesh; several hosts go through ``--launcher ssh``, one
+process each.  For the same reason this launcher never imports jax: a parent
+that has touched JAX holds the chip its child needs.
 """
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import socket
@@ -42,7 +53,8 @@ import threading
 # families (…_MAX_MB, …_CACHE, …_MODEL, …_TOPK): an operator pointing the
 # AOT/autotune caches at shared storage must have every rank see them, or
 # a pod restart is warm on rank 0 and cold everywhere else.
-_PROPAGATE_EXACT = ("MXNET_TELEMETRY", "MXNET_TRACE", "MXNET_FLIGHTREC_DIR")
+_PROPAGATE_EXACT = ("MXNET_TELEMETRY", "MXNET_TRACE", "MXNET_FLIGHTREC_DIR",
+                    "JAX_COMPILATION_CACHE_DIR")
 _PROPAGATE_PREFIX = ("MXNET_POD_METRICS", "MXNET_AOT_CACHE",
                      "MXNET_AUTOTUNE", "MXNET_ELASTIC")
 
@@ -94,8 +106,24 @@ def _spawn_prefixed(cmd, rank, env=None):
     return p, t
 
 
+def _children_would_share_tpu():
+    """True when children started here would each open this host's TPU
+    chips: the chips' device nodes exist and ``JAX_PLATFORMS`` does not keep
+    jax off them.  Decided without importing jax (module docstring)."""
+    platforms = os.environ.get("JAX_PLATFORMS", "").strip().lower()
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
 def launch_local(n, command, verbose=False):
     """N processes on this host (the reference local tracker)."""
+    if n > 1 and _children_would_share_tpu():
+        raise SystemExit(
+            "launch.py --launcher local -n %d: this host has TPU chips and "
+            "every child would claim all of them (a chip belongs to one "
+            "process).  Run ONE process that drives the chips through a "
+            "mesh, or set JAX_PLATFORMS=cpu for a CPU fake cluster." % n)
     coordinator = "127.0.0.1:%d" % _free_port()
     procs, pumps = [], []
     try:

@@ -27,12 +27,15 @@ are always visible — a registered kernel can never be invisible again.
 
 Usage::
 
-    python tools/trace_summary.py profile.json
-    python tools/trace_summary.py profile.json --ledger cost_ledger.jsonl
+    K="TPU v5 lite"    # jax.devices()[0].device_kind of the traced chip
+    python tools/trace_summary.py profile.json --device-kind "$K"
+    python tools/trace_summary.py profile.json --device-kind "$K" \
+        --ledger cost_ledger.jsonl
     python tools/trace_summary.py profile.json --costs telemetry.jsonl \
         --peak-flops 197e12 --peak-bw 819e9 --top 20
-    python tools/trace_summary.py profile.json --json   # machine-readable
-    python tools/trace_summary.py rank0.json rank1.json --per-rank
+    python tools/trace_summary.py profile.json --device-kind "$K" --json
+    python tools/trace_summary.py rank0.json rank1.json --device-kind "$K" \
+        --per-rank
 
 **Per-rank inputs (ISSUE 12).**  A pod run produces one trace/flight dump
 per process; pass them all — each file's rank is detected like
@@ -44,8 +47,9 @@ up as one rank's ops running long.
 
 Roofline: intensity = flops/bytes (declared), attainable = min(peak_flops,
 intensity * peak_bw); %roof compares achieved FLOP/s (or B/s for zero-flop
-ops) against it.  Defaults are one TPU v5e chip: 197 TFLOP/s bf16,
-819 GB/s HBM (docs/PERF_NOTES.md).
+ops) against it.  A trace does not say which chip produced it, so the peaks
+have no default: name the chip (``--device-kind``, looked up in ``PEAKS``;
+an unknown kind is an error) or give both ``--peak-flops`` and ``--peak-bw``.
 """
 from __future__ import annotations
 
@@ -53,6 +57,11 @@ import argparse
 import gzip
 import json
 import sys
+
+
+# device_kind (as jax reports it) -> (bf16 FLOP/s, HBM B/s) of ONE chip.
+# Source: Google Cloud documentation, "TPU v5e".
+PEAKS = {"TPU v5 lite": (197e12, 819e9)}
 
 
 def load_trace(path):
@@ -325,15 +334,32 @@ def main(argv=None):
     p.add_argument("--live-registry", action="store_true",
                    help="also pull traced costs from the in-process Pallas "
                         "registry (imports jax)")
-    p.add_argument("--peak-flops", type=float, default=197e12,
-                   help="roofline compute peak, FLOP/s (default v5e bf16)")
-    p.add_argument("--peak-bw", type=float, default=819e9,
-                   help="roofline HBM peak, B/s (default v5e)")
+    p.add_argument("--device-kind", default=None,
+                   help="device_kind of the chip that produced the trace; "
+                        "its peaks come from the PEAKS table (known: %s)"
+                        % ", ".join(sorted(PEAKS)))
+    p.add_argument("--peak-flops", type=float, default=None,
+                   help="roofline compute peak, FLOP/s (overrides the table)")
+    p.add_argument("--peak-bw", type=float, default=None,
+                   help="roofline HBM peak, B/s (overrides the table)")
     p.add_argument("--top", type=int, default=30,
                    help="show only the top-N ops by total time (0 = all)")
     p.add_argument("--json", action="store_true",
                    help="emit machine-readable JSON instead of the table")
     args = p.parse_args(argv)
+    if args.device_kind is not None:
+        if args.device_kind not in PEAKS:
+            p.error("unknown --device-kind %r (known: %s); give --peak-flops "
+                    "and --peak-bw" % (args.device_kind,
+                                       ", ".join(sorted(PEAKS))))
+        table = PEAKS[args.device_kind]
+        if args.peak_flops is None:
+            args.peak_flops = table[0]
+        if args.peak_bw is None:
+            args.peak_bw = table[1]
+    if args.peak_flops is None or args.peak_bw is None:
+        p.error("the roofline needs the chip's peaks: --device-kind, or both "
+                "--peak-flops and --peak-bw")
 
     ops, costs, ranks = {}, {}, []
     for path in args.trace:
