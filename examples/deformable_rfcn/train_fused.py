@@ -138,30 +138,32 @@ def make_rfcn_train_step(net, batch, learning_rate=5e-4, momentum=0.9,
          _rois, label, bbox_target, bbox_weight, cls_score, bbox_pred) = (
             jnp.asarray(o).astype(jnp.float32) for o in outs)
 
-        # RPN losses (reference train_end2end loss heads; anchor order
-        # h·(W·A)+w·A+a matches rpn_anchor_target / MultiProposal)
-        logits = rpn_cls.reshape(batch, 2, A, Hf, Wf).transpose(0, 3, 4, 2, 1)
-        logits = logits.reshape(batch, a_total, 2)
-        valid = rpn_label >= 0
-        lab = jnp.maximum(rpn_label, 0.0).astype(jnp.int32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ce = -jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
-        rpn_cls_loss = jnp.where(valid, ce, 0.0).sum() / jnp.maximum(valid.sum(), 1)
-        bp = rpn_bbox.reshape(batch, A, 4, Hf, Wf).transpose(0, 3, 4, 1, 2)
-        bp = bp.reshape(batch, a_total, 4)
-        rpn_bbox_loss = _smooth_l1(bp, rpn_bt, rpn_bw, 3.0).sum() / (
-            net.rpn_batch * batch)
+        with jax.named_scope("loss"):
+            # RPN losses (reference train_end2end loss heads; anchor order
+            # h·(W·A)+w·A+a matches rpn_anchor_target / MultiProposal)
+            logits = rpn_cls.reshape(batch, 2, A, Hf, Wf).transpose(0, 3, 4, 2, 1)
+            logits = logits.reshape(batch, a_total, 2)
+            valid = rpn_label >= 0
+            lab = jnp.maximum(rpn_label, 0.0).astype(jnp.int32)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            ce = -jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
+            rpn_cls_loss = jnp.where(valid, ce, 0.0).sum() / jnp.maximum(
+                valid.sum(), 1)
+            bp = rpn_bbox.reshape(batch, A, 4, Hf, Wf).transpose(0, 3, 4, 1, 2)
+            bp = bp.reshape(batch, a_total, 4)
+            rpn_bbox_loss = _smooth_l1(bp, rpn_bt, rpn_bw, 3.0).sum() / (
+                net.rpn_batch * batch)
 
-        # R-CNN head losses (class-agnostic bbox, R-FCN convention)
-        logp2 = jax.nn.log_softmax(cls_score, axis=-1)
-        rcnn_cls_loss = -jnp.take_along_axis(
-            logp2, label.astype(jnp.int32)[:, None], axis=1).mean()
-        rcnn_bbox_loss = _smooth_l1(bbox_pred, bbox_target, bbox_weight, 1.0
-                                    ).sum() / label.shape[0]
+            # R-CNN head losses (class-agnostic bbox, R-FCN convention)
+            logp2 = jax.nn.log_softmax(cls_score, axis=-1)
+            rcnn_cls_loss = -jnp.take_along_axis(
+                logp2, label.astype(jnp.int32)[:, None], axis=1).mean()
+            rcnn_bbox_loss = _smooth_l1(bbox_pred, bbox_target, bbox_weight, 1.0
+                                        ).sum() / label.shape[0]
 
-        total = rpn_cls_loss + rpn_bbox_loss + rcnn_cls_loss + rcnn_bbox_loss
-        parts = jnp.stack([rpn_cls_loss, rpn_bbox_loss, rcnn_cls_loss,
-                           rcnn_bbox_loss])
+            total = rpn_cls_loss + rpn_bbox_loss + rcnn_cls_loss + rcnn_bbox_loss
+            parts = jnp.stack([rpn_cls_loss, rpn_bbox_loss, rcnn_cls_loss,
+                               rcnn_bbox_loss])
         return total, (new_aux, parts)
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
@@ -171,12 +173,13 @@ def make_rfcn_train_step(net, batch, learning_rate=5e-4, momentum=0.9,
         # as a traced scalar — decays then cost zero recompiles
         learn, mom, aux = state
         (loss, (new_aux, parts)), grads = grad_fn(learn, aux, data, im_info, gt, key)
-        if momentum:
-            mom = [momentum * m + g for m, g in zip(mom, grads)]
-            upd = mom
-        else:
-            upd = grads
-        learn = [p - lr * g for p, g in zip(learn, upd)]
+        with jax.named_scope("optimizer"):
+            if momentum:
+                mom = [momentum * m + g for m, g in zip(mom, grads)]
+                upd = mom
+            else:
+                upd = grads
+            learn = [p - lr * g for p, g in zip(learn, upd)]
         return (learn, mom, new_aux), loss, parts
 
     learn_vals = [vals[i] for i in learn_idx]

@@ -90,7 +90,19 @@ _stats = {"hits": 0, "misses": 0, "errors": 0,
           # wall seconds spent in tier-1 XLA compiles this process (ISSUE
           # 20): a warm restart must read 0.0 here on EVERY rank — the
           # train-side analog of the serving warmup's aot_compile_s
-          "compile_s": 0.0}
+          "compile_s": 0.0,
+          # JAX's own duration events summed over every program of the
+          # process (:func:`_on_jax_duration`): tracing to a jaxpr, lowering
+          # it to MLIR, the backend compile (on a persistent-cache hit: the
+          # load), and reading the cache entry, a part of the last
+          "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+          "cache_load_s": 0.0}
+_DURATION_KEYS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+}
 _activated_dir = None
 _listener_registered = False
 
@@ -121,7 +133,12 @@ def stats():
     signature re-use counts as neither); ``xla_hits``/``xla_misses`` mirror
     JAX's persistent-compilation-cache events (tier 2 — every XLA backend
     compile in the process, donated steps included); ``errors`` are
-    rejected tier-1 entries (each one a clean miss + recompile)."""
+    rejected tier-1 entries (each one a clean miss + recompile).
+    ``trace_s`` / ``lower_s`` / ``backend_s`` / ``cache_load_s`` are the
+    seconds JAX reports for its compile stages, summed over the process:
+    where set-up time goes below ``xla_hits``.  A jit traced inside another
+    reports its trace on its own and inside the outer's, so ``trace_s`` is
+    an upper estimate for nested programs."""
     with _mu:
         return dict(_stats)
 
@@ -159,6 +176,15 @@ def _on_jax_event(name, **kw):
         telemetry.note_aot_cache("misses", tier="xla")
 
 
+def _on_jax_duration(name, secs, **kw):
+    """Sum jax's compile-stage durations into :func:`stats` (no gate: four
+    float additions per compiled program)."""
+    key = _DURATION_KEYS.get(name)
+    if key is not None:
+        with _mu:
+            _stats[key] += secs
+
+
 def _exec_dir():
     return os.path.join(cache_dir(), "exec")
 
@@ -188,7 +214,8 @@ def _platform_hint():
 
 def place_jax_cache():
     """Decide where JAX's persistent compilation cache lives (module
-    docstring) and count its hit/miss events.  MUST run before the first
+    docstring), count its hit/miss events and sum jax's compile-stage
+    durations.  MUST run before the first
     XLA compile — jax latches the cache directory at first use
     (``mxnet_tpu/__init__.py`` calls this at import) — and must itself not
     trigger backend init, hence :func:`_platform_hint`.  Idempotent."""
@@ -205,6 +232,7 @@ def place_jax_cache():
         from jax._src import monitoring
 
         monitoring.register_event_listener(_on_jax_event)
+        monitoring.register_event_duration_secs_listener(_on_jax_duration)
         _listener_registered = True
 
 

@@ -304,22 +304,15 @@ class Executor:
         # the Module fused stepper keeps it across re-binds, and an
         # executor reference would pin the old buffers after reshape
         head_names = list(heads)
-        # compile plane (ISSUE 13): under MXNET_COSTPLANE each node's ops
-        # trace inside jax.named_scope(node.name), so profiler traces and
-        # HLO metadata attribute device time back to symbolic node names.
-        # Snapshot at build: the scope is pure trace-time metadata (the
-        # jaxpr is unchanged, zero retraces — tested), and with the gate
-        # off the eval loop below is byte-identical to a scopeless build.
-        from .telemetry import costplane
+        # each node's ops trace inside jax.named_scope(node.name), so a
+        # profiler trace (the xplane's tf_op stat) and the HLO's op_name
+        # attribute device time back to symbolic node names.  Pure
+        # trace-time metadata: the jaxpr is unchanged, zero retraces
+        # (tested), and JAX's persistent-cache key leaves it out.
+        import jax as _jax
 
-        if costplane.enabled():
-            import jax as _jax
-
-            def run_node(node, args, attrs):
-                with _jax.named_scope(node.name):
-                    return node.op.fn(*args, **attrs)
-        else:
-            def run_node(node, args, attrs):
+        def run_node(node, args, attrs):
+            with _jax.named_scope(node.name):
                 return node.op.fn(*args, **attrs)
 
         def fn(arg_vals, aux_vals, key):  # mxlint: traced
@@ -491,9 +484,9 @@ class Executor:
             # train-step dispatch accounting (ISSUE 3 regression surface):
             # counted here at the dispatch site so manual loops and
             # BucketingModule report the same 2+P as Module.forward_backward
-            from . import telemetry
+            from .telemetry import tracing
 
-            telemetry.note_dispatch(1, path="legacy")
+            tracing.count("dispatch", path="legacy")
         if _pt0 is not None:
             # duration = trace+enqueue (async dispatch), same caveat as the
             # eager per-op events; the XLA device timeline is use_xla_trace
@@ -574,9 +567,9 @@ class Executor:
                 tgt._rebind(tgt._data + g)
             else:
                 tgt._rebind(g)
-        from . import telemetry
+        from .telemetry import tracing
 
-        telemetry.note_dispatch(1, path="legacy")
+        tracing.count("dispatch", path="legacy")
         if _pt0 is not None:
             _prof._emit_op("Executor::Backward", _pt0,
                            _prof._now_us() - _pt0)

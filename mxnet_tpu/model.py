@@ -90,7 +90,6 @@ def _initialize_kvstore(kvstore, param_arrays, arg_params, param_names, update_o
 
 def _update_params_on_kvstore(param_arrays, grad_arrays, kvstore, param_names):
     """Reference ``model.py:145`` — push grads, pull updated weights."""
-    from . import telemetry
     from .telemetry import tracing
 
     with tracing.span("optimizer_update", path="kvstore",
@@ -104,12 +103,11 @@ def _update_params_on_kvstore(param_arrays, grad_arrays, kvstore, param_names):
             kvstore.pull(name, arg_list, priority=-index)
             # the per-parameter dispatch storm the fused Module step removes
             # (ISSUE 3) — counted so bench/telemetry expose dispatches_per_step
-            telemetry.note_dispatch(1, path="legacy")
+            tracing.count("dispatch", path="legacy")
 
 
 def _update_params(param_arrays, grad_arrays, updater, num_device, kvstore=None, param_names=None):
     """Reference ``model.py:157+`` — kvstore aggregation + local updater."""
-    from . import telemetry
     from .telemetry import tracing
 
     with tracing.span("optimizer_update", path="local",
@@ -128,7 +126,7 @@ def _update_params(param_arrays, grad_arrays, updater, num_device, kvstore=None,
             for k, (w, g) in enumerate(zip(arg_list, grad_list)):
                 # one updater state per device copy (reference uses index*num_device+k)
                 updater(index * num_device + k, g, w)
-                telemetry.note_dispatch(1, path="legacy")
+                tracing.count("dispatch", path="legacy")
 
 
 def save_checkpoint(prefix, epoch, symbol, arg_params, aux_params):

@@ -49,6 +49,7 @@ import numpy as np
 
 from .. import telemetry
 from ..base import MXNetError, env_flag
+from ..telemetry import tracing
 from ..ndarray.ndarray import NDArray, _wrap
 
 __all__ = ["FusedStepper", "fused_enabled", "fused_ineligible_reason",
@@ -220,16 +221,18 @@ def _build_step_fn(graph_fn, arg_names, diff_names, const_names, kind, hp,
         heads, vjp_fn, new_aux = jax.vjp(f, diff_vals, has_aux=True)
         (grads,) = vjp_fn([jnp.ones_like(h) for h in heads])
         new_params, new_state = [], []
-        for i, (w, g) in enumerate(zip(diff_vals, grads)):
-            st = tuple(opt_state[i])
-            # like sgd_rule: a parameter updates with momentum iff it HAS a
-            # momentum slot (created when the optimizer's momentum was set),
-            # so mid-run momentum edits behave exactly like the legacy path
-            k = ("sgd_mom" if st else "sgd") if kind == "sgd" else kind
-            new_w, new_st = fused_update(k, w, g, st,
-                                         lr=lr_vec[i], wd=wd_vec[i], **hp)
-            new_params.append(new_w)
-            new_state.append(list(new_st))
+        with jax.named_scope("optimizer"):
+            for i, (w, g) in enumerate(zip(diff_vals, grads)):
+                st = tuple(opt_state[i])
+                # like sgd_rule: a parameter updates with momentum iff it
+                # HAS a momentum slot (created when the optimizer's momentum
+                # was set), so mid-run momentum edits behave exactly like
+                # the legacy path
+                k = ("sgd_mom" if st else "sgd") if kind == "sgd" else kind
+                new_w, new_st = fused_update(k, w, g, st, lr=lr_vec[i],
+                                             wd=wd_vec[i], **hp)
+                new_params.append(new_w)
+                new_state.append(list(new_st))
         out = (new_params, new_state, new_aux, heads, grads)
         if nancheck:
             finite = jnp.bool_(True)
@@ -551,83 +554,95 @@ class FusedStepper:
         """Dispatch ONE fused step over the feed already staged in the
         executor's arg buffers, then commit params / optimizer state / aux /
         outputs / grads.  Consumes exactly one RNG key (like the legacy
-        forward), so seeded runs stay reproducible across paths."""
+        forward), so seeded runs stay reproducible across paths.
+
+        Three child spans of the caller's ``update`` split the host's time
+        (telemetry/tracing.py; a profiler session or ``MXNET_TRACE``):
+        ``fused.prepare`` everything before the launch, ``fused.dispatch``
+        the jitted call alone until it returns, ``fused.commit`` rebinding
+        what came back."""
         from .. import random as _rnd
 
-        exec_ = module._exec
-        opt = self._opt
-        updater = module._updater
-        diff_vals = [exec_.arg_dict[n]._data for n in self._diff_names]
-        grads_in = [exec_.grad_dict[n]._data for n in self._diff_names]
-        const_vals = [exec_.arg_dict[n]._data for n in self._const_names]
-        aux_vals = [exec_.aux_dict[n]._data for n in self._aux_names]
-        states, leaves = [], []
-        for i, n in enumerate(self._diff_names):
-            if i not in updater.states:
-                updater.states[i] = opt.create_state(i, exec_.arg_dict[n])
-                updater.states_synced[i] = True
-            states.append(updater.states[i])
-            leaves.append(_state_leaves(updater.states[i]))
-        if self._mesh is not None:
-            # commit every donated operand to its pinned layout (params/aux
-            # replicated over the mesh, grads + opt state per _shard_spec —
-            # 1/dp shards in ZeRO-1 mode).  Only the FIRST step actually
-            # moves bytes; afterwards the step's out_shardings return
-            # buffers already in layout and _place is a sharding == check.
-            # The batch feed itself is already dp-sharded by _stage_batch.
-            if self._shardings is None:
-                self._shardings = (
-                    self._repl(),
-                    [self._shard_spec(v) for v in diff_vals],
-                    [[self._shard_spec(v) for v in lv] for lv in leaves])
-            repl, grad_sh, state_sh = self._shardings
-            diff_vals = [self._place(v, repl) for v in diff_vals]
-            aux_vals = [self._place(v, repl) for v in aux_vals]
-            grads_in = [self._place(g, s)
-                        for g, s in zip(grads_in, grad_sh)]
-            leaves = [[self._place(v, s) for v, s in zip(lv, shl)]
-                      for lv, shl in zip(leaves, state_sh)]
-        self._ensure_jit(diff_vals, leaves)
-        # host-side hyperparam prep, O(P) python and zero dispatches: update
-        # counts first (the legacy Updater order), then read lr/wd through
-        # the optimizer's scheduler/multiplier logic; adam's bias correction
-        # folds into lr so the in-graph kernel stays schedule-free
-        for i in range(len(self._diff_names)):
-            opt._update_count(i)
-        lrs, wds = [], []
-        from ..ops.optimizer_ops import adam_bias_corrected_lr
+        with tracing.span("fused.prepare"):
+            exec_ = module._exec
+            opt = self._opt
+            updater = module._updater
+            diff_vals = [exec_.arg_dict[n]._data for n in self._diff_names]
+            grads_in = [exec_.grad_dict[n]._data for n in self._diff_names]
+            const_vals = [exec_.arg_dict[n]._data for n in self._const_names]
+            aux_vals = [exec_.aux_dict[n]._data for n in self._aux_names]
+            states, leaves = [], []
+            for i, n in enumerate(self._diff_names):
+                if i not in updater.states:
+                    updater.states[i] = opt.create_state(i, exec_.arg_dict[n])
+                    updater.states_synced[i] = True
+                states.append(updater.states[i])
+                leaves.append(_state_leaves(updater.states[i]))
+            if self._mesh is not None:
+                # commit every donated operand to its pinned layout
+                # (params/aux replicated over the mesh, grads + opt state
+                # per _shard_spec — 1/dp shards in ZeRO-1 mode).  Only the
+                # FIRST step actually moves bytes; afterwards the step's
+                # out_shardings return buffers already in layout and _place
+                # is a sharding == check.  The batch feed itself is already
+                # dp-sharded by _stage_batch.
+                if self._shardings is None:
+                    self._shardings = (
+                        self._repl(),
+                        [self._shard_spec(v) for v in diff_vals],
+                        [[self._shard_spec(v) for v in lv] for lv in leaves])
+                repl, grad_sh, state_sh = self._shardings
+                diff_vals = [self._place(v, repl) for v in diff_vals]
+                aux_vals = [self._place(v, repl) for v in aux_vals]
+                grads_in = [self._place(g, s)
+                            for g, s in zip(grads_in, grad_sh)]
+                leaves = [[self._place(v, s) for v, s in zip(lv, shl)]
+                          for lv, shl in zip(leaves, state_sh)]
+            self._ensure_jit(diff_vals, leaves)
+            # host-side hyperparam prep, O(P) python and zero dispatches:
+            # update counts first (the legacy Updater order), then read
+            # lr/wd through the optimizer's scheduler/multiplier logic;
+            # adam's bias correction folds into lr so the in-graph kernel
+            # stays schedule-free
+            for i in range(len(self._diff_names)):
+                opt._update_count(i)
+            lrs, wds = [], []
+            from ..ops.optimizer_ops import adam_bias_corrected_lr
 
-        for i in range(len(self._diff_names)):
-            lr, wd = opt._get_lr(i), opt._get_wd(i)
-            if self._kind == "adam":
-                lr = adam_bias_corrected_lr(lr, opt._index_update_count[i],
-                                            opt.beta1, opt.beta2)
-            lrs.append(lr)
-            wds.append(wd)
-        key = _rnd.next_key()
-        if self._nancheck:
-            self.check_nonfinite()
-        out = self._step(
-            diff_vals, grads_in, leaves, aux_vals, const_vals, key,
-            np.asarray(lrs, np.float32), np.asarray(wds, np.float32))
-        new_params, new_state, new_aux, heads, grads = out[:5]
-        extra = list(out[5:])
-        self._nsteps += 1
-        if self._nancheck:
-            self._pending_flag = (extra.pop(0), self._nsteps)
-        if self._health_groups is not None:
-            # device arrays, NOT read here (that would add the per-step
-            # sync the in-graph fold avoids): the fit loop drains them
-            # after its metric read has already synced this dispatch
-            self._last_health = (self._nsteps, extra.pop(0))
-        for n, v in zip(self._diff_names, new_params):
-            exec_.arg_dict[n]._rebind(v)
-        for n, g in zip(self._diff_names, grads):
-            exec_.grad_dict[n]._rebind(g)
-        for n, v in zip(self._aux_names, new_aux):
-            exec_.aux_dict[n]._rebind(v)
-        for st, new_leaves in zip(states, new_state):
-            _commit_state(st, new_leaves)
-        exec_.outputs = [_wrap(h) for h in heads]
-        exec_._last_key = key
-        exec_._last_is_train = True
+            for i in range(len(self._diff_names)):
+                lr, wd = opt._get_lr(i), opt._get_wd(i)
+                if self._kind == "adam":
+                    lr = adam_bias_corrected_lr(lr, opt._index_update_count[i],
+                                                opt.beta1, opt.beta2)
+                lrs.append(lr)
+                wds.append(wd)
+            lrs = np.asarray(lrs, np.float32)
+            wds = np.asarray(wds, np.float32)
+            key = _rnd.next_key()
+            if self._nancheck:
+                self.check_nonfinite()
+        with tracing.span("fused.dispatch"):
+            out = self._step(diff_vals, grads_in, leaves, aux_vals,
+                             const_vals, key, lrs, wds)
+        with tracing.span("fused.commit"):
+            new_params, new_state, new_aux, heads, grads = out[:5]
+            extra = list(out[5:])
+            self._nsteps += 1
+            if self._nancheck:
+                self._pending_flag = (extra.pop(0), self._nsteps)
+            if self._health_groups is not None:
+                # device arrays, NOT read here (that would add the per-step
+                # sync the in-graph fold avoids): the fit loop drains them
+                # after its metric read has already synced this dispatch
+                self._last_health = (self._nsteps, extra.pop(0))
+            for n, v in zip(self._diff_names, new_params):
+                exec_.arg_dict[n]._rebind(v)
+            for n, g in zip(self._diff_names, grads):
+                exec_.grad_dict[n]._rebind(g)
+            for n, v in zip(self._aux_names, new_aux):
+                exec_.aux_dict[n]._rebind(v)
+            for st, new_leaves in zip(states, new_state):
+                _commit_state(st, new_leaves)
+            exec_.outputs = [_wrap(h) for h in heads]
+            exec_._last_key = key
+            exec_._last_is_train = True

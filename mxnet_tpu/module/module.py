@@ -549,13 +549,13 @@ class Module(BaseModule):
                 if self._fused is None:
                     self._fused = FusedStepper(self)
                 self._fused.run(self)
+                tracing.count("dispatch", path=span_kw["path"])
             if self._stat_monitor is not None \
                     and getattr(self._stat_monitor, "activated", False):
                 # in-graph monitor route (install_monitor): feed this
                 # step's stats rows, pattern-filtered by the monitor
                 self._fused.feed_monitor(self._stat_monitor)
             telemetry.note_train_step(span_kw["path"])
-            telemetry.note_dispatch(1, path=span_kw["path"])
             return
         telemetry.note_train_step("legacy")
         if env_flag("MXNET_NANCHECK"):

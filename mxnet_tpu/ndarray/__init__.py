@@ -14,9 +14,13 @@ import types
 
 import numpy as np
 
+from jax import named_scope as _named_scope
+from jax.core import Tracer as _Tracer
+
 from ..base import parse_attr, dtype_np
 from ..context import current_context, Context
 from .. import profiler as _prof
+from ..telemetry import tracing as _tracing
 from ..ops import registry as _registry
 from ..ops import _load_all  # noqa: F401  (populates the registry)
 from .ndarray import NDArray, array, empty, concatenate, waitall, _wrap, _to_device
@@ -51,6 +55,13 @@ _VISIBLE_RULES = {
 }
 
 
+def _op_name(fn):
+    """The registered operator's name (``fn.op`` is set by ops.registry),
+    else the function's own."""
+    op = getattr(fn, "op", None)
+    return op.name if op is not None else getattr(fn, "__name__", "op")
+
+
 def _tape_if_recording(fn, nd_inputs, jargs, attrs, nd_outputs):
     from .. import autograd
 
@@ -62,19 +73,31 @@ def _invoke_raw(fn, nd_args, attrs, visible=None, ctx=None):
     """Execute a pure fn on NDArray args: unwrap → run → wrap → tape."""
     jargs = []
     nd_inputs = []
+    traced = False
     for a in nd_args:
         if isinstance(a, NDArray):
-            jargs.append(a._data)
             nd_inputs.append(a)
+            a = a._data
         else:
-            jargs.append(a)
             nd_inputs.append(None)
-    if _prof._op_profiling_active():
-        t0 = _prof._now_us()
-        res = fn(*jargs, **attrs)
-        _prof._emit_op(getattr(fn, "__name__", "op"), t0, _prof._now_us() - t0)
+        jargs.append(a)
+        traced = traced or isinstance(a, _Tracer)
+    if traced:
+        # under jit (the Gluon-functional path): the operator's name goes
+        # into the HLO's op_name and the device trace's tf_op, so device
+        # time reads by operator.  Trace-time metadata only.
+        with _named_scope(_op_name(fn)):
+            res = fn(*jargs, **attrs)
     else:
-        res = fn(*jargs, **attrs)
+        # an eager operator is a program launched on the device
+        _tracing.count("dispatch")
+        if _prof._op_profiling_active():
+            t0 = _prof._now_us()
+            res = fn(*jargs, **attrs)
+            _prof._emit_op(getattr(fn, "__name__", "op"), t0,
+                           _prof._now_us() - t0)
+        else:
+            res = fn(*jargs, **attrs)
     multi = isinstance(res, tuple)
     outs = res if multi else (res,)
     if ctx is not None:

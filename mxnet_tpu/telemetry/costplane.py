@@ -16,9 +16,10 @@ measured cost features per program).
 
 Everything gates on ``MXNET_COSTPLANE`` (docs/ENV_VARS.md) with the PR
 1/4/10/12 zero-overhead contract: unset ⇒ every helper is a no-op behind
-one env read, jitted programs lower byte-identically (no ``named_scope``
-wrapping, no AOT split), AOT-cache keys are untouched, and no ledger I/O
-happens (tested in tests/test_costplane.py).
+one env read, jits stay plain (no AOT split), AOT-cache keys are untouched,
+and no ledger I/O happens (tested in tests/test_costplane.py).  The
+executor's per-node ``named_scope``s are unconditional (``executor.py``
+``run_node``) and no part of this plane.
 
 Surfaces, gate on:
 

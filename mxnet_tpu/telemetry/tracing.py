@@ -8,19 +8,31 @@ stamped with the trace id so a 504-reaped request or a slow fused step is
 visible as a causal timeline even when its lifecycle crosses threads
 (serving ``submit`` → device loop).
 
-Design, mirroring ``telemetry.instrument``'s gating contract:
+Design:
 
-- everything gates on ``MXNET_TRACE`` (docs/ENV_VARS.md): unset/0 means
-  ``start_trace``/``span`` return the shared ``NULL_SPAN`` singleton — no
-  tracer object, no buffer, no file, zero added work on the hot path
-  (tested like the ``test_noop_guard_*`` family);
+- the switch is the profiler session: a span is recorded while a
+  ``jax.profiler`` trace is being taken (``TraceAnnotation.is_enabled()``,
+  checked first: tens of nanoseconds) or ``MXNET_TRACE`` is set
+  (docs/ENV_VARS.md, a span-only run).  Neither ⇒ ``start_trace``/``span``
+  return the shared ``NULL_SPAN`` singleton — no tracer object, no buffer,
+  no file (tested like the ``test_noop_guard_*`` family);
+- while a session is live a span entered as a context manager also opens a
+  ``jax.profiler.TraceAnnotation(name, trace=<id>, **attrs)``: it lands on
+  the xplane's host plane, on the clock of the device's ``XLA Ops`` lines,
+  nested by containment in whatever annotation the caller holds.  Attrs
+  and ``count()``ers that change inside the span are appended when it
+  closes.  Spans closed by an explicit ``finish()`` (they may end on
+  another thread) stay in the ring only;
 - sampling is per trace root: ``MXNET_TRACE_SAMPLE`` (0..1) keeps that
   fraction of traces via deterministic systematic sampling, and an
   unsampled root propagates nothing — child ``span()`` calls under it are
-  ``NULL_SPAN`` too;
+  ``NULL_SPAN`` too.  Under a live session every root is kept;
 - finished spans land in a bounded in-memory ring (``MXNET_TRACE_BUFFER``
   spans, oldest evicted) — tracing a long run can never grow memory without
-  limit;
+  limit; ``snapshot()`` is what an in-process reader (the benchmark's
+  per-layer metrics) reads;
+- ``count(name)`` adds to a counter attr of the innermost span, so a sum
+  over one root says how many happened in that step and under which span;
 - ``export()`` writes Chrome-trace/Perfetto JSON: ``ph:"X"`` duration
   events plus ``ph:"s"``/``ph:"f"`` flow events linking a trace's spans
   across threads, thread-name metadata, and a ``clock_sync`` record
@@ -53,12 +65,16 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from ..base import env_flag
 from ..profiler import _now_us  # shared host timebase with mx.profiler
+from .instrument import note_dispatch
 
-__all__ = ["enabled", "sample_rate", "trace_path", "buffer_cap",
-           "SpanContext", "Span", "NULL_SPAN", "Tracer", "tracer",
-           "start_trace", "span", "current", "export"]
+__all__ = ["enabled", "session_live", "sample_rate", "trace_path",
+           "buffer_cap", "SpanContext", "Span", "NULL_SPAN", "Tracer",
+           "tracer", "start_trace", "span", "current", "count", "snapshot",
+           "export"]
 
 _PID = 0                 # all host spans share one chrome-trace process
 _LANE_BASE = 10_000_000  # synthetic per-trace track ids (lane=True spans)
@@ -67,9 +83,13 @@ _tls = threading.local()
 
 
 # -- gates (read per call, like telemetry.instrument) -------------------------
+session_live = _Annotation.is_enabled  # a jax.profiler trace is being taken
+
+
 def enabled():
-    """``MXNET_TRACE`` gate (base.env_flag falsy-string rule)."""
-    return env_flag("MXNET_TRACE")
+    """A profiler session is live, or ``MXNET_TRACE`` is set
+    (base.env_flag falsy-string rule)."""
+    return session_live() or env_flag("MXNET_TRACE")
 
 
 def sample_rate():
@@ -126,7 +146,8 @@ class Span:
     paths and dispatch paths may race to close a request span."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "t0", "dur", "tid", "thread_name", "_tracer", "_ctx")
+                 "t0", "dur", "tid", "thread_name", "_tracer", "_ctx",
+                 "_ann")
 
     def __init__(self, tracer, name, trace_id, parent_id=None, lane=False,
                  attrs=None):
@@ -145,6 +166,7 @@ class Span:
             self.tid = threading.get_ident() % 1_000_000
             self.thread_name = threading.current_thread().name
         self._ctx = None
+        self._ann = None  # (TraceAnnotation, attrs as it was opened with)
 
     def __bool__(self):
         return True
@@ -178,9 +200,21 @@ class Span:
         if stack is None:
             stack = _tls.stack = []
         stack.append(self)
+        if session_live():
+            entered = dict(self.attrs)
+            ann = _Annotation(self.name, trace=self.trace_id, **entered)
+            ann.__enter__()
+            self._ann = (ann, entered)
         return self
 
     def __exit__(self, *exc):
+        if self._ann is not None:
+            (ann, entered), self._ann = self._ann, None
+            late = {k: v for k, v in self.attrs.items()
+                    if k not in entered or entered[k] != v}
+            if late:
+                ann.set_metadata(**late)
+            ann.__exit__(*exc)
         stack = getattr(_tls, "stack", None)
         if stack:
             if stack[-1] is self:
@@ -359,7 +393,7 @@ def tracer():
     with _mu:
         if _tracer is None:
             _tracer = Tracer()
-        if enabled() and not _atexit_registered:
+        if env_flag("MXNET_TRACE") and not _atexit_registered:
             atexit.register(_exit_export)
             _atexit_registered = True
         return _tracer
@@ -368,7 +402,7 @@ def tracer():
 def _exit_export():
     with _mu:
         t = _tracer
-    if t is not None and t._spans and enabled():
+    if t is not None and t._spans and env_flag("MXNET_TRACE"):
         try:
             t.export()
         except Exception:  # interpreter teardown: never mask the real exit
@@ -384,21 +418,55 @@ def _reset_for_tests():
 
 # -- hot-path API -------------------------------------------------------------
 def start_trace(name, lane=False, **attrs):
-    """Begin a new sampled trace → its root Span, or NULL_SPAN when tracing
-    is off or this root is sampled out.  One env lookup on the off path."""
-    if not enabled():
+    """Begin a new trace → its root Span, or NULL_SPAN when tracing is off
+    or this root is sampled out (a root under a live profiler session never
+    is).  Off path: the session check and one env lookup."""
+    if session_live():
+        t = tracer()
+        return Span(t, name, t._new_id(), None, lane=lane, attrs=attrs)
+    if not env_flag("MXNET_TRACE"):
         return NULL_SPAN
     return tracer().start_trace(name, lane=lane, **attrs)
 
 
 def span(name, parent=None, lane=False, **attrs):
     """Child span under ``parent`` (or the thread-local current span);
-    NULL_SPAN when tracing is off or no sampled trace is active here."""
-    if not enabled():
-        return NULL_SPAN
+    NULL_SPAN when no trace is active here or tracing is off."""
     if parent is None and current() is None:
         return NULL_SPAN
+    if not enabled():
+        return NULL_SPAN
     return tracer().span(name, parent=parent, lane=lane, **attrs)
+
+
+def count(name, n=1, path=None):
+    """Add ``n`` to the counter attr ``name`` of the innermost span on this
+    thread; with no span entered, nothing.  ``count("dispatch")`` sits at
+    every place that launches a program on the device, so the sum over one
+    ``step`` root is that step's dispatches and the span that holds each
+    says where it fell.  The train-step launch sites pass their ``path``
+    ("fused", "fused_mesh", "legacy"): those also feed the registry's
+    ``step_dispatches_total{path}`` when ``MXNET_TELEMETRY`` is on."""
+    sp = current()
+    if sp is not None:
+        sp.attrs[name] = sp.attrs.get(name, 0) + n
+    if path is not None:
+        note_dispatch(n, path=path)
+
+
+def snapshot():
+    """The finished spans still in the ring, oldest first, as dicts:
+    ``name``, ``trace`` (id shared by one root and all under it), ``span``,
+    ``parent`` (span id or None), ``start_us`` (``mx.profiler``'s epoch),
+    ``dur_us``, ``attrs`` (counters among them).  [] when nothing was ever
+    traced."""
+    with _mu:
+        t = _tracer
+    if t is None:
+        return []
+    return [{"name": s.name, "trace": s.trace_id, "span": s.span_id,
+             "parent": s.parent_id, "start_us": s.t0, "dur_us": s.dur,
+             "attrs": dict(s.attrs)} for s in list(t._spans)]
 
 
 def export(path=None, reset=True):

@@ -4,7 +4,10 @@ context propagation with flow events, the serving request lifecycle
 (queue/assemble/execute across submit and device-loop threads, drop
 reasons), fit-loop step/data_wait spans, kvstore/Predictor spans, the
 exporter's chrome-trace invariants (ci/check_trace.py), and the
-trace_merge clock rebase."""
+trace_merge clock rebase.  ISSUE 26: the switch is the profiler session
+(spans land on the xplane's host plane as TraceAnnotations), the spans
+inside FusedStepper.run, ``count("dispatch")``, ``snapshot()``, operator
+scopes in the lowered program, and compile_cache's stage durations."""
 import json
 import os
 import threading
@@ -314,6 +317,201 @@ class TestTrainingTrace:
             kv.pull("w", out=out)
         xs = {e["name"] for e in _spans(_export_events(tmp_path / "kv.json"))}
         assert {"kv_push", "kv_pull", "step"} <= xs
+
+
+# -- the profiler session as the switch (ISSUE 26) ----------------------------
+FUSED = ("fused.prepare", "fused.dispatch", "fused.commit")
+
+
+def _host_events(trace_dir, names):
+    """[(name, start_ns, duration_ns)] of the xplane's host-plane events
+    whose name is in ``names``."""
+    import glob
+
+    import jax
+
+    (path,) = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    (host,) = [pl for pl in data.planes if pl.name == "/host:CPU"]
+    return [(e.name, e.start_ns, e.duration_ns) for line in host.lines
+            for e in line.events if e.name in names]
+
+
+def _inside(inner, outers):
+    return any(o[1] <= inner[1] and inner[1] + inner[2] <= o[1] + o[2]
+               for o in outers)
+
+
+class TestProfilerSession:
+    def test_fit_spans_land_on_the_xplanes_host_plane(self, tr_disabled,
+                                                       tmp_path):
+        """No MXNET_TRACE: a live jax.profiler session alone records the
+        spans, and writes each as a TraceAnnotation into the trace the
+        device's lines are in — nested by containment, not by an offset."""
+        import jax
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            TestTrainingTrace()._fit(batches=3)
+        finally:
+            jax.profiler.stop_trace()
+        snap = tracing.snapshot()
+        evs = _host_events(tmp_path, ("step", "update") + FUSED)
+        by = {n: [e for e in evs if e[0] == n] for n in ("step", "update")
+              + FUSED}
+        assert [len(by[n]) for n in by] == [3] * 5
+        assert all(_inside(u, by["step"]) for u in by["update"])
+        for n in FUSED:
+            assert all(_inside(e, by["update"]) for e in by[n])
+            ring = sorted(s["dur_us"] for s in snap if s["name"] == n)
+            plane = sorted(e[2] / 1e3 for e in by[n])
+            # the annotation opens after the span's clock starts and closes
+            # before it stops
+            assert all(0 <= r - p < 5e3 for r, p in zip(ring, plane)), \
+                (n, ring, plane)
+        updates = {s["span"]: s for s in snap if s["name"] == "update"}
+        assert all(s["parent"] in updates for s in snap if s["name"] in FUSED)
+        # prepare, dispatch, commit tile update: nothing else of size in it
+        for u in updates.values():
+            parts = sum(s["dur_us"] for s in snap if s["parent"] == u["span"])
+            assert parts <= u["dur_us"]
+
+    def test_roots_under_a_session_are_never_sampled_out(self, tr_disabled,
+                                                         monkeypatch,
+                                                         tmp_path):
+        import jax
+
+        monkeypatch.setenv("MXNET_TRACE_SAMPLE", "0")
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            roots = [tracing.start_trace("step", step=i) for i in range(4)]
+            for r in roots:
+                with r:
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        assert all(roots)
+        assert len(tracing.snapshot()) == 4
+        assert tracing.start_trace("step") is tracing.NULL_SPAN  # session over
+
+    def test_fit_with_no_session_and_no_env_creates_no_ring(self,
+                                                            tr_disabled):
+        TestTrainingTrace()._fit()
+        mx.nd.ones((2,)) + 1        # an eager operator: count() finds no span
+        tracing.count("dispatch")
+        assert tracing._tracer is None
+        assert tracing.snapshot() == []
+        assert tracing.current() is None
+
+    def test_dispatch_counts_fall_under_the_span_that_launched(self,
+                                                               tr_enabled):
+        TestTrainingTrace()._fit()
+        snap = tracing.snapshot()
+        steps = [s for s in snap if s["name"] == "step"]
+        assert len(steps) == 2
+        for st in steps:
+            mine = [s for s in snap if s["trace"] == st["trace"]]
+            (upd,) = [s for s in mine if s["name"] == "update"]
+            assert upd["attrs"]["dispatch"] == 1  # the one fused program
+            assert sum(s["attrs"].get("dispatch", 0) for s in mine) >= 1
+        with tracing.start_trace("eager") as root:
+            x = mx.nd.ones((2, 2))
+            (x + x).wait_to_read()
+            assert root.attrs["dispatch"] == 1
+            import jax
+
+            jax.jit(lambda a: (mx.nd.NDArray(a) * 2)._data)(x._data)
+            assert root.attrs["dispatch"] == 1  # traced, not launched
+
+
+def _lowered_gluon():
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.gluon import nn
+    from mxnet_tpu.gluon.functional import functionalize
+
+    net = nn.HybridSequential()
+    net.add(nn.Dense(4, activation="relu"))
+    net.initialize()
+    net(mx.nd.ones((2, 8)))
+    apply, _, vals, _ = functionalize(net)
+    return jax.jit(lambda v, x: apply(v, x, jax.random.PRNGKey(0))[0]).lower(
+        vals, jnp.ones((2, 8))), ("FullyConnected", "Activation")
+
+
+def _lowered_symbol():
+    import jax
+
+    fc = mx.sym.FullyConnected(mx.sym.var("data"), name="fc1", num_hidden=8)
+    exe = mx.sym.Activation(fc, name="act1", act_type="relu").simple_bind(
+        data=(2, 8), grad_req="null")
+    return jax.jit(exe._graph_fn(False)).lower(
+        *exe._aot_example_args()), ("fc1", "act1")
+
+
+@pytest.mark.parametrize("lower", [_lowered_gluon, _lowered_symbol])
+def test_operator_scopes_reach_the_lowered_op_name(lower, monkeypatch):
+    """Both front ends put their operator's name on every op they trace,
+    with no switch: the lowered module's locations and the compiled HLO's
+    ``op_name`` carry it, and so does the device trace's ``tf_op``."""
+    import re
+
+    monkeypatch.delenv("MXNET_COSTPLANE", raising=False)
+    lowered, names = lower()
+    locs = set(re.findall(r'loc\("(jit\([^"]+)"', lowered.as_text(
+        debug_info=True)))
+    op_names = set(re.findall(r'op_name="([^"]+)"',
+                              lowered.compile().as_text()))
+    for n in names:
+        assert any("/%s/" % n in loc for loc in locs), (n, sorted(locs))
+    assert any("/%s/" % names[0] in o for o in op_names), sorted(op_names)
+
+
+def test_compile_cache_stats_sum_jaxs_stage_durations(tmp_path):
+    """trace_s / lower_s / backend_s grow on a fresh compile; on a load from
+    the persistent cache backend_s and cache_load_s grow and nothing is
+    compiled."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from mxnet_tpu import compile_cache
+
+    def f(x):
+        return jnp.tanh(x) @ x + 26.0
+
+    def grown(after, before):
+        return {k for k in ("trace_s", "lower_s", "backend_s", "cache_load_s")
+                if after[k] > before[k]}
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    old = [getattr(jax.config, k) for k in keys]
+    try:
+        for k, v in zip(keys, (str(tmp_path), 0.0, -1)):
+            jax.config.update(k, v)
+        cc.reset_cache()
+        x = jnp.ones((8, 8))
+        s0 = compile_cache.stats()
+        jax.jit(f)(x).block_until_ready()
+        s1 = compile_cache.stats()
+        assert grown(s1, s0) == {"trace_s", "lower_s", "backend_s"}
+        assert s1["xla_misses"] > s0["xla_misses"]
+        jax.clear_caches()
+        jax.jit(f)(x).block_until_ready()
+        s2 = compile_cache.stats()
+        assert {"backend_s", "cache_load_s"} <= grown(s2, s1)
+        assert s2["xla_hits"] > s1["xla_hits"]
+        assert s2["xla_misses"] == s1["xla_misses"]
+        assert s2["cache_load_s"] - s1["cache_load_s"] <= \
+            s2["backend_s"] - s1["backend_s"]
+    finally:
+        for k, v in zip(keys, old):
+            jax.config.update(k, v)
+        cc.reset_cache()
 
 
 # -- exporter invariants / tools ----------------------------------------------
