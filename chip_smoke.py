@@ -290,65 +290,93 @@ def nms_leg(boxes=6000, batch=2, interpret=False):
     return facts
 
 
-def dconv_leg(bg=1, channels=128, hw=(38, 64), interpret=False):
-    """``dconv_col_pallas`` forward and all four gradients, bf16 features,
-    against the dense one-hot matmul it replaces (9 taps per position; the
-    defaults are one (image, group) of north-star res5)."""
+# the leg's three draws of deformable samples: offsets below one cell (the
+# benchmark's seeded offset branches, and how every fine-tune starts), up to
+# three cells (trained res5 offsets), and uniform over the map (no detector
+# sends it: every row block's band is the whole map, the kernels' worst case)
+DCONV_REGIMES = (("offsets<1", 1.0), ("offsets<=3", 3.0), ("uniform", None))
+
+
+def dconv_leg(bg=32, channels=128, hw=(38, 64), interpret=False, calls=20):
+    """``dconv_col_pallas`` at north-star res5's shape, bf16 features, in
+    the three regimes of ``DCONV_REGIMES``: forward and all four gradients
+    against the dense one-hot matmul it replaces (on the first (image,
+    group): the dense A of all ``bg`` would not fit), then
+    ``dconv_band_share`` and the forward and the backward kernel's time for
+    one call over ``bg`` (image, group)s, 8 images of 4 groups by default
+    as in the benchmark's step."""
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.test_utils import (dconv_dense_reference,
+                                      dconv_sample_inputs)
 
-    rng = np.random.RandomState(0)
     H, W = hw
     N = 9 * H * W
-    sy = jnp.asarray(rng.uniform(0, H - 1, (bg, N)).astype(np.float32))
-    sx = jnp.asarray(rng.uniform(0, W - 1, (bg, N)).astype(np.float32))
-    y0 = jnp.floor(sy).astype(jnp.int32)
-    x0 = jnp.floor(sx).astype(jnp.int32)
-    y1 = jnp.minimum(y0 + 1, H - 1)
-    x1 = jnp.minimum(x0 + 1, W - 1)
-    ly, lx = sy - y0, sx - x0
-    lf = jnp.asarray((rng.rand(bg, N) > 0.1).astype(np.float32))
-    ft = jnp.asarray(rng.randn(bg, H * W, channels).astype(np.float32)
-                     ).astype(jnp.bfloat16)
-    cot = jnp.cos(jnp.arange(bg * N * channels, dtype=jnp.float32)
-                  ).reshape(bg, N, channels)
 
-    def dense(ly, lx, lf, ft):
-        hh = jnp.arange(H * W, dtype=jnp.int32) // W
-        ww = jnp.arange(H * W, dtype=jnp.int32) % W
-        a = (((1 - ly)[..., None] * (hh == y0[..., None])
-              + ly[..., None] * (hh == y1[..., None]))
-             * ((1 - lx)[..., None] * (ww == x0[..., None])
-                + lx[..., None] * (ww == x1[..., None]))
-             * lf[..., None])
-        return jnp.einsum("bnp,bpc->bnc", a.astype(ft.dtype), ft,
-                          preferred_element_type=jnp.float32).astype(ft.dtype)
+    def dense(*args):
+        return dconv_dense_reference(*args, hw)
 
-    def fused(ly, lx, lf, ft):
+    def first(arrays):
+        return [a[:1] for a in arrays]
+
+    def fused(y0, y1, x0, x1, ly, lx, lf, ft):
         return pk.dconv_col_pallas(y0, y1, x0, x1, ly, lx, lf, ft, (H, W),
                                    interpret)
 
-    def run(fn):
+    def values(fn, ints, flts, cot):
         def loss(*a):
-            return jnp.sum(fn(*a).astype(jnp.float32) * cot)
-        out = jax.jit(fn)(ly, lx, lf, ft)
-        grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(ly, lx, lf, ft)
+            return jnp.sum(fn(*ints, *a).astype(jnp.float32) * cot)
+        out = jax.jit(fn)(*ints, *flts)
+        grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(*flts)
         return [np.asarray(v.astype(jnp.float32)) for v in (out,) + grads]
 
-    errs = {}
-    for name, got, want in zip(("col", "d_ly", "d_lx", "d_lf", "d_ft"),
-                               run(fused), run(dense)):
-        scale = max(float(np.abs(want).max()), 1e-6)
-        errs[name] = float("%.3g" % (float(np.abs(got - want).max()) / scale))
-    # bf16 operands, f32 accumulation on both sides, but the dense path's AD
-    # rounds dA to bf16 where the kernel keeps it f32: allow 4 bf16 ulps
-    # (2^-6) of the largest element
-    if max(errs.values()) > 2.0 ** -6:
-        raise AssertionError("dconv kernel disagrees with the dense "
-                             "formulation: %r" % (errs,))
-    return {"dconv_rel_err": errs}
+    def per_call_ms(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t) / calls * 1e3, 3)
+
+    # the backward kernel alone: the forward's output is dead code here
+    backward = jax.jit(lambda ints, flts, g: jax.vjp(
+        lambda *a: fused(*ints, *a), *flts)[1](g))
+    forward = jax.jit(fused)
+
+    facts = {}
+    for name, offset in DCONV_REGIMES:
+        rng = np.random.RandomState(0)
+        rows = [jnp.asarray(a) for a in
+                dconv_sample_inputs(rng, bg, hw, offset)]
+        ft = jnp.asarray(rng.randn(bg, H * W, channels).astype(np.float32)
+                         ).astype(jnp.bfloat16)
+        ints, flts = rows[:4], rows[4:] + [ft]
+        cot = jnp.cos(jnp.arange(N * channels, dtype=jnp.float32)
+                      ).reshape(1, N, channels)
+        errs = {}
+        for out, got, want in zip(
+                ("col", "d_ly", "d_lx", "d_lf", "d_ft"),
+                values(fused, first(ints), first(flts), cot),
+                values(dense, first(ints), first(flts), cot)):
+            scale = max(float(np.abs(want).max()), 1e-6)
+            errs[out] = float("%.3g" % (float(np.abs(got - want).max())
+                                        / scale))
+        # bf16 operands, f32 accumulation on both sides, but the dense
+        # path's AD rounds dA to bf16 where the kernel keeps it f32: allow 4
+        # bf16 ulps (2^-6) of the largest element
+        if max(errs.values()) > 2.0 ** -6:
+            raise AssertionError("dconv kernel disagrees with the dense "
+                                 "formulation (%s): %r" % (name, errs))
+        g = jnp.broadcast_to(cot, (bg, N, channels)).astype(jnp.bfloat16)
+        facts[name] = {
+            "rel_err": errs,
+            "band_share": round(float(pk.dconv_band_share(
+                ints[0], ints[1], hw)), 4),
+            "fwd_ms": per_call_ms(forward, *ints, *flts),
+            "bwd_ms": per_call_ms(backward, ints, flts, g)}
+    return {"bg": bg, "dconv": facts}
 
 
 def module_fit_leg(num_layers=50, image=224, classes=1000, batch=32,

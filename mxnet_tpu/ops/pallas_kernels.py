@@ -192,6 +192,11 @@ def cost_psroi_abuild_bwd(n, s, h, w, g_itemsize=4):
 @register_cost("dconv_col_pallas_fwd",
                aliases=("dconv_col", "dconv_fwd_kernel"))
 def cost_dconv_col_fwd(bg, n, hw, c, ft_itemsize=4):
+    """FLOPs of the kernel at a band of the whole map, an UPPER BOUND since
+    PR 27: the kernel contracts each row block over the chunks its samples
+    touch, a share ``dconv_band_share`` of these (0.15 at the R-FCN cell's
+    offsets), and how many is data, not shape.  Bytes are the operator's own
+    and do not depend on the band."""
     # A build (~10 elementwise flops per A element) + col = A @ ft; A stays
     # in VMEM so its HW*N footprint never counts as bytes_accessed
     flops = 2 * bg * n * hw * c + 10 * bg * n * hw
@@ -203,8 +208,10 @@ def cost_dconv_col_fwd(bg, n, hw, c, ft_itemsize=4):
 
 @register_cost("dconv_col_pallas_bwd", aliases=("dconv_bwd_kernel",))
 def cost_dconv_col_bwd(bg, n, hw, c, ft_itemsize=4):
-    # dA = g @ ft^T and dft += A^T @ g (2 MXU dots) + three masked row
-    # reductions over dA (~12 flops per A element); dA also VMEM-resident
+    """As ``cost_dconv_col_fwd``: FLOPs at a band of the whole map, the
+    upper bound; bytes do not depend on the band."""
+    # dA = g @ ft^T and dft += A^T @ g (2 MXU dots) + the four masked corner
+    # sums over dA (~12 flops per A element); dA also VMEM-resident
     flops = 4 * bg * n * hw * c + 12 * bg * n * hw
     bytes_accessed = (7 * bg * n * 4
                       + bg * hw * c * ft_itemsize    # ft in
@@ -768,47 +775,87 @@ psroi_abuild_pallas.defvjp(_abuild_fwd, _abuild_bwd)
 # ---------------------------------------------------------------------------
 #
 # The deformable conv's one-hot path materializes, per (image, group), a
-# rank-1 sample matrix A[n, h*W+w] = yw[n,h]*xw[n,w] (bf16, ~106 MB at
-# north-star shapes) and feeds it to ``col = A @ feat``; AD then
-# materializes dA in f32 (~213 MB).  The round-5 batch-8 source-line
-# accounting put the whole sampling machinery at ~88 ms of a 227 ms step
-# — nearly all of it A/dA HBM traffic.  This kernel keeps A (and dA, in
-# the backward) entirely in VMEM: the one-hot factors are rebuilt per
-# block from the integer/lerp inputs with lane-iota compares (no gather,
-# no reshape), and the contraction runs as one MXU dot per block.
+# sample matrix A[n, p] over the flat feature axis p = h*W + w (bf16, ~106 MB
+# at north-star shapes) and feeds it to ``col = A @ feat``; AD then
+# materializes dA in f32 (~213 MB).  This kernel pair keeps A (and dA, in
+# the backward) in VMEM: the one-hot entries are rebuilt per block from the
+# integer/lerp inputs with iota compares (no gather, no reshape) and the
+# contraction runs on the MXU.
 #
-# Forward:  col[bg, n, c] = sum_p A[bg, n, p] * ft[bg, p, c]
-#   with A = [(1-ly)(hh==y0) + ly(hh==y1)] * [(1-lx)(ww==x0) + lx(ww==x1)] * lf
-#   where hh = p // W, ww = p % W.
-# Backward (custom VJP): dA = g @ ft^T stays in VMEM; d_ly/d_lx/d_lf are
-#   elementwise-masked row reductions of dA; d_ft accumulates A^T @ g
-#   across row blocks.
+# Each row of A has four non-zero entries, the bilinear corners, and the
+# rows arrive tap-major: one block of ``nblk`` consecutive rows is a few
+# output rows of one tap, so all its corners fall in a few feature rows.
+# The contraction is therefore BAND-LIMITED (PR 27): the flat axis is cut
+# into chunks of ``_DCONV_CHUNK`` positions, XLA computes per (bg, row
+# block) the first and last chunk any corner of the block falls in
+# (``_dconv_band``, from y0/y1), the two small int32 arrays reach the kernel
+# by scalar prefetch (SMEM), and the kernel contracts over ``lo..hi`` only,
+# ``_DCONV_STEP`` chunks a loop step.  The chunks left out hold exact zeros
+# of A, so nothing changes in the sums.  A block whose band is more than
+# half the map (large offsets, a block that straddles two taps) contracts
+# over all of it in one step, which is the dense form this replaced and
+# costs what that did (chip run, PR 27: PERF.md section 6).
+#
+# The block of A is built TRANSPOSED, positions on sublanes and samples on
+# lanes: the per-sample inputs are stored lane-dense, so comparing a sublane
+# iota against them needs no relayout, and the masked reductions of the
+# backward run down the sublanes (plain vector adds).
+#
+#   A_T[p, n] = sum over the corners k of  w_k[n] * (p == f_k[n])
+#     f_k = y*W + x of corner k,  w_k = its lerp weight times lf
+#     (corners that coincide at the last row / column add their weights)
+# Forward:   col^T = sum over the band's steps of  ft^T[:, step] @ A_T[step]
+#   (ft^T comes from XLA, col^T is transposed once per grid step)
+# Backward:  dA_T[step] = ft[step] @ g^T stays in VMEM; the four corner
+#   values of dA are masked column sums of it, and d_ly / d_lx / d_lf are
+#   their lerp combinations; d_ft[step] += A_T[step] @ g for the band's
+#   steps only (the accumulator is zeroed once per bg).
 
 _DCONV_NBLK = 128
+# the band's unit: positions of the flat feature axis per chunk (the 128
+# lanes), and the chunks one step of the kernels' loop contracts over.  The
+# loop starts at the band's first chunk, wherever that is, so a band of up
+# to ``_DCONV_STEP`` chunks is one step; the map is padded inside the
+# wrapper so that the last step of any band stays inside it.  3 is the
+# band of two output rows of a 64-wide map under offsets below one cell;
+# the chip's sweep of both constants is in PERF.md section 6 (PR 27)
+_DCONV_CHUNK = 128
+_DCONV_STEP = 3
 
 # Mosaic hard-fails when one grid step's working set exceeds its scoped VMEM
-# limit.  The estimate below intentionally OVERCOUNTS (it sums all six
-# factor planes as if simultaneously resident; Mosaic fuses several), and
-# the limit is a number that worked, not one the compiler is given: no
-# pallas_call here passes ``vmem_limit_bytes``.  On jaxlib 0.9.0 / libtpu
-# 0.0.34, TPU v5 lite (chip run, PR 21), north-star res5 (HW=2432, cpg=128:
-# 10.2 MB bf16) and cpg=512 (15.8 MB) compile and run — and so did a
-# conv4-scale map (HW=9728, cpg=64) scoring 36.9 MB.  So 24 MB is
-# conservative here: shapes between it and the real limit take the XLA scan
-# though the kernel would build (ROADMAP A2 ties guard and compiler to one
-# number).
+# limit.  The estimate below intentionally OVERCOUNTS (it sums seven planes
+# as if simultaneously resident; Mosaic fuses several), and the limit is a
+# number that worked, not one the compiler is given: no pallas_call here
+# passes ``vmem_limit_bytes``.  On jaxlib 0.9.0 / libtpu 0.0.34, TPU v5 lite
+# (chip run, PR 21), north-star res5 (HW=2432, cpg=128: 10.2 MB bf16) and
+# cpg=512 (15.8 MB) compile and run — and so did a conv4-scale map (HW=9728,
+# cpg=64) scoring 36.9 MB.  So 24 MB is conservative here: shapes between
+# it and the real limit take the XLA scan though the kernel would build
+# (ROADMAP A2c ties guard and compiler to one number).
 _DCONV_VMEM_LIMIT = 24 << 20
+
+
+def _dconv_chunks(HW):
+    return -(-HW // _DCONV_CHUNK)
+
+
+def _dconv_hw_pad(HW):
+    return (_dconv_chunks(HW) + _DCONV_STEP - 1) * _DCONV_CHUNK
 
 
 def dconv_bwd_vmem_bytes(HW, C, itemsize, nblk=_DCONV_NBLK):
     """Estimated per-grid-step VMEM working set of the dconv BACKWARD kernel
-    (the larger of the two passes): dA + the six one-hot/lerp factor planes
-    (f32, (nblk, HW) each), the ft block and the f32 dft accumulator
-    ((HW, C)), and the g block ((nblk, C)).  Drives the auto-branch guard in
-    ``detection.py deformable_convolution`` — above ``_DCONV_VMEM_LIMIT``
-    (override: MXNET_DCONV_VMEM_MB) large feature maps take the XLA scan
-    instead of risking a hard Mosaic failure (ADVICE round 5)."""
-    return (7 * 4 * nblk * HW          # dA + 6 factor planes, f32
+    (the larger of the two passes) at its widest, a band of the whole
+    (padded) map in one step: dA_T, A_T and the four corner masks with
+    their selects (seven f32 ``(HW, nblk)`` planes), the ft block and the
+    f32 dft accumulator (``(HW, C)``), and the g block (``(nblk, C)``).  A
+    narrow band needs ``_DCONV_STEP`` chunks of the planes only, but which
+    a block gets is data.  Drives the auto-branch guard in ``detection.py
+    deformable_convolution`` — above ``_DCONV_VMEM_LIMIT`` (override:
+    MXNET_DCONV_VMEM_MB) large feature maps take the XLA scan instead of
+    risking a hard Mosaic failure (ADVICE round 5)."""
+    HW = _dconv_hw_pad(HW)
+    return (7 * 4 * nblk * HW          # dA_T + A_T + masks and selects, f32
             + HW * C * (itemsize + 4)  # ft block + f32 dft accumulator
             + nblk * C * (itemsize + 4))  # g block + col block
 
@@ -820,21 +867,33 @@ def dconv_fits_vmem(HW, C, itemsize, nblk=_DCONV_NBLK):
     return dconv_bwd_vmem_bytes(HW, C, itemsize, nblk=nblk) <= _vmem_limit()
 
 
-def _dconv_factors(y0, y1, x0, x1, ly, lx, H, W):
-    """One-hot lerp factor planes over the flat p = h*W + w lane axis —
-    pure elementwise compares against lane iotas (no gather/reshape)."""
-    n = y0.shape[0]
-    HW = H * W
-    idx = jax.lax.broadcasted_iota(jnp.int32, (n, HW), 1)
-    hh = idx // W
-    ww = idx - hh * W
-    e0y = (hh == y0[:, None]).astype(jnp.float32)
-    e1y = (hh == y1[:, None]).astype(jnp.float32)
-    e0x = (ww == x0[:, None]).astype(jnp.float32)
-    e1x = (ww == x1[:, None]).astype(jnp.float32)
-    yfac = (1.0 - ly)[:, None] * e0y + ly[:, None] * e1y
-    xfac_nolf = (1.0 - lx)[:, None] * e0x + lx[:, None] * e1x
-    return yfac, xfac_nolf, e0y, e1y, e0x, e1x
+def _dconv_band(y0, y1, W, HW, nblk):
+    """First and last chunk of the flat feature axis that any corner of
+    each row block falls in: two int32 ``(BG, n_pad // nblk)`` arrays.
+    y0 / y1: ``(BG, n_pad)``, clipped to the map, padded rows repeating a
+    live row of their block (``_dconv_pad(..., "edge")``)."""
+    BG = y0.shape[0]
+    lo = y0.reshape(BG, -1, nblk).min(-1) * W // _DCONV_CHUNK
+    hi = (y1.reshape(BG, -1, nblk).max(-1) * W + W - 1) // _DCONV_CHUNK
+    # the kernels slice VMEM by these and nothing checks a slice there: a
+    # row outside the map must not move the band outside it
+    last = _dconv_chunks(HW) - 1
+    lo = jnp.clip(lo, 0, last)
+    return lo, jnp.clip(hi, lo, last)
+
+
+def dconv_band_share(y0, y1, hw, nblk=_DCONV_NBLK):
+    """Mean share of the flat feature axis that a row block of ``nblk``
+    samples contracts over, ``(hi - lo + 1) / n_chunks`` of ``_dconv_band``:
+    1.0 means the band saved nothing.  A device value; read it outside the
+    step (``chip_smoke.py``), never from inside it."""
+    H, W = hw
+    N = y0.shape[1]
+    nblk = min(nblk, N)
+    n_pad = -(-N // nblk) * nblk
+    lo, hi = _dconv_band(_dconv_pad(y0, n_pad, "edge")[:, 0],
+                         _dconv_pad(y1, n_pad, "edge")[:, 0], W, H * W, nblk)
+    return jnp.mean((hi - lo + 1) / _dconv_chunks(H * W))
 
 
 def _dconv_prec(dot_dtype):
@@ -845,68 +904,121 @@ def _dconv_prec(dot_dtype):
             if jnp.dtype(dot_dtype) == jnp.float32 else None)
 
 
-def _dconv_fwd_kernel_factory(H, W, nblk, dot_dtype):
-    def kern(y0_ref, y1_ref, x0_ref, x1_ref, ly_ref, lx_ref, lf_ref,
-             ft_ref, col_ref):
+def _dconv_corners(y0, y1, x0, x1, ly, lx, lf, W):
+    """Flat index and weight of the four bilinear corners, in the inputs'
+    own (lane-dense) layout; weights in the order 00, 01, 10, 11."""
+    f = (y0 * W + x0, y0 * W + x1, y1 * W + x0, y1 * W + x1)
+    w = ((1.0 - ly) * (1.0 - lx) * lf, (1.0 - ly) * lx * lf,
+         ly * (1.0 - lx) * lf, ly * lx * lf)
+    return f, w
+
+
+def _dconv_band_loop(lo_ref, hi_ref, nblk, n_chunks, f, w, step_fn, init):
+    """What both kernels do with this grid step's band:
+    ``step_fn(rows, hit, a_t, carry) -> carry`` once per step of it, with
+    ``rows`` the slice of the flat axis, ``hit`` the four corner masks and
+    ``a_t`` the f32 ``(len(rows), nblk)`` block of A transposed.  A band of
+    more than half the map is one step over all of it, the dense form: a
+    step of the loop costs a fixed time beside its positions'."""
+    import jax.experimental.pallas as pl
+
+    bg, i = pl.program_id(0), pl.program_id(1)
+    lo, hi = lo_ref[bg, i], hi_ref[bg, i]
+
+    def step(rows, carry):
+        pos = jax.lax.broadcasted_iota(jnp.int32, (rows.size, nblk), 0)
+        hit = [pos == fk - rows.start for fk in f]
+        a_t = sum(jnp.where(h, wk, 0.0) for h, wk in zip(hit, w))
+        return step_fn(rows, hit, a_t, carry)
+
+    def banded():
+        win = _DCONV_STEP * _DCONV_CHUNK
+        return jax.lax.fori_loop(
+            0, (hi - lo + _DCONV_STEP) // _DCONV_STEP,
+            lambda j, carry: step(pl.ds(pl.multiple_of(
+                (lo + j * _DCONV_STEP) * _DCONV_CHUNK, _DCONV_CHUNK), win),
+                carry), init)
+
+    return jax.lax.cond(
+        2 * (hi - lo + 1) > n_chunks,
+        lambda: step(pl.ds(0, n_chunks * _DCONV_CHUNK), init), banded)
+
+
+def _dconv_fwd_kernel_factory(W, nblk, dot_dtype):
+    def kern(lo_ref, hi_ref, y0_ref, y1_ref, x0_ref, x1_ref, ly_ref, lx_ref,
+             lf_ref, ftt_ref, col_ref):
         import jax.experimental.pallas as pl
 
         # factor blocks hold the WHOLE (padded) row per bg (N*4 bytes =
         # ~87 KB at north-star shapes — Mosaic requires lane-dim blocks be
-        # full or 128-multiples; slicing the current chunk in-kernel keeps
+        # full or 128-multiples; slicing the current block in-kernel keeps
         # the spec legal and the row resident across the i-grid)
         off = pl.program_id(1) * nblk
-        sl = lambda ref: ref[0, 0, pl.ds(off, nblk)]
-        yfac, xfac_nolf, *_ = _dconv_factors(
-            sl(y0_ref), sl(y1_ref), sl(x0_ref), sl(x1_ref),
-            sl(ly_ref), sl(lx_ref), H, W)
-        a = yfac * xfac_nolf * sl(lf_ref)[:, None]
-        col_ref[0] = jnp.dot(
-            a.astype(dot_dtype), ft_ref[0], precision=_dconv_prec(dot_dtype),
-            preferred_element_type=jnp.float32).astype(col_ref.dtype)
+        sl = lambda ref: ref[0, :, pl.ds(off, nblk)]          # (1, nblk)
+        f, w = _dconv_corners(*(sl(r) for r in (
+            y0_ref, y1_ref, x0_ref, x1_ref, ly_ref, lx_ref, lf_ref)), W)
+
+        # col^T += ft^T[:, step] @ A_T[step]: with the samples on the lanes
+        # nothing is transposed inside the loop, col^T once after it
+        def step(rows, hit, a_t, acc):
+            return acc + jnp.dot(
+                ftt_ref[0, :, rows], a_t.astype(dot_dtype),
+                precision=_dconv_prec(dot_dtype),
+                preferred_element_type=jnp.float32)
+
+        acc = _dconv_band_loop(
+            lo_ref, hi_ref, nblk, ftt_ref.shape[2] // _DCONV_CHUNK, f, w,
+            step, jnp.zeros(col_ref.shape[:0:-1], jnp.float32))
+        col_ref[0] = acc.T.astype(col_ref.dtype)
     return kern
 
 
-def _dconv_bwd_kernel_factory(H, W, nblk, dot_dtype):
-    def kern(y0_ref, y1_ref, x0_ref, x1_ref, ly_ref, lx_ref, lf_ref,
-             ft_ref, g_ref, dly_ref, dlx_ref, dlf_ref, dft_ref):
+def _dconv_bwd_kernel_factory(W, nblk, dot_dtype):
+    def kern(lo_ref, hi_ref, y0_ref, y1_ref, x0_ref, x1_ref, ly_ref, lx_ref,
+             lf_ref, ft_ref, g_ref, dly_ref, dlx_ref, dlf_ref, dft_ref):
         import jax.experimental.pallas as pl
 
-        off = pl.program_id(1) * nblk
-        sl = lambda ref: ref[0, 0, pl.ds(off, nblk)]
-        yfac, xfac_nolf, e0y, e1y, e0x, e1x = _dconv_factors(
-            sl(y0_ref), sl(y1_ref), sl(x0_ref), sl(x1_ref),
-            sl(ly_ref), sl(lx_ref), H, W)
-        lf = sl(lf_ref)[:, None]
+        i = pl.program_id(1)
+        out = pl.ds(i * nblk, nblk)
+        sl = lambda ref: ref[0, :, out]                        # (1, nblk)
+        ly, lx, lf = sl(ly_ref), sl(lx_ref), sl(lf_ref)
+        f, w = _dconv_corners(sl(y0_ref), sl(y1_ref), sl(x0_ref),
+                              sl(x1_ref), ly, lx, lf, W)
         g = g_ref[0].astype(dot_dtype)
-        # dA = g @ ft^T — contraction over channels, stays in VMEM
-        da = jax.lax.dot_general(
-            g, ft_ref[0], (((1,), (1,)), ((), ())),
-            precision=_dconv_prec(dot_dtype),
-            preferred_element_type=jnp.float32)
-        dly_ref[0, 0, pl.ds(off, nblk)] = (
-            da * (e1y - e0y) * xfac_nolf * lf).sum(axis=1)
-        dlx_ref[0, 0, pl.ds(off, nblk)] = (
-            da * yfac * (e1x - e0x) * lf).sum(axis=1)
-        dlf_ref[0, 0, pl.ds(off, nblk)] = (da * yfac * xfac_nolf).sum(axis=1)
-        # d_ft += A^T @ g, accumulated across the row-block grid dim
-        a = (yfac * xfac_nolf * lf).astype(dot_dtype)
-        contrib = jax.lax.dot_general(
-            a, g, (((0,), (0,)), ((), ())),
-            precision=_dconv_prec(dot_dtype),
-            preferred_element_type=jnp.float32)
 
-        @pl.when(pl.program_id(1) == 0)
+        @pl.when(i == 0)
         def _init():
             dft_ref[0] = jnp.zeros_like(dft_ref[0])
 
-        dft_ref[0] += contrib
+        def step(rows, hit, a_t, corner_sums):
+            # dA_T = ft[step] @ g^T — contraction over channels, in VMEM
+            da_t = jax.lax.dot_general(
+                ft_ref[0, rows, :], g, (((1,), (1,)), ((), ())),
+                precision=_dconv_prec(dot_dtype),
+                preferred_element_type=jnp.float32)
+            # d_ft[step] += A_T[step] @ g: the band's rows only
+            dft_ref[0, rows, :] += jnp.dot(
+                a_t.astype(dot_dtype), g, precision=_dconv_prec(dot_dtype),
+                preferred_element_type=jnp.float32)
+            return tuple(
+                s + jnp.where(h, da_t, 0.0).sum(axis=0, keepdims=True)
+                for s, h in zip(corner_sums, hit))
+
+        zero = jnp.zeros((1, nblk), jnp.float32)
+        d00, d01, d10, d11 = _dconv_band_loop(
+            lo_ref, hi_ref, nblk, ft_ref.shape[1] // _DCONV_CHUNK, f, w,
+            step, (zero,) * 4)
+        dly_ref[0, :, out] = lf * ((1.0 - lx) * (d10 - d00)
+                                   + lx * (d11 - d01))
+        dlx_ref[0, :, out] = lf * ((1.0 - ly) * (d01 - d00)
+                                   + ly * (d11 - d10))
+        dlf_ref[0, :, out] = ((1.0 - ly) * ((1.0 - lx) * d00 + lx * d01)
+                              + ly * ((1.0 - lx) * d10 + lx * d11))
     return kern
 
 
-def _dconv_pad(a, n_pad, fill=0):
-    if a.shape[1] != n_pad:
-        a = jnp.pad(a, ((0, 0), (0, n_pad - a.shape[1])),
-                    constant_values=fill)
+def _dconv_pad(a, n_pad, mode="constant"):
+    a = jnp.pad(a, ((0, 0), (0, n_pad - a.shape[1])), mode=mode)
     # (BG, 1, n_pad): Mosaic block shapes need the last two dims full or
     # (8, 128)-divisible; a singleton sublane dim satisfies "full"
     return a[:, None, :]
@@ -916,9 +1028,10 @@ def _dconv_pad(a, n_pad, fill=0):
 def dconv_col_pallas(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret=False):
     """col[bg, n, :] = A[bg, n, :] @ ft[bg] with A built in VMEM (above).
 
-    y0..x1: (BG, N) int32; ly/lx/lf: (BG, N) f32; ft: (BG, H*W, C);
-    ``hw`` = (H, W) static.  Returns (BG, N, C) in ft's dtype with f32
-    accumulation (== the XLA path's a.astype(ft.dtype) @ ft contract).
+    y0..x1: (BG, N) int32, inside the map; ly/lx/lf: (BG, N) f32;
+    ft: (BG, H*W, C); ``hw`` = (H, W) static.  Returns (BG, N, C) in ft's
+    dtype with f32 accumulation (== the XLA path's a.astype(ft.dtype) @ ft
+    contract).
     """
     return _dconv_impl(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret)
 
@@ -958,6 +1071,25 @@ def _dconv_grid(N, HW=None, C=None, itemsize=4):
     return nblk, -(-N // nblk) * nblk
 
 
+def _dconv_operands(y0, y1, x0, x1, ly, lx, lf, ft, W):
+    """What both kernels take: the grid, then the band (scalar prefetch)
+    and the seven per-sample rows padded to the grid, then ft padded to
+    whole steps, and the spec of a per-sample row."""
+    from jax.experimental import pallas as pl
+
+    N = y0.shape[1]
+    HW, C = ft.shape[1], ft.shape[2]
+    nblk, n_pad = _dconv_grid(N, HW, C, jnp.dtype(ft.dtype).itemsize)
+    # padded rows carry lf=0 => A row = 0 => no effect anywhere; their
+    # corners repeat a live row of the block, so they never widen its band
+    ints = [_dconv_pad(a, n_pad, "edge") for a in (y0, y1, x0, x1)]
+    flts = [_dconv_pad(a, n_pad) for a in (ly, lx, lf)]
+    band = _dconv_band(ints[0][:, 0], ints[1][:, 0], W, HW, nblk)
+    ft = jnp.pad(ft, ((0, 0), (0, _dconv_hw_pad(HW) - HW), (0, 0)))
+    row_spec = pl.BlockSpec((1, 1, n_pad), lambda bg, i, lo, hi: (bg, 0, 0))
+    return nblk, n_pad, (*band, *ints, *flts), ft, row_spec
+
+
 def _dconv_impl(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret):
     return _per_batch_shard(
         lambda *a: _dconv_fwd_call(*a, hw, interpret),
@@ -966,29 +1098,29 @@ def _dconv_impl(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret):
 
 def _dconv_fwd_call(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    H, W = hw
+    W = hw[1]
     BG, N = y0.shape
     HW, C = ft.shape[1], ft.shape[2]
     _record_cost(
         "dconv_col_pallas_fwd",
         cost_dconv_col_fwd(BG, N, HW, C, jnp.dtype(ft.dtype).itemsize),
         ft.shape)
-    nblk, n_pad = _dconv_grid(N, HW, C, jnp.dtype(ft.dtype).itemsize)
-    ints = [_dconv_pad(a, n_pad) for a in (y0, y1, x0, x1)]
-    # padded rows carry lf=0 => A row = 0 => no effect anywhere
-    flts = [_dconv_pad(a, n_pad) for a in (ly, lx)] + [_dconv_pad(lf, n_pad)]
-    fac_spec = pl.BlockSpec((1, 1, n_pad), lambda bg, i: (bg, 0, 0))
+    nblk, n_pad, rows, ft, row_spec = _dconv_operands(
+        y0, y1, x0, x1, ly, lx, lf, ft, W)
     out = pl.pallas_call(
-        _dconv_fwd_kernel_factory(H, W, nblk, ft.dtype),
+        _dconv_fwd_kernel_factory(W, nblk, ft.dtype),
         out_shape=jax.ShapeDtypeStruct((BG, n_pad, C), ft.dtype),
-        grid=(BG, n_pad // nblk),
-        in_specs=[fac_spec] * 7 + [
-            pl.BlockSpec((1, HW, C), lambda bg, i: (bg, 0, 0))],
-        out_specs=pl.BlockSpec((1, nblk, C), lambda bg, i: (bg, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(BG, n_pad // nblk),
+            in_specs=[row_spec] * 7 + [pl.BlockSpec(
+                (1, C, ft.shape[1]), lambda bg, i, lo, hi: (bg, 0, 0))],
+            out_specs=pl.BlockSpec((1, nblk, C),
+                                   lambda bg, i, lo, hi: (bg, i, 0))),
         interpret=interpret,
         name="dconv_col_pallas_fwd",
-    )(*ints, *flts, ft)
+    )(*rows, ft.transpose(0, 2, 1))
     return out[:, :N]
 
 
@@ -1008,36 +1140,35 @@ def _dconv_bwd(hw, interpret, res, g):
 
 def _dconv_bwd_call(y0, y1, x0, x1, ly, lx, lf, ft, g, hw, interpret):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    H, W = hw
+    W = hw[1]
     BG, N = y0.shape
     HW, C = ft.shape[1], ft.shape[2]
     _record_cost(
         "dconv_col_pallas_bwd",
         cost_dconv_col_bwd(BG, N, HW, C, jnp.dtype(ft.dtype).itemsize),
         ft.shape)
-    nblk, n_pad = _dconv_grid(N, HW, C, jnp.dtype(ft.dtype).itemsize)
-    ints = [_dconv_pad(a, n_pad) for a in (y0, y1, x0, x1)]
-    flts = [_dconv_pad(a, n_pad) for a in (ly, lx)] + [_dconv_pad(lf, n_pad)]
-    gp = jnp.pad(g, ((0, 0), (0, n_pad - N), (0, 0))) if n_pad != N else g
-    fac_spec = pl.BlockSpec((1, 1, n_pad), lambda bg, i: (bg, 0, 0))
+    nblk, n_pad, rows, ft, row_spec = _dconv_operands(
+        y0, y1, x0, x1, ly, lx, lf, ft, W)
+    gp = jnp.pad(g, ((0, 0), (0, n_pad - N), (0, 0)))
+    row_out = jax.ShapeDtypeStruct((BG, 1, n_pad), jnp.float32)
+    map_spec = pl.BlockSpec((1, ft.shape[1], C),
+                            lambda bg, i, lo, hi: (bg, 0, 0))
     dly, dlx, dlf, dft = pl.pallas_call(
-        _dconv_bwd_kernel_factory(H, W, nblk, ft.dtype),
-        out_shape=(jax.ShapeDtypeStruct((BG, 1, n_pad), jnp.float32),
-                   jax.ShapeDtypeStruct((BG, 1, n_pad), jnp.float32),
-                   jax.ShapeDtypeStruct((BG, 1, n_pad), jnp.float32),
-                   jax.ShapeDtypeStruct((BG, HW, C), jnp.float32)),
-        grid=(BG, n_pad // nblk),
-        in_specs=[fac_spec] * 7 + [
-            pl.BlockSpec((1, HW, C), lambda bg, i: (bg, 0, 0)),
-            pl.BlockSpec((1, nblk, C), lambda bg, i: (bg, i, 0))],
-        out_specs=(fac_spec, fac_spec, fac_spec,
-                   pl.BlockSpec((1, HW, C), lambda bg, i: (bg, 0, 0))),
+        _dconv_bwd_kernel_factory(W, nblk, ft.dtype),
+        out_shape=(row_out, row_out, row_out,
+                   jax.ShapeDtypeStruct(ft.shape, jnp.float32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(BG, n_pad // nblk),
+            in_specs=[row_spec] * 7 + [map_spec, pl.BlockSpec(
+                (1, nblk, C), lambda bg, i, lo, hi: (bg, i, 0))],
+            out_specs=(row_spec, row_spec, row_spec, map_spec)),
         interpret=interpret,
         name="dconv_col_pallas_bwd",
-    )(*ints, *flts, ft, gp)
+    )(*rows, ft, gp)
     return (dly[:, 0, :N], dlx[:, 0, :N], dlf[:, 0, :N],
-            dft.astype(ft.dtype))
+            dft[:, :HW].astype(ft.dtype))
 
 
 dconv_col_pallas.defvjp(_dconv_fwd, _dconv_bwd)

@@ -340,3 +340,58 @@ def deploy_twin_checkpoint(batch=16, image=32, seed=0):
             np.ones(s, np.float32) if n.endswith("_var")
             else np.zeros(s, np.float32))
     return sym, params, input_shapes
+
+
+def dconv_sample_inputs(rng, bg, hw, offset=None, kernel=3, dilate=2,
+                        dead=0.1):
+    """The seven per-sample inputs of ``dconv_col_pallas`` as numpy arrays
+    ``(y0, y1, x0, x1, ly, lx, lf)``, each ``(bg, kernel**2 * H * W)``, rows
+    tap-major as ``deformable_convolution`` orders them.
+
+    ``offset=None`` draws every sample uniformly over the map and kills a
+    share ``dead`` of them: the band's worst case, traffic no detector
+    sends.  ``offset=m`` is what a deformable layer sends: the stride-1
+    'same' grid of a dilated ``kernel`` x ``kernel`` convolution plus
+    offsets uniform in ``(-m, m)``, with the operator's own clipping and
+    ``lf`` (samples outside the map are dead and sit on its edge)."""
+    H, W = hw
+    n = kernel * kernel * H * W
+    if offset is None:
+        sy = rng.uniform(0, H - 1, (bg, n))
+        sx = rng.uniform(0, W - 1, (bg, n))
+        live = rng.rand(bg, n) > dead
+    else:
+        pad = dilate * (kernel - 1) // 2
+        tap = np.arange(kernel) * dilate - pad
+        sy = (tap[:, None, None, None] + np.arange(H)[None, None, :, None]
+              + np.zeros((1, kernel, 1, W))).reshape(1, n)
+        sx = (tap[None, :, None, None] + np.arange(W)[None, None, None, :]
+              + np.zeros((kernel, 1, H, 1))).reshape(1, n)
+        sy = sy + rng.uniform(-offset, offset, (bg, n))
+        sx = sx + rng.uniform(-offset, offset, (bg, n))
+        live = (sy >= 0) & (sy < H) & (sx >= 0) & (sx < W)
+    sy = np.clip(sy, 0, H - 1).astype(np.float32)
+    sx = np.clip(sx, 0, W - 1).astype(np.float32)
+    y0, x0 = np.floor(sy).astype(np.int32), np.floor(sx).astype(np.int32)
+    y1, x1 = np.minimum(y0 + 1, H - 1), np.minimum(x0 + 1, W - 1)
+    return y0, y1, x0, x1, sy - y0, sx - x0, live.astype(np.float32)
+
+
+def dconv_dense_reference(y0, y1, x0, x1, ly, lx, lf, ft, hw):
+    """The dense one-hot formulation of ``dconv_col_pallas``, what
+    ``deformable_convolution``'s XLA scan computes: the whole sample matrix
+    A, rounded to ft's dtype, times ft with f32 accumulation.  A is
+    ``(BG, N, H*W)`` f32: for toy sizes, or one (image, group)."""
+    import jax
+    import jax.numpy as jnp
+
+    H, W = hw
+    iy, ix = jnp.arange(H), jnp.arange(W)
+    yv = ((1 - ly)[..., None] * (y0[..., None] == iy)
+          + ly[..., None] * (y1[..., None] == iy))
+    xv = lf[..., None] * ((1 - lx)[..., None] * (x0[..., None] == ix)
+                          + lx[..., None] * (x1[..., None] == ix))
+    a = (yv[..., :, None] * xv[..., None, :]).reshape(*y0.shape, H * W)
+    return jnp.einsum("bnp,bpc->bnc", a.astype(ft.dtype), ft,
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32).astype(ft.dtype)

@@ -463,6 +463,22 @@ class TestCLI:
         assert at.main(list(argv) + ["--force"]) == 0
         assert autotune.measurements() > first
 
+    def test_dconv_search_on_a_deformable_layers_samples(self, at_on):
+        """``--offset``: the search measures on a dilated 3x3 grid plus
+        small offsets (narrow bands) where the default draw is uniform
+        over the map (every band the whole map); more rows than the grid
+        has are refused."""
+        at = _load_tool("tools/autotune.py")
+        argv = ["search", "--kernel", "dconv_col_pallas", "--h", "6",
+                "--w", "8", "--c", "16", "--warmup", "1", "--repeat", "1",
+                "--offset", "1"]
+        assert at.main(argv + ["--n", "432"]) == 0
+        winner = autotune.lookup("dconv_col_pallas",
+                                 autotune.dconv_shape_sig(432, 48, 16, 4))
+        assert winner is not None and "nblk" in winner
+        with pytest.raises(SystemExit, match="9\\*h\\*w = 432"):
+            at.main(argv + ["--n", "433"])
+
     def test_ladder_search_roundtrip(self, at_on, tmp_path, capsys):
         at = _load_tool("tools/autotune.py")
         trace = _mk_trace(tmp_path / "t.jsonl",

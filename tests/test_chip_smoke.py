@@ -54,9 +54,17 @@ def test_nms_leg_interpret():
 
 
 def test_dconv_leg_interpret():
-    errs = chip_smoke.dconv_leg(bg=2, channels=8, hw=(5, 7),
-                                interpret=True)["dconv_rel_err"]
-    assert set(errs) == {"col", "d_ly", "d_lx", "d_lf", "d_ft"}
+    facts = chip_smoke.dconv_leg(bg=2, channels=8, hw=(12, 16),
+                                 interpret=True, calls=1)
+    assert facts["bg"] == 2
+    regimes = facts["dconv"]
+    assert list(regimes) == [name for name, _ in chip_smoke.DCONV_REGIMES]
+    for fact in regimes.values():
+        assert set(fact["rel_err"]) == {"col", "d_ly", "d_lx", "d_lf", "d_ft"}
+        assert fact["fwd_ms"] > 0 and fact["bwd_ms"] > 0
+    # narrow where a detector's offsets are small, the whole map otherwise
+    assert regimes["offsets<1"]["band_share"] <= regimes["offsets<=3"][
+        "band_share"] <= regimes["uniform"]["band_share"] == 1.0
 
 
 def test_module_fit_leg_toy():

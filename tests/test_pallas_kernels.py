@@ -215,3 +215,117 @@ def test_batched_kernels_run_per_dp_shard_under_a_visible_mesh():
     for g, w in zip(got, want):
         assert g.sharding.spec == P("dp")
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# -- the band-limited dconv kernel pair (PR 27) ------------------------------
+# name: (map, offsets' magnitude or None for map-wide, row block or None)
+_DCONV_REGIMES = {
+    # the base grid of a dilated 3x3 alone: every lerp weight 0 or 1
+    "zero_offsets": ((24, 32), 0.0, None),
+    "small_offsets": ((24, 32), 0.7, None),
+    # band = the whole map: the dense step
+    "map_wide": ((24, 32), None, None),
+    # most samples pushed outside: clipped to the edge, lf = 0
+    "outside": ((10, 16), 12.0, None),
+    # N = 891 is no multiple of 64, and blocks of 64 straddle taps of 99
+    # rows; W no power of two, HW no multiple of 128
+    "ragged_straddling": ((9, 11), 1.5, 64),
+    # wider than one step of the loop, narrower than half the map
+    "two_steps": ((40, 32), 2.5, None),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("regime", sorted(_DCONV_REGIMES))
+def test_dconv_band_matches_dense(regime, dtype, monkeypatch):
+    """Forward and all four gradients of the band-limited kernels against
+    the dense formulation, whatever the band: one step, several, the whole
+    map; padded rows; a block that straddles two taps; a ragged map."""
+    import jax
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.test_utils import (dconv_dense_reference,
+                                      dconv_sample_inputs)
+
+    hw, offset, nblk = _DCONV_REGIMES[regime]
+    if nblk:
+        monkeypatch.setattr(pk, "_DCONV_NBLK", nblk)
+    rng = np.random.RandomState(3)
+    BG, C = 2, 16
+    y0, y1, x0, x1, ly, lx, lf = map(
+        jnp.asarray, dconv_sample_inputs(rng, BG, hw, offset))
+    share = float(pk.dconv_band_share(y0, y1, hw, pk._DCONV_NBLK))
+    if regime == "map_wide":
+        assert share > 0.9
+    elif regime == "two_steps":
+        n_chunks = pk._dconv_chunks(hw[0] * hw[1])
+        assert pk._DCONV_STEP / n_chunks < share < 0.5
+    ft = jnp.asarray(rng.randn(BG, hw[0] * hw[1], C).astype(np.float32)
+                     ).astype(dtype)
+    cot = jnp.asarray(rng.randn(BG, y0.shape[1], C).astype(np.float32))
+
+    def run(fn):
+        def loss(*a):
+            return jnp.sum(fn(*a).astype(jnp.float32) * cot)
+        out = fn(ly, lx, lf, ft)
+        grads = jax.grad(loss, argnums=(0, 1, 2, 3))(ly, lx, lf, ft)
+        return [np.asarray(v.astype(jnp.float32)) for v in (out,) + grads]
+
+    got = run(lambda *a: pk.dconv_col_pallas(y0, y1, x0, x1, *a, hw, True))
+    want = run(lambda *a: dconv_dense_reference(y0, y1, x0, x1, *a, hw))
+    # bf16: one rounding of the output / of d_ft, and the dense path's AD
+    # rounds dA where the kernel keeps it f32
+    tol = 2.0 ** -7 if dtype == "bfloat16" else 1e-5
+    for name, g, w in zip(("col", "d_ly", "d_lx", "d_lf", "d_ft"), got, want):
+        assert g.shape == w.shape, name
+        assert np.abs(g - w).max() <= tol * max(np.abs(w).max(), 1.0), name
+
+
+def test_dconv_band_holds_every_corner_and_padding_never_widens_it():
+    """Property of ``_dconv_band``: each corner of each row lies in its
+    block's ``[lo, hi]`` chunks, and the rows padded on to the last block
+    leave its band what its live rows alone make it."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.test_utils import dconv_sample_inputs
+
+    rng = np.random.RandomState(5)
+    chunk = pk._DCONV_CHUNK
+    for hw, offset, nblk in (((24, 32), 0.7, 80), ((13, 21), 3.0, 48),
+                             ((24, 32), None, 80), ((9, 11), 40.0, 64)):
+        H, W = hw
+        y0, y1, x0, x1 = dconv_sample_inputs(rng, 3, hw, offset)[:4]
+        N = y0.shape[1]
+        assert N % nblk  # the last block is padded
+        n_pad = -(-N // nblk) * nblk
+        lo, hi = (np.asarray(v) for v in pk._dconv_band(
+            pk._dconv_pad(jnp.asarray(y0), n_pad, "edge")[:, 0],
+            pk._dconv_pad(jnp.asarray(y1), n_pad, "edge")[:, 0],
+            W, H * W, nblk))
+        assert lo.shape == hi.shape == (3, n_pad // nblk)
+        blk = np.arange(N) // nblk
+        assert (lo[:, blk] * chunk <= y0 * W + x0).all()
+        assert (y1 * W + x1 < (hi[:, blk] + 1) * chunk).all()
+        assert (0 <= lo).all() and (hi < pk._dconv_chunks(H * W)).all()
+        tail = slice(N - N % nblk, N)
+        np.testing.assert_array_equal(
+            lo[:, -1], y0[:, tail].min(1) * W // chunk)
+        np.testing.assert_array_equal(
+            hi[:, -1], (y1[:, tail].max(1) * W + W - 1) // chunk)
+
+
+def test_dconv_band_share_at_the_cells_shape():
+    """``dconv_band_share``: 1.0 when every block's samples span the map,
+    at most 5 of 19 chunks at R-FCN res5's shape under offsets below one
+    cell (two output rows of a dilated tap touch five feature rows)."""
+    from mxnet_tpu.ops.pallas_kernels import dconv_band_share
+    from mxnet_tpu.test_utils import dconv_sample_inputs
+
+    hw = (38, 64)
+    rng = np.random.RandomState(0)
+    y0, y1 = dconv_sample_inputs(rng, 2, hw, None)[:2]
+    assert float(dconv_band_share(y0, y1, hw, 128)) > 0.999
+    y0, y1 = dconv_sample_inputs(rng, 2, hw, 1.0)[:2]
+    share = float(dconv_band_share(y0, y1, hw, 128))
+    assert 2 / 19 < share <= 5 / 19
+    # a block of the whole tap sees the whole map
+    assert float(dconv_band_share(y0, y1, hw, 38 * 64)) == 1.0

@@ -12,9 +12,10 @@ winner per (device kind, kernel, shape signature) in the
 
 Examples::
 
-    # search dconv_col_pallas block shapes at a concrete problem shape
+    # search dconv_col_pallas block shapes at a concrete problem shape,
+    # on the samples a deformable layer sends (offsets below one cell)
     python tools/autotune.py search --kernel dconv_col_pallas \\
-        --bg 8 --n 2432 --h 38 --w 64 --c 512 --dtype bfloat16
+        --bg 32 --n 21888 --h 38 --w 64 --c 128 --dtype bfloat16 --offset 1
 
     # propose ladder rungs from recorded traffic, adopted by any Engine
     # started with MXNET_AUTOTUNE=1 for the same sample shapes
@@ -186,15 +187,29 @@ def _search_dconv(args):
     if _warm_hit(kernel, sig, "dconv", args):
         return 0
 
-    # the same inputs the parity test builds, deterministic
     rng = np.random.RandomState(args.seed)
-    y0 = jnp.asarray(rng.randint(0, max(1, H - 1), (BG, N)).astype(np.int32))
-    y1 = jnp.minimum(y0 + 1, H - 1)
-    x0 = jnp.asarray(rng.randint(0, max(1, W - 1), (BG, N)).astype(np.int32))
-    x1 = jnp.minimum(x0 + 1, W - 1)
-    ly = jnp.asarray(rng.rand(BG, N).astype(np.float32))
-    lx = jnp.asarray(rng.rand(BG, N).astype(np.float32))
-    lf = jnp.asarray((rng.rand(BG, N) > 0.2).astype(np.float32))
+    if args.offset is None:
+        # every sample anywhere on the map: the band's worst case (each row
+        # block contracts over the whole map), traffic no detector sends
+        y0 = rng.randint(0, max(1, H - 1), (BG, N)).astype(np.int32)
+        x0 = rng.randint(0, max(1, W - 1), (BG, N)).astype(np.int32)
+        y1, x1 = np.minimum(y0 + 1, H - 1), np.minimum(x0 + 1, W - 1)
+        ly = rng.rand(BG, N).astype(np.float32)
+        lx = rng.rand(BG, N).astype(np.float32)
+        lf = (rng.rand(BG, N) > 0.2).astype(np.float32)
+    else:
+        # what a deformable layer sends: the first N rows (tap-major) of a
+        # dilated 3x3 grid plus offsets below --offset cells
+        from mxnet_tpu.test_utils import dconv_sample_inputs
+
+        if N > 9 * HW:
+            raise SystemExit("autotune: --offset draws 9*h*w = %d rows, "
+                             "--n asks for %d" % (9 * HW, N))
+        y0, y1, x0, x1, ly, lx, lf = (
+            a[:, :N] for a in dconv_sample_inputs(rng, BG, (H, W),
+                                                  args.offset))
+    y0, y1, x0, x1, ly, lx, lf = map(jnp.asarray,
+                                     (y0, y1, x0, x1, ly, lx, lf))
     ft = jnp.asarray(rng.randn(BG, HW, C)).astype(dtype)
     g = jnp.asarray(rng.randn(BG, N, C).astype(np.float32))
     # the compiled kernel exists only on TPU; elsewhere measure the
@@ -645,14 +660,19 @@ def main(argv=None):
                         "always-measured default); 0 = MXNET_AUTOTUNE_TOPK "
                         "or a quarter of the grid")
     # dconv problem shape (defaults: a CPU-sized smoke problem; use the
-    # north-star res5 shape on the chip: --bg 8 --n 2432 --h 38 --w 64
-    # --c 512 --dtype bfloat16)
+    # north-star res5 shape on the chip: --bg 32 --n 21888 --h 38 --w 64
+    # --c 128 --dtype bfloat16 --offset 1)
     s.add_argument("--bg", type=int, default=1, help="batch x groups")
     s.add_argument("--n", type=int, default=128, help="sample rows")
     s.add_argument("--h", type=int, default=4)
     s.add_argument("--w", type=int, default=8)
     s.add_argument("--c", type=int, default=16, help="channels per group")
     s.add_argument("--dtype", default="float32")
+    s.add_argument("--offset", type=float, default=None,
+                   help="dconv samples as a deformable layer sends them: a "
+                        "dilated 3x3 grid plus offsets below this many "
+                        "cells (--n <= 9*h*w rows of it); unset = uniform "
+                        "over the map, the band's worst case")
     s.add_argument("--warmup", type=int, default=2)
     s.add_argument("--repeat", type=int, default=5)
     s.add_argument("--max-trials", type=int, default=64)
