@@ -22,7 +22,7 @@ from ..ndarray.ndarray import NDArray, _wrap
 from ..ndarray import _invoke_raw
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 
-__all__ = ["Block", "HybridBlock", "SymbolBlock"]
+__all__ = ["Block", "HybridBlock", "SymbolBlock", "remat"]
 
 # per-capture invocation counts (thread-local, reset by _get_graph): lets
 # a block invoked several times WITHIN one capture (weight sharing —
@@ -620,6 +620,34 @@ def _swap_trace_call(params, param_vals, call, key, train):
         _TRACING.active = prev_tracing
         for p, old in swapped:
             p._data = old
+
+
+def remat(fn):
+    """``fn`` over NDArrays (child blocks called inside, their parameters
+    closed over) -> the same function, recomputed in the backward pass of a
+    jitted train step instead of keeping its intermediates
+    (``jax.checkpoint``).  Outside a trace, eager or symbolic, it is ``fn``
+    itself: the autograd tape keeps what it records."""
+    import jax
+    from jax.core import Tracer
+
+    def wrapped(*args):
+        if not any(isinstance(a, NDArray) and isinstance(a._data, Tracer)
+                   for a in args):
+            return fn(*args)
+
+        def pure(*vals):
+            out = fn(*[NDArray(v) for v in vals])
+            if isinstance(out, (list, tuple)):
+                return tuple(o._data for o in out)
+            return out._data
+
+        out = jax.checkpoint(pure)(*[a._data for a in args])
+        if isinstance(out, tuple):
+            return [NDArray(o) for o in out]
+        return NDArray(out)
+
+    return wrapped
 
 
 class SymbolBlock(HybridBlock):

@@ -9,11 +9,15 @@ that form — the same param-swap trace technique HybridBlock's CachedOp uses
 """
 from __future__ import annotations
 
+import numpy as np
+
 from .. import random as _rnd
 from ..ndarray.ndarray import NDArray
+from ..ops.optimizer_ops import adam_bias_corrected_lr, adam_update
+from ..telemetry import tracing
 from .block import Block, _swap_trace_call
 
-__all__ = ["functionalize", "merge_params", "make_train_step"]
+__all__ = ["functionalize", "merge_params", "make_train_step", "count_step"]
 
 
 def functionalize(net, train=False):
@@ -67,8 +71,9 @@ def merge_params(names, aux_names, learn, aux):
 
 def make_train_step(net, loss_fn, learning_rate=0.01, momentum=0.0,
                     compute_dtype=None, mesh=None, data_axis="dp",
-                    shard_optimizer_states=False):
-    """Build a fully-jittable SGD train step for an initialized Block.
+                    shard_optimizer_states=False, optimizer="sgd",
+                    beta1=0.9, beta2=0.999, epsilon=1e-8):
+    """Build a fully-jittable train step for an initialized Block.
 
     → (step, state) where ``state = (param_vals, momentum_vals, aux_vals)``
     pytrees and ``step(state, x, y, key) -> (state, loss)``.  All compute —
@@ -100,10 +105,26 @@ def make_train_step(net, loss_fn, learning_rate=0.01, momentum=0.0,
     bytes drop ~axis-size×, which is what frees HBM for activations at
     north-star scale (the ``__graft_entry__`` ZeRO phase measures 50 MB vs
     399 MB at ResNet-101 scale).
+
+    ``optimizer="adam"`` replaces SGD's rule by ``ops.optimizer_ops.
+    adam_update`` (``beta1`` / ``beta2`` / ``epsilon``; no decay): the
+    state's second entry is then ``{"mean": [...], "var": [...], "t": step
+    count}``, all donated with the rest, and the bias correction is folded
+    into the learning rate from ``t`` inside the step
+    (``adam_bias_corrected_lr``).
+
+    A net with several outputs hands ``loss_fn`` a list.  ``loss_fn`` may
+    return ``(loss, aux)``, ``aux`` a dict of further device values (the
+    loss's terms apart, counters the model computed): the step then returns
+    ``(state, loss, aux)``; :func:`count_step` records the counters among
+    them on the host's open span.
     """
     import jax
     import jax.numpy as jnp
 
+    if optimizer not in ("sgd", "adam"):
+        raise ValueError("optimizer %r: make_train_step knows 'sgd' and "
+                         "'adam'" % (optimizer,))
     apply, names, vals, aux_names = functionalize(net, train=True)
     aux_idx = [i for i, n in enumerate(names) if n in set(aux_names)]
     learn_idx = [i for i, n in enumerate(names) if n not in set(aux_names)]
@@ -126,27 +147,61 @@ def make_train_step(net, loss_fn, learning_rate=0.01, momentum=0.0,
             x_ = x
         out, new_aux = apply(merged, x_, key)
         if cdtype is not None:
-            out = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), out)
+            # integer outputs (counters, choices) keep their type
+            out = jax.tree_util.tree_map(
+                lambda a: a.astype(jnp.float32)
+                if jnp.issubdtype(a.dtype, jnp.floating) else a, out)
             new_aux = [a.astype(jnp.float32) for a in new_aux]
-        loss = loss_fn(NDArray(out), NDArray(y))
-        return jnp.mean(loss._data), new_aux
+        loss = loss_fn([NDArray(o) for o in out] if isinstance(out, tuple)
+                       else NDArray(out), NDArray(y))
+        extra = None
+        if isinstance(loss, tuple):
+            loss, extra = loss
+            extra = {k: v._data if isinstance(v, NDArray) else v
+                     for k, v in extra.items()}
+        return jnp.mean(loss._data), (new_aux, extra)
 
     grad_fn = jax.value_and_grad(compute_loss, has_aux=True)
 
     def step(state, x, y, key):
         learn_vals, mom_vals, aux_vals = state
-        (loss, new_aux), grads = grad_fn(learn_vals, aux_vals, x, y, key)
-        if momentum:
-            mom_vals = [momentum * m + g for m, g in zip(mom_vals, grads)]
-            upd = mom_vals
+        (loss, (new_aux, extra)), grads = grad_fn(
+            learn_vals, aux_vals, x, y, key)
+        if optimizer == "adam":
+            t = mom_vals["t"] + 1
+            lr_t = adam_bias_corrected_lr(
+                learning_rate, t.astype(jnp.float32), beta1, beta2)
+            new = [adam_update(p, g, m, v, lr=lr_t, beta1=beta1, beta2=beta2,
+                               epsilon=epsilon)
+                   for p, g, m, v in zip(learn_vals, grads, mom_vals["mean"],
+                                         mom_vals["var"])]
+            learn_vals, mean, var = (list(c) for c in zip(*new))
+            mom_vals = {"mean": mean, "var": var, "t": t}
         else:
-            upd = grads
-        learn_vals = [p - learning_rate * g for p, g in zip(learn_vals, upd)]
-        return (learn_vals, mom_vals, new_aux), loss
+            if momentum:
+                mom_vals = [momentum * m + g for m, g in zip(mom_vals, grads)]
+                upd = mom_vals
+            else:
+                upd = grads
+            learn_vals = [p - learning_rate * g
+                          for p, g in zip(learn_vals, upd)]
+        state = (learn_vals, mom_vals, new_aux)
+        if extra is None:
+            return state, loss
+        if mesh is not None:
+            raise ValueError("a loss_fn that returns (loss, aux) is not "
+                             "supported with mesh=: the jitted step pins "
+                             "the shardings of (state, loss) only")
+        return state, loss, extra
 
     learn_vals = [vals[i] for i in learn_idx]
     aux_vals = [vals[i] for i in aux_idx]
-    mom_vals = [jnp.zeros_like(v) for v in learn_vals] if momentum else []
+    if optimizer == "adam":
+        mom_vals = {"mean": [jnp.zeros_like(v) for v in learn_vals],
+                    "var": [jnp.zeros_like(v) for v in learn_vals],
+                    "t": jnp.zeros((), jnp.int32)}
+    else:
+        mom_vals = [jnp.zeros_like(v) for v in learn_vals] if momentum else []
     state = (learn_vals, mom_vals, aux_vals)
 
     if shard_optimizer_states and mesh is None:
@@ -162,7 +217,8 @@ def make_train_step(net, loss_fn, learning_rate=0.01, momentum=0.0,
         spec = ((lambda v: zero_shard_spec(v, mesh, data_axis))
                 if shard_optimizer_states else (lambda v: repl))
         state = ([jax.device_put(v, spec(v)) for v in learn_vals],
-                 [jax.device_put(v, spec(v)) for v in mom_vals],
+                 jax.tree_util.tree_map(
+                     lambda v: jax.device_put(v, spec(v)), mom_vals),
                  [jax.device_put(v, repl) for v in aux_vals])
         state_sh = jax.tree_util.tree_map(lambda v: v.sharding, state)
         step = jax.jit(step, donate_argnums=(0,),
@@ -174,3 +230,15 @@ def make_train_step(net, loss_fn, learning_rate=0.01, momentum=0.0,
         step = telemetry.instrument_step(step, name="gluon_train_step")
 
     return step, state, (names, learn_idx, aux_idx)
+
+
+def count_step(aux, counters):
+    """Record a finished step's device counters on the host's innermost
+    open span (``telemetry.tracing.count``, so only while a profiler session
+    or ``MXNET_TRACE`` is live): ``counters`` names the entries of the
+    step's ``aux`` to record, each summed over its array.  Reading them
+    waits for the step, so call it where the step's loss has come back."""
+    if tracing.current() is None:
+        return
+    for name in counters:
+        tracing.count(name, int(np.asarray(aux[name]).sum()))

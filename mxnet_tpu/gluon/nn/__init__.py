@@ -34,3 +34,10 @@ from .conv_layers import (
     GlobalAvgPool3D,
     ReflectionPad2D,
 )
+from .transformer_layers import (
+    RMSNorm,
+    RotaryEmbedding,
+    GatedFFN,
+    IndexerSparseAttention,
+    SparseMoE,
+)

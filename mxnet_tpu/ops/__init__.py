@@ -22,5 +22,6 @@ from . import misc_ops  # noqa: F401
 from . import detection  # noqa: F401
 from . import rcnn_targets  # noqa: F401
 from . import custom  # noqa: F401
+from . import transformer  # noqa: F401
 
 _load_all = True

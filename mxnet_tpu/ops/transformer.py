@@ -1,0 +1,312 @@
+"""Transformer operators: RMSNorm, rotary embedding with sections, the gated
+feed-forward, indexer-selected sparse attention and the expert layer.
+
+None has a counterpart in the reference (its attention lived in user code
+over ``batch_dot`` + ``softmax``).  Layouts are token-major, one sequence:
+activations ``(S, D)``, heads ``(S, heads, head_dim)``; weights are
+``(out, in)`` like ``FullyConnected``'s.  Statistics, softmaxes, index
+scores and the router run in float32 whatever the activations' type.
+
+``IndexerSparseAttention`` is DeepSeek-V3.2-Exp's sparse attention as a
+training operator: a light indexer scores every causal key for every query,
+an exact threshold keeps the ``topk`` best, attention reads only those, and a
+KL term teaches the indexer the attention's own distribution.  It walks the
+queries in blocks (no ``(heads, S, S)`` array exists), skips the key blocks
+above the diagonal by spans, and saves each query's threshold for the
+backward pass, which recomputes scores but never the selection.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from .registry import register
+
+
+@register("RMSNorm")
+def rms_norm(data, gamma, *, eps=1e-6):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, statistics
+    in float32."""
+    x = data.astype(jnp.float32)
+    y = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+@register("RotaryEmbedding")
+def rotary_embedding(data, positions, *, theta=10000.0, sections=()):
+    """Rotary position embedding, half-rotation form, on ``data`` (S, heads,
+    d).  ``d / 2`` frequency pairs ``theta^(-2i/d)``.  ``positions`` is (S,)
+    for 1-D rotary, or (len(sections), S) with ``sections`` the number of
+    pairs that read each row of ids (M-RoPE: ``sum(sections) == d / 2``;
+    pair i reads the ids of the section it falls in)."""
+    half = data.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    pos = positions.astype(jnp.float32)
+    if pos.ndim == 2:
+        if sum(sections) != half or len(sections) != pos.shape[0]:
+            raise ValueError(
+                "sections %r must be one per row of positions %r and sum to "
+                "%d frequency pairs" % (sections, pos.shape, half))
+        which = np.repeat(np.arange(len(sections)), sections)
+        pos = pos[which].T                                   # (S, half)
+    else:
+        pos = pos[:, None]
+    ang = pos * inv_freq                                     # (S, half)
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x = data.astype(jnp.float32)
+    x1, x2 = x[..., :half], x[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.astype(data.dtype)
+
+
+@register("GatedFFN")
+def gated_ffn(data, gate_weight, up_weight, down_weight):
+    """SwiGLU feed-forward ``(silu(x Wg^T) * (x Wu^T)) Wd^T``; ``Wg`` / ``Wu``
+    (F, D), ``Wd`` (D, F)."""
+    g = jnp.einsum("td,fd->tf", data, gate_weight)
+    u = jnp.einsum("td,fd->tf", data, up_weight)
+    return jnp.einsum("tf,df->td", jax.nn.silu(g) * u, down_weight)
+
+
+# -- indexer-selected sparse attention -----------------------------------------
+def _sortable(x):
+    """float32 -> uint32 with the same order (and -0.0 == +0.0)."""
+    b = lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(0x80000000))
+
+
+def _kth_largest(keys, k):
+    """``keys`` (R, N) uint32 -> (R,) the k-th largest of each row: the
+    largest T with ``count(keys >= T) >= k``, by 32 halvings of the key
+    range, each one compare-and-count pass.  Exact, no sort."""
+    def halve(i, prefix):
+        cand = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        n = jnp.sum(keys >= cand[:, None], axis=1, dtype=jnp.int32)
+        return jnp.where(n >= k, cand, prefix)
+
+    return lax.fori_loop(0, 32, halve,
+                         jnp.zeros(keys.shape[0], jnp.uint32))
+
+
+def _pack_bits(mask, width):
+    """(R, N) bool -> (R, width // 32) uint32, bit j of word i = column
+    32 i + j; columns from N to ``width`` read 0."""
+    r, n = mask.shape
+    words = jnp.sum(mask.reshape(r, n // 32, 32).astype(jnp.uint32)
+                    << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                    dtype=jnp.uint32)
+    return jnp.pad(words, ((0, 0), (0, (width - n) // 32)))
+
+
+def _index_scores(iq, ik, iw, with_vjp=False):
+    """iq (B, HI, dI), ik (Sk, dI), iw (B, HI) -> (B, Sk) float32: ``sum_j
+    w[., j] relu(qI[., j] . kI[s]) / sqrt(HI dI)``.  ``with_vjp``: also the
+    function from the scores' cotangent to (diq, dik, diw), its (B, HI, Sk)
+    array held in the compute type like the attention's."""
+    dots = jnp.einsum("qjd,kd->qjk", iq, ik,
+                      preferred_element_type=jnp.float32)
+    scale = (iq.shape[1] * iq.shape[2]) ** -0.5
+    w = iw.astype(jnp.float32)[:, :, None]
+    scores = jnp.sum(jax.nn.relu(dots) * w, axis=1) * scale
+    if not with_vjp:
+        return scores
+
+    def vjp(g):
+        g = g[:, None, :] * scale
+        diw = jnp.sum(g * jax.nn.relu(dots), axis=2).astype(iw.dtype)
+        dd = jnp.where(dots > 0, g * w, 0.0).astype(ik.dtype)
+        return (jnp.einsum("qjk,kd->qjd", dd, ik),
+                jnp.einsum("qjk,qjd->kd", dd, iq), diw)
+
+    return scores, vjp
+
+
+def _select(scores, t, topk, tau=None):
+    """Index scores (B, Sk) of the queries at positions ``t`` -> (sel (B, Sk)
+    bool, tau (B,) uint32, causal): the keys at or above each row's
+    ``topk``-th largest causal score.  Given ``tau`` nothing is searched."""
+    causal = jnp.arange(scores.shape[1])[None, :] <= t[:, None]
+    keys = jnp.where(causal, _sortable(scores), 0)
+    if tau is None:
+        tau = _kth_largest(keys, topk)
+    return (keys >= tau[:, None]) & causal, tau, causal
+
+
+def _weights(qg, k, sel):
+    """qg (B, h, g, d), k (Sk, h, d), sel (B, Sk) -> e (h, g, B, Sk) the
+    unnormalised attention weights in the compute type, z (h, g, B) their
+    float32 sums.  Softmax is shift-invariant: the shift is an upper bound of
+    the row's scores (|q . k| <= |q| max_s |k_s|), which costs no pass over
+    the (heads, B, Sk) scores where their maximum costs two."""
+    d = qg.shape[-1]
+    norm = lambda x: jnp.sqrt(jnp.sum(jnp.square(  # noqa: E731
+        x.astype(jnp.float32)), -1))
+    bound = (norm(qg) * jnp.max(norm(k), 0)[None, :, None]).transpose(1, 2, 0)
+    s = jnp.einsum("qhgd,khd->hgqk", qg, k,
+                   preferred_element_type=jnp.float32)
+    e = jnp.where(sel, jnp.exp((s - bound[..., None]) * d ** -0.5),
+                  0.0).astype(k.dtype)
+    return e, jnp.sum(e.astype(jnp.float32), axis=-1)
+
+
+def _target(e, z):
+    """Mean over the heads of the attention probabilities -> (B, Sk)."""
+    return jnp.mean(e.astype(jnp.float32) / z[..., None], axis=(0, 1))
+
+
+def _block_forward(topk, emit_width, k, v, ik, blk):
+    q, iq, iw, t = blk
+    B, Hq, d = q.shape
+    Hkv = k.shape[1]
+    with jax.named_scope("sparse_attention.indexer"):
+        scores = _index_scores(iq, ik, iw)
+    with jax.named_scope("sparse_attention.select"):
+        sel, tau, causal = _select(scores, t, topk)
+    with jax.named_scope("sparse_attention.attend"):
+        e, z = _weights(q.reshape(B, Hkv, Hq // Hkv, d), k, sel)
+        o = (jnp.einsum("hgqk,khd->qhgd", e, v,
+                        preferred_element_type=jnp.float32)
+             / z.transpose(2, 0, 1)[..., None]).astype(v.dtype)
+        target = _target(e, z)
+    with jax.named_scope("sparse_attention.indexer"):
+        logp = jax.nn.log_softmax(jnp.where(sel, scores, -jnp.inf), axis=-1)
+        kl = jnp.sum(jnp.where(
+            target > 0,
+            target * (jnp.log(jnp.where(target > 0, target, 1.0))
+                      - jnp.where(sel, logp, 0.0)), 0.0))
+    out = (o.reshape(B, Hq, d), kl, jnp.sum(sel, dtype=jnp.int32),
+           jnp.sum(causal, dtype=jnp.int32))
+    if emit_width:
+        out += (_pack_bits(sel, emit_width),)
+    return out, tau
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _attend_block(topk, emit_width, k, v, ik, blk):
+    """One block of queries against the keys ``[0, Sk)``.  ``blk``: q (B,
+    Hq, d), iq (B, HI, dI), iw (B, HI), t (B,) the queries' positions.
+    -> (o (B, Hq, d), kl, selected, causal[, packed selection]).
+
+    The backward pass is written out: the block's thresholds are saved and
+    its scores and weights recomputed, so nothing of (heads, B, Sk) outlives
+    the block, and every array of that size is held in the compute type (the
+    float32 scores and their gradient exist only inside the fusions that
+    make them)."""
+    return _block_forward(topk, emit_width, k, v, ik, blk)[0]
+
+
+def _attend_block_fwd(topk, emit_width, k, v, ik, blk):
+    out, tau = _block_forward(topk, emit_width, k, v, ik, blk)
+    return out, (k, v, ik, blk, tau, out[0])
+
+
+def _attend_block_bwd(topk, emit_width, res, cts):
+    k, v, ik, (q, iq, iw, t), tau, o = res
+    do, dkl = cts[0], cts[1]
+    B, Hq, d = q.shape
+    Hkv = k.shape[1]
+    heads = (B, Hkv, Hq // Hkv, d)
+    with jax.named_scope("sparse_attention.indexer"):
+        scores, index_vjp = _index_scores(iq, ik, iw, with_vjp=True)
+    with jax.named_scope("sparse_attention.select"):
+        sel, _, _ = _select(scores, t, topk, tau)
+    with jax.named_scope("sparse_attention.attend"):
+        qg = q.reshape(heads)
+        e, z = _weights(qg, k, sel)
+        zt = z.transpose(2, 0, 1)[..., None]                     # (B, h, g, 1)
+        dog = do.reshape(heads).astype(jnp.float32)
+        # o = (e v) / z:  de = (do . v - do . o) / z,  ds = e de / sqrt(d)
+        dov = (dog / zt).astype(v.dtype)
+        shift = (jnp.sum(dog * o.reshape(heads).astype(jnp.float32), -1,
+                         keepdims=True) / zt).transpose(1, 2, 0, 3)
+        ds = (e.astype(jnp.float32) * d ** -0.5
+              * (jnp.einsum("qhgd,khd->hgqk", dov, v,
+                            preferred_element_type=jnp.float32)
+                 - shift)).astype(k.dtype)
+        dv = jnp.einsum("hgqk,qhgd->khd", e, dov)
+        dq = jnp.einsum("hgqk,khd->qhgd", ds, k).reshape(q.shape)
+        dk = jnp.einsum("hgqk,qhgd->khd", ds, qg)
+        target = _target(e, z)
+    with jax.named_scope("sparse_attention.indexer"):
+        # d KL(target || softmax over S_t of I) / dI = softmax - target
+        p = jax.nn.softmax(jnp.where(sel, scores, -jnp.inf), axis=-1)
+        diq, dik, diw = index_vjp(dkl * jnp.where(sel, p - target, 0.0))
+    return dk, dv, dik, (dq, diq, diw, None)
+
+
+_attend_block.defvjp(_attend_block_fwd, _attend_block_bwd)
+
+
+@register("IndexerSparseAttention")
+def indexer_sparse_attention(query, key, value, index_query, index_key,
+                             index_weight, *, topk, block=256, span=2048,
+                             emit_selection=False):
+    """Causal attention of one sequence over the keys an indexer selects.
+
+    ``query`` (S, Hq, d); ``key`` / ``value`` (S, Hkv, d), ``Hq % Hkv == 0``;
+    ``index_query`` (S, HI, dI), ``index_key`` (S, dI), ``index_weight``
+    (S, HI).  For query t and key s <= t the index score is ``I[t, s] =
+    sum_j w[t, j] relu(qI[t, j] . kI[s]) / sqrt(HI dI)`` in float32;
+    ``tau[t]`` is the ``topk``-th largest of ``I[t, :t+1]`` (none while
+    ``t < topk``), ``S_t = {s <= t : I[t, s] >= tau[t]}``, and the output is
+    softmax attention over ``S_t`` (scale ``d^-1/2``).  The selection carries
+    no gradient.  The softmax is shifted by ``|q| max_s |k_s| d^-1/2`` and not
+    by the row's maximum: the same result while that bound stays under about
+    40 (per-head-normalised queries and keys of 128 dims read 11 to 17), after
+    which ``exp`` underflows.
+
+    -> out (S, Hq, d); kl (): ``sum_t KL(sg(mean over heads of the attention
+    probabilities) || softmax over S_t of I[t, .])``, whose gradient reaches
+    only the index inputs; selected (): ``sum_t |S_t|``; causal (): ``S (S +
+    1) / 2``; with ``emit_selection`` also bits (S, S / 32) uint32, bit
+    ``s % 32`` of word ``s // 32`` of row t set where ``s in S_t``.
+
+    ``block`` queries are scored at a time against the keys up to the end
+    of their ``span`` of queries (tiles: they change no result).  Each
+    block's thresholds are saved for the backward pass, which recomputes
+    the block's scores but not its selection.
+    """
+    S = query.shape[0]
+    span = min(span, S)
+    block = min(block, span)
+    if S % span or span % block or (emit_selection and span % 32):
+        raise ValueError("sequence %d, span %d and block %d must divide "
+                         "(and the span by 32 to emit the selection)"
+                         % (S, span, block))
+    t_all = jnp.arange(S, dtype=jnp.int32)
+    outs = []
+    for end in range(span, S + 1, span):
+        rows = slice(end - span, end)
+        fn = functools.partial(
+            _attend_block, topk, S if emit_selection else 0, key[:end],
+            value[:end], index_key[:end])
+        outs.append(lax.map(fn, tuple(
+            a[rows].reshape((span // block, block) + a.shape[1:])
+            for a in (query, index_query, index_weight, t_all))))
+    o, kl, selected, causal = (jnp.concatenate([r[i] for r in outs])
+                               for i in range(4))
+    res = (o.reshape(query.shape), jnp.sum(kl), jnp.sum(selected),
+           jnp.sum(causal))
+    if emit_selection:
+        res += (jnp.concatenate([r[4] for r in outs]).reshape(S, S // 32),)
+    return res
+
+
+@register("MoEExperts")
+def moe_experts(data, router_weight, gate_weight, up_weight, down_weight, *,
+                top_k, first_expert=0, norm_topk_prob=True, capacity=None):
+    """The held experts' part of a top-k mixture-of-experts layer
+    (``parallel.moe.moe_layer``).  ``data`` (T, D), ``router_weight`` (E, D)
+    over all E experts, ``gate_weight`` / ``up_weight`` (H, D, F) and
+    ``down_weight`` (H, F, D) the H experts held, from ``first_expert``.
+    -> out (T, D), balance (), pairs (H,), dropped (), choice (T, top_k)."""
+    from ..parallel.moe import moe_layer
+
+    y, aux = moe_layer(data, router_weight, gate_weight, up_weight,
+                       down_weight, top_k=top_k, first_expert=first_expert,
+                       normalize=norm_topk_prob, capacity=capacity)
+    return y, aux["balance"], aux["pairs"], aux["dropped"], aux["choice"]

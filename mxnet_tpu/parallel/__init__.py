@@ -44,7 +44,8 @@ from . import dist
 from . import checkpoint
 from .ring import ring_attention, ring_self_attention
 from .pipeline import gpipe, stack_stage_params
-from .moe import moe_ffn, stack_expert_params
+from .moe import (held_experts_ffn, moe_ffn, moe_layer, route,
+                  stack_expert_params)
 
 __all__ = [
     "make_mesh",
@@ -81,5 +82,8 @@ __all__ = [
     "gpipe",
     "stack_stage_params",
     "moe_ffn",
+    "moe_layer",
+    "held_experts_ffn",
+    "route",
     "stack_expert_params",
 ]

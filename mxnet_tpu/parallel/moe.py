@@ -1,28 +1,181 @@
-"""Expert parallelism — Mixture-of-Experts dispatch over an ``ep`` mesh axis.
+"""Expert parallelism — a Mixture-of-Experts layer that is told which
+experts it holds.
 
 Absent from the reference (SURVEY §2.2: EP/MoE "out of scope"); provided
-here because expert parallelism is a first-class TPU distribution strategy:
-each device owns one expert's FFN weights, tokens are routed top-1
-(Switch-Transformer style) with fixed capacity, and two ``all_to_all``
-collectives over ICI move token buffers to their experts and back — the
-GShard dispatch/combine einsum formulation, which keeps everything dense,
-static-shaped, and MXU-friendly (no gather/scatter of ragged groups).
+here because expert parallelism is a first-class TPU distribution strategy.
+The router always has its published width (every expert of the layer); a
+chip holds a contiguous share of the experts and computes its own experts'
+part of the result:
 
-Routing contract: ``n_experts == mesh.shape[axis]``; tokens beyond an
-expert's capacity are dropped (output 0 for that token — standard Switch
-behavior); the router is differentiable through the combine weights.
+* :func:`route` — softmax over all experts in float32, top-k, gates
+  (normalised over the k chosen experts, held here or not);
+* :func:`held_experts_ffn` — the (token, expert) pairs routed to the experts
+  held here, sorted by expert, through grouped matrix products
+  (``jax.lax.ragged_dot``: on a TPU a grouped Mosaic product, one pass over
+  the pairs, no expert computed densely) and summed back per token.  No pair
+  is dropped: the buffer holds ``capacity`` pairs (default: every pair, the
+  worst case) and the layer counts what did not fit, so a caller that sizes
+  it tighter can fail the step on overflow;
+* :func:`moe_layer` — the two together, with the load-balance term.  On one
+  chip it runs without an exchange, and nothing stands in for absent chips;
+* :func:`moe_ffn` — the same routing over an ``ep`` mesh axis, one expert
+  per device, with the two ``all_to_all`` collectives that move fixed-size
+  token buffers to their experts and back (GShard dispatch/combine einsums;
+  tokens beyond a buffer's capacity are dropped there, Switch style).
 """
 from __future__ import annotations
 
-__all__ = ["moe_ffn", "stack_expert_params"]
+import functools
+
+__all__ = ["route", "held_experts_ffn", "moe_layer", "moe_ffn",
+           "stack_expert_params"]
 
 
 from .pipeline import stack_stage_params as stack_expert_params  # same op
 
 
+def route(x, router_w, top_k, normalize=True):
+    """Router of a MoE layer.  ``x`` (T, D), ``router_w`` (E, D).
+    -> probs (T, E) float32 softmax over all E experts, choice (T, k) int32,
+    gates (T, k) float32: the chosen experts' probabilities, divided by
+    their sum when ``normalize``.  The product runs in float32 at the
+    highest precision: a top-k choice flips on the last bits."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = jnp.einsum("td,ed->te", x.astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, choice = jax.lax.top_k(probs, top_k)
+    if normalize:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return probs, choice.astype(jnp.int32), gates
+
+
+def _gather_rows(src, idx):
+    import jax.numpy as jnp
+
+    return jnp.take(src, idx, axis=0, mode="clip")
+
+
+def _dispatch_combine():
+    """``dispatch(x, tok)``: rows of ``x`` (T, D) for each buffered pair ->
+    (C, D).  ``combine(y, pos)``: buffered rows ``y`` (C, D) summed back per
+    token through ``pos`` (T, k), the pair's row in the buffer or C for a
+    pair not held -> (T, D).  ``pos`` and ``tok`` describe one permutation,
+    so each is the other's transpose: both passes are gathers, never a
+    scatter-add over colliding rows."""
+    import jax
+    import jax.numpy as jnp
+
+    def combine_rows(y, pos):
+        padded = jnp.concatenate([y, jnp.zeros((1,) + y.shape[1:], y.dtype)])
+        return jnp.sum(_gather_rows(padded, pos).astype(jnp.float32),
+                       axis=1).astype(y.dtype)
+
+    def dispatch_rows(x, tok, valid):
+        return jnp.where(valid[:, None], _gather_rows(x, tok), 0)
+
+    @jax.custom_vjp
+    def dispatch(x, tok, valid, pos):
+        return dispatch_rows(x, tok, valid)
+
+    def dispatch_fwd(x, tok, valid, pos):
+        return dispatch_rows(x, tok, valid), pos
+
+    def dispatch_bwd(pos, g):
+        return combine_rows(g, pos), None, None, None
+
+    dispatch.defvjp(dispatch_fwd, dispatch_bwd)
+
+    @jax.custom_vjp
+    def combine(y, pos, tok, valid):
+        return combine_rows(y, pos)
+
+    def combine_fwd(y, pos, tok, valid):
+        return combine_rows(y, pos), (tok, valid)
+
+    def combine_bwd(res, g):
+        tok, valid = res
+        return dispatch_rows(g, tok, valid), None, None, None
+
+    combine.defvjp(combine_fwd, combine_bwd)
+    return dispatch, combine
+
+
+def held_experts_ffn(x, choice, gates, gate_w, up_w, down_w, first_expert=0,
+                     capacity=None):
+    """The held experts' part of a MoE layer's result.
+
+    ``x`` (T, D) tokens; ``choice`` / ``gates`` (T, k) from :func:`route`;
+    ``gate_w`` / ``up_w`` (H, D, F) and ``down_w`` (H, F, D): the H experts
+    ``first_expert .. first_expert + H - 1`` of the layer, each a gated
+    feed-forward ``(silu(x Wg) * (x Wu)) Wd``.  ``capacity``: rows of the
+    buffer of held pairs (default ``T * k``, every pair).
+    -> y (T, D): sum over the pairs routed to a held expert of gate x
+    expert(x); pairs (H,) int32: pairs routed to each held expert; dropped
+    () int32: held pairs that did not fit the buffer (0 at the default)."""
+    import jax
+    import jax.numpy as jnp
+
+    T, k = choice.shape
+    H = gate_w.shape[0]
+    C = T * k if capacity is None else min(int(capacity), T * k)
+    local = choice - first_expert
+    held = (local >= 0) & (local < H)
+    flat = jnp.where(held, local, H).reshape(-1)              # (T*k,)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # by expert
+    pairs = jnp.sum(jax.nn.one_hot(flat, H, dtype=jnp.int32), axis=0)
+    starts = jnp.cumsum(pairs) - pairs
+    sizes = jnp.clip(jnp.minimum(pairs, C - starts), 0)       # what fits
+    n_fit = jnp.sum(sizes)
+    dropped = jnp.sum(pairs) - n_fit
+    rows = order[:C]                                          # pair of row c
+    valid = jnp.arange(C) < n_fit
+    tok = rows // k
+    # where each pair sits in the buffer (C: nowhere)
+    pos = jnp.full((T * k,), C, jnp.int32).at[rows].set(
+        jnp.where(valid, jnp.arange(C, dtype=jnp.int32), C)).reshape(T, k)
+    dispatch, combine = _dispatch_combine()
+    xg = dispatch(x, tok, valid, pos)                         # (C, D)
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                            preferred_element_type=x.dtype)
+    h = jax.nn.silu(dot(xg, gate_w)) * dot(xg, up_w)
+    g = jnp.where(valid, gates.reshape(-1)[rows], 0.0)
+    out = jnp.where(valid[:, None],
+                    dot(h, down_w) * g[:, None].astype(x.dtype), 0)
+    return combine(out, pos, tok, valid), pairs, dropped
+
+
+def moe_layer(x, router_w, gate_w, up_w, down_w, *, top_k, first_expert=0,
+              normalize=True, capacity=None):
+    """Router + held experts.  -> (y, aux) with ``aux``: ``balance`` (the
+    load-balance term ``E * sum_e frac_e * mean_t probs[t, e]``, ``frac_e``
+    the pairs routed to expert e over T: Switch / Qwen3-MoE's form, all E
+    experts), ``pairs`` (H,), ``dropped`` (), ``choice`` (T, k)."""
+    import jax
+    import jax.numpy as jnp
+
+    E = router_w.shape[0]
+    with jax.named_scope("moe.route"):
+        probs, choice, gates = route(x, router_w, top_k, normalize)
+        frac = jnp.sum(jax.nn.one_hot(choice.reshape(-1), E,
+                                      dtype=jnp.float32), axis=0) / x.shape[0]
+        balance = E * jnp.sum(jax.lax.stop_gradient(frac)
+                              * jnp.mean(probs, axis=0))
+    with jax.named_scope("moe.experts"):
+        y, pairs, dropped = held_experts_ffn(
+            x, choice, gates, gate_w, up_w, down_w, first_expert, capacity)
+    return y, {"balance": balance, "pairs": pairs, "dropped": dropped,
+               "choice": choice}
+
+
 def moe_ffn(x, gate_w, expert_params, expert_fn, *, mesh, axis="ep",
             capacity_factor=1.25):
-    """Top-1 routed MoE layer over the ``axis`` mesh dimension.
+    """Top-1 routed MoE layer over the ``axis`` mesh dimension: one expert
+    a device, :func:`route` at ``top_k=1`` with the gate left unnormalised
+    (Switch style), fixed-capacity buffers through two ``all_to_all``s.
 
     Parameters
     ----------
@@ -64,10 +217,9 @@ def moe_ffn(x, gate_w, expert_params, expert_fn, *, mesh, axis="ep",
 
     def per_device(x_loc, gw, p_stacked):
         p = jax.tree_util.tree_map(lambda a: a[0], p_stacked)
-        logits = x_loc @ gw                           # (T, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        expert = jnp.argmax(probs, axis=-1)           # (T,)
-        gate = jnp.take_along_axis(probs, expert[:, None], axis=1)[:, 0]
+        _, choice, gates = route(x_loc, gw.T, 1, normalize=False)
+        expert = choice[:, 0]                         # (T,)
+        gate = gates[:, 0].astype(x_loc.dtype)        # its probability
         # slot counting in int32: token dtype may be bf16, whose integers
         # stop being exact at 256 — silent slot collisions otherwise
         onehot = jax.nn.one_hot(expert, E, dtype=jnp.int32)    # (T, E)
