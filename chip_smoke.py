@@ -14,6 +14,9 @@ It drives the main path once at full width, in this one process:
    holds the Mosaic custom calls for dconv forward, dconv backward and NMS.
 2. Every Pallas kernel that is default-on for TPU, non-interpreted, against
    the repo's XLA / jnp formulation of the same operator.
+   Beside them ``psroi_leg``: deformable PS-ROI pooling alone (no kernel:
+   XLA's one-hot path) at the step's three shapes, against its gather path,
+   with milliseconds a call.
 3. ``Module.fit`` on the symbolic ResNet-50 (224x224, 1000 classes): Symbol
    -> Executor -> ``FusedStepper`` -> optimizer, with the recipe's
    ``context=mx.current_context()``; the fused step must engage and the
@@ -290,6 +293,19 @@ def nms_leg(boxes=6000, batch=2, interpret=False):
     return facts
 
 
+def per_call_ms(calls, fn, *args):
+    """Milliseconds a call of a jitted ``fn`` over ``calls`` chained calls,
+    after one that compiles it."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    t = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return round((time.perf_counter() - t) / calls * 1e3, 3)
+
+
 # the leg's three draws of deformable samples: offsets below one cell (the
 # benchmark's seeded offset branches, and how every fine-tune starts), up to
 # three cells (trained res5 offsets), and uniform over the map (no detector
@@ -332,14 +348,6 @@ def dconv_leg(bg=32, channels=128, hw=(38, 64), interpret=False, calls=20):
         grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(*flts)
         return [np.asarray(v.astype(jnp.float32)) for v in (out,) + grads]
 
-    def per_call_ms(fn, *args):
-        jax.block_until_ready(fn(*args))
-        t = time.perf_counter()
-        for _ in range(calls):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return round((time.perf_counter() - t) / calls * 1e3, 3)
-
     # the backward kernel alone: the forward's output is dead code here
     backward = jax.jit(lambda ints, flts, g: jax.vjp(
         lambda *a: fused(*ints, *a), *flts)[1](g))
@@ -374,9 +382,75 @@ def dconv_leg(bg=32, channels=128, hw=(38, 64), interpret=False, calls=20):
             "rel_err": errs,
             "band_share": round(float(pk.dconv_band_share(
                 ints[0], ints[1], hw)), 4),
-            "fwd_ms": per_call_ms(forward, *ints, *flts),
-            "bwd_ms": per_call_ms(backward, ints, flts, g)}
+            "fwd_ms": per_call_ms(calls, forward, *ints, *flts),
+            "bwd_ms": per_call_ms(calls, backward, ints, flts, g)}
     return {"bg": bg, "dconv": facts}
+
+
+# the R-FCN head's three poolings (model_zoo/detection.py ``_head``): name,
+# output_dim, no_trans
+PSROI_POOLINGS = (("offsets", 2, True), ("classes", 81, False),
+                  ("boxes", 8, False))
+
+
+def psroi_leg(batch=8, rois=128, hw=(38, 64), poolings=PSROI_POOLINGS, k=7,
+              calls=20):
+    """``deformable_psroi_pooling`` alone at the benchmark head's three
+    shapes (``rois`` grouped rois an image over a res5 map, bf16): the
+    one-hot path's first roi against the gather path's (one roi alone is
+    under the threshold), then milliseconds a call forward, and forward +
+    backward to the data and the offsets.  The operator by itself: the
+    step's time is the benchmark's to say."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.detection import deformable_psroi_pooling
+
+    H, W = hw
+    R = batch * rois
+    rng = np.random.RandomState(0)
+    box = np.zeros((R, 5), np.float32)
+    box[:, 0] = np.repeat(np.arange(batch), rois)
+    ctr = rng.rand(R, 2) * np.array([W, H]) * 16.0
+    wh = rng.rand(R, 2) * np.array([W, H]) * 8.0 + 16.0
+    box[:, 1:3], box[:, 3:5] = ctr - wh / 2, ctr + wh / 2  # some overhang
+    box = jnp.asarray(box)
+    trans = jnp.asarray(0.5 * rng.randn(R, 2, k, k).astype(np.float32)
+                        ).astype(jnp.bfloat16)
+    facts = {}
+    for name, od, no_trans in poolings:
+        kw = dict(spatial_scale=1.0 / 16, output_dim=od, group_size=k,
+                  pooled_size=k, part_size=k, trans_std=0.1,
+                  no_trans=no_trans)
+        data = jnp.asarray(rng.randn(batch, od * k * k, H, W)
+                           .astype(np.float32)).astype(jnp.bfloat16)
+        cot = jnp.cos(jnp.arange(R * od * k * k, dtype=jnp.float32)
+                      ).reshape(R, od, k, k)
+
+        def pool(d, t, kw=kw):
+            return deformable_psroi_pooling(d, box, t, rois_per_image=rois,
+                                            **kw)
+
+        forward = jax.jit(pool)
+        both = jax.jit(jax.value_and_grad(
+            lambda d, t: jnp.sum(pool(d, t).astype(jnp.float32) * cot),
+            argnums=(0, 1)))
+        got = np.asarray(forward(data, trans)[:1].astype(jnp.float32))
+        want = np.asarray(deformable_psroi_pooling(
+            data, box[:1], trans[:1], **kw).astype(jnp.float32))
+        if not np.isfinite(got).all():
+            raise AssertionError("PS-ROI pooling (%s) is not finite" % name)
+        err = float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-6))
+        # bf16 data on both sides, f32 accumulation: A's entries round to
+        # bf16 on the one-hot path only; allow 4 bf16 ulps of the largest
+        if err > 2.0 ** -6:
+            raise AssertionError("PS-ROI one-hot path disagrees with the "
+                                 "gather path (%s): %.3g" % (name, err))
+        facts[name] = {
+            "rel_err": float("%.3g" % err),
+            "fwd_ms": per_call_ms(calls, forward, data, trans),
+            "fwd_bwd_ms": per_call_ms(calls, both, data, trans)}
+    return {"rois": R, "psroi": facts}
 
 
 def module_fit_leg(num_layers=50, image=224, classes=1000, batch=32,
@@ -456,7 +530,7 @@ def main():
     say("rfcn 1 chip: %s" % json.dumps(single))
     check_step_kernels(single)
 
-    for leg in (quant_leg, nms_leg, dconv_leg):
+    for leg in (quant_leg, nms_leg, dconv_leg, psroi_leg):
         say("%s vs XLA/jnp: %s" % (leg.__name__, json.dumps(leg())))
 
     facts = module_fit_leg()

@@ -222,13 +222,12 @@ def main():
     # new kernel spaces AND the non-kernel layout space all record lines
     sweep_out = run(
         [py, at, "search", "--all-kernels", "--warmup", "0", "--repeat",
-         "1", "--n", "96", "--nms-boxes", "256", "--ab-n", "64",
-         "--q-rows", "256", "--fs-steps", "2"], env=env18)
+         "1", "--n", "96", "--nms-boxes", "256", "--q-rows", "256",
+         "--fs-steps", "2"], env=env18)
     swept = {d["kernel"]: d for d in autotune_lines(sweep_out)
              if "kernel" in d}
-    for kern in ("nms_alive_pallas", "psroi_abuild_pallas",
-                 "quantize_int8_pallas", "dequantize_int8_pallas",
-                 "fused_step_layout"):
+    for kern in ("nms_alive_pallas", "quantize_int8_pallas",
+                 "dequantize_int8_pallas", "fused_step_layout"):
         assert kern in swept, "--all-kernels skipped %s" % kern
         assert swept[kern]["cached"] or swept[kern]["measurements"] > 0, \
             swept[kern]

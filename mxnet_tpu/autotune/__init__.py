@@ -44,8 +44,7 @@ from .measure import (failed_measurements, measure_candidate, measurements,
 from .search import predict_then_measure
 from .search import search as run_search
 from .space import (TuningSpace, dconv_shape_sig, fused_step_sig, get_space,
-                    nms_shape_sig, psroi_shape_sig, quant_shape_sig,
-                    register_space, spaces)
+                    nms_shape_sig, quant_shape_sig, register_space, spaces)
 from .store import (clear, config_for, enabled, entries, lookup, override,
                     record, stats, store_path)
 
@@ -56,8 +55,7 @@ __all__ = [
     "failed_measurements", "measure_candidate", "measurements",
     "time_callable", "predict_then_measure", "run_search",
     "TuningSpace", "dconv_shape_sig", "fused_step_sig", "get_space",
-    "nms_shape_sig", "psroi_shape_sig", "quant_shape_sig",
-    "register_space", "spaces",
+    "nms_shape_sig", "quant_shape_sig", "register_space", "spaces",
     "clear", "config_for", "enabled", "entries", "lookup", "override",
     "record", "stats", "store_path", "tuned_ladder",
 ]

@@ -19,9 +19,6 @@ Registered spaces (this module, at import):
 * ``nms_alive_pallas`` — the box-tile size ``tile`` of the blocked greedy
   NMS kernel (lane-aligned multiples of 128; ``nms_fits_vmem`` prunes
   tiles whose per-image working set would blow VMEM at the problem's N).
-* ``psroi_abuild_pallas`` — the rois-per-grid-step block ``rb`` of the
-  deformable-PSROI accumulation-matrix kernel, fwd+bwd (the backward is
-  the larger pass; ``abuild_fits_vmem`` prunes on it).
 * ``quantize_int8_pallas`` / ``dequantize_int8_pallas`` — the row-block
   ``block`` of the tiled elementwise int8 kernels (``quant_fits_vmem``).
 * ``fused_step_layout`` — the one NON-kernel space (ISSUE 18): fused
@@ -37,8 +34,8 @@ from __future__ import annotations
 import itertools
 
 __all__ = ["TuningSpace", "register_space", "get_space", "spaces",
-           "dconv_shape_sig", "nms_shape_sig", "psroi_shape_sig",
-           "quant_shape_sig", "fused_step_sig"]
+           "dconv_shape_sig", "nms_shape_sig", "quant_shape_sig",
+           "fused_step_sig"]
 
 _SPACES = {}
 
@@ -183,38 +180,6 @@ register_space(TuningSpace(
     params={"tile": (128, 256, 512, 1024)},
     default={"tile": 256},   # the shipped _NMS_TILE
     constraint=_nms_constraint))
-
-
-# -- psroi_abuild_pallas ------------------------------------------------------
-def psroi_shape_sig(N, S, H, W, itemsize):
-    """Shape signature of one accumulation-matrix build: rois × sample
-    points × bin map dims × the out/grad itemsize (fwd keys on the output
-    dtype, bwd on the cotangent's — both route through the same space)."""
-    return "N%d-S%d-H%d-W%d-i%d" % (int(N), int(S), int(H), int(W),
-                                    int(itemsize))
-
-
-def _abuild_constraint(config, N=None, S=None, H=None, W=None, itemsize=4,
-                       **_):
-    """The candidate's EFFECTIVE block (rb caps at N at the dispatch site)
-    must keep the backward working set inside the shared VMEM budget."""
-    from ..ops.pallas_kernels import abuild_fits_vmem
-
-    if S is None or H is None or W is None:
-        return True
-    rb = int(config["rb"])
-    if rb < 1:
-        return False
-    if N is not None:
-        rb = min(rb, int(N))
-    return abuild_fits_vmem(int(S), int(H), int(W), int(itemsize), rb=rb)
-
-
-register_space(TuningSpace(
-    "psroi_abuild_pallas",
-    params={"rb": (16, 32, 64, 128, 256)},
-    default={"rb": 64},      # the shipped _ABUILD_RB
-    constraint=_abuild_constraint))
 
 
 # -- quantize/dequantize_int8_pallas ------------------------------------------

@@ -28,6 +28,7 @@ here XLA emits the scatter-add from the gather's transpose automatically).
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -86,38 +87,6 @@ def _check_grouped_layout(batch_idx, B, Rb, op):
             "proposal_target, or drop the rois_per_image hint."
             % (op, Rb, Rb, bad, int(idx.reshape(-1)[bad]),
                int(expect.reshape(-1)[bad])))
-
-
-def _abuild(yv, xv, out_dtype):
-    """A[n, h, w] = Σ_s yv[n, s, h]·xv[n, s, w] — the separable one-hot
-    accumulation-matrix build shared by the pooling paths below.
-
-    XLA lowers this einsum as a convolution whose spp2(=16)-deep
-    contraction pads to 128 lanes — the round-5 batch-8 chip trace measured
-    those kernels at ~48 GB/s, 33 ms/step of a 227 ms north-star step, and
-    a Pallas MXU kernel (``pallas_kernels.psroi_abuild_pallas``) beats the
-    einsum 10 vs 35 us standalone.  The einsum stays the DEFAULT anyway:
-    measured in-module (rfcn_account.py, batch 8), the custom calls
-    serialize against the TensorCore and force the one-hot factors yv/xv
-    to materialize through HBM instead of fusing into the build — module
-    wall 227 -> 264 ms, headline 33.8 -> 29.2 img/s.  The "slow" conv
-    lowering wins because it FUSES the compare/lerp producers and overlaps
-    with backbone compute (same lesson as the round-4 scan-unroll red
-    herring: judge module wall, not op-lane composition).
-    ``MXNET_ABUILD_IMPL=pallas`` opts in (future chips / other shapes);
-    ``=xla`` pins the einsum.
-    """
-    impl = os.environ.get("MXNET_ABUILD_IMPL", "xla")
-
-    if impl == "pallas":
-        from .pallas_kernels import psroi_abuild_pallas
-
-        return jax.lax.platform_dependent(
-            tpu=lambda: psroi_abuild_pallas(yv, xv, out_dtype, False),
-            default=lambda: psroi_abuild_pallas(yv, xv, out_dtype, True))
-    return jnp.einsum(
-        "nsh,nsw->nhw", yv, xv,
-        precision=jax.lax.Precision.HIGHEST).astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -471,7 +440,6 @@ def deformable_psroi_pooling(
     part_h = np.asarray((np.arange(PH) * part) // PH)  # (PH,)
     part_w = np.asarray((np.arange(PW) * part) // PW)
 
-    su = jnp.arange(spp, dtype=f32)
     r1 = (slice(None), None, None, None)  # (R,) -> (R,1,1,1)
     K = num_classes
 
@@ -485,71 +453,71 @@ def deformable_psroi_pooling(
         ty = t[:, :, 1][:, :, part_h][:, :, :, part_w] * trans_std
     wst = jnp.arange(PW, dtype=f32)[None, None, None, :] * bs_w[r1] + xs[r1] + tx * roi_w[r1]
     hst = jnp.arange(PH, dtype=f32)[None, None, :, None] * bs_h[r1] + ys[r1] + ty * roi_h[r1]
-    # sample grid (R, K, PH, PW, spp, spp)
-    sy = hst[..., None, None] + su[None, None, None, None, :, None] * sub_h[:, None, None, None, None, None]
-    sx = wst[..., None, None] + su[None, None, None, None, None, :] * sub_w[:, None, None, None, None, None]
-    sy, sx = jnp.broadcast_arrays(sy, sx)
-    # inclusive boundary: sample at exactly ±0.5 survives (reference
-    # skips only w < −0.5 / w > W−0.5, deformable_psroi_pooling.cc:159)
-    live = (sx >= -0.5) & (sx <= W - 0.5) & (sy >= -0.5) & (sy <= H - 0.5)
-    syc = jnp.clip(sy, 0.0, H - 1.0)
-    sxc = jnp.clip(sx, 0.0, W - 1.0)
-    y0 = jnp.floor(syc).astype(jnp.int32)
-    x0 = jnp.floor(sxc).astype(jnp.int32)
-    y1 = jnp.minimum(y0 + 1, H - 1)
-    x1 = jnp.minimum(x0 + 1, W - 1)
-    ly = syc - y0.astype(f32)
-    lx = sxc - x0.astype(f32)
-    lf = live.astype(f32)
-    cnt = lf.sum(axis=(4, 5))[..., None]  # (R, K, PH, PW, 1)
 
-    spp2 = spp * spp
+    def axis_samples(start, sub, size):
+        """One axis of a bin's sample grid, on (R, K, PH, PW, spp): the two
+        corner indices, the second corner's weight and the live mask.  The
+        spp × spp grid is the product of its two axes (a sample's row
+        depends on its row index only, its column on its column index, as in
+        deformable_psroi_pooling.cc), and so is everything built from it."""
+        s = start[..., None] + jnp.arange(spp, dtype=f32) * sub[:, None, None, None, None]
+        # inclusive boundary: sample at exactly ±0.5 survives (reference
+        # skips only w < −0.5 / w > W−0.5, deformable_psroi_pooling.cc:159)
+        live = ((s >= -0.5) & (s <= size - 0.5)).astype(f32)
+        sc = jnp.clip(s, 0.0, size - 1.0)
+        i0 = jnp.floor(sc).astype(jnp.int32)
+        return i0, jnp.minimum(i0 + 1, size - 1), sc - i0.astype(f32), live
+
+    y0, y1, ly, lfy = axis_samples(hst, sub_h, H)
+    x0, x1, lx, lfx = axis_samples(wst, sub_w, W)
+    cnt = (lfy.sum(-1) * lfx.sum(-1))[..., None]  # (R, K, PH, PW, 1)
+
     Rb = int(rois_per_image)
     grouped = Rb > 0 and R == B * Rb
     if grouped:
         _check_grouped_layout(batch_idx, B, Rb, "DeformablePSROIPooling")
-    if R * K * PH * PW * spp2 * ch_per_class >= (1 << 16):
-        # -- separable one-hot matmul path (TPU hot path) -----------------
+    if R * K * PH * PW * spp * spp * ch_per_class >= (1 << 16):
+        # -- rank-one one-hot matmul path (TPU hot path) ------------------
         # Per bin (k, ph, pw): accumulate every (roi, sample)'s live-masked
         # bilinear footprint into a dense accumulation matrix A and multiply
         # by that bin's flattened plane.  Both directions are MXU matmuls —
         # no gather OR scatter touches HBM (the scatter-add XLA derives from
         # a gather formulation measured ~580 ms/step at north-star shapes).
         #
-        # The 4-corner footprint is SEPARABLE:
-        #   Σ_corners w_c·e(y_c,x_c) = [(1−ly)e_{y0}+ly·e_{y1}] ⊗
-        #                              [(1−lx)e_{x0}+lx·e_{x1}]
-        # so A[r] = Σ_s yv[r,s,:] ⊗ xv[r,s,:] — a rank-spp2 outer-product
-        # batch matmul.  One-hot compares run over H and W separately
-        # (~(H+W)/(H·W)·¼ of the fused-compare cost that profiled as ~70
-        # ms/step of VPU time at batch 4) and the contraction rides the MXU.
+        # A sample's 4-corner footprint is separable,
+        #   [(1−ly)e_{y0} + ly·e_{y1}] ⊗ [(1−lx)e_{x0} + lx·e_{x1}],
+        # and so is the grid of samples, hence their sum is RANK ONE:
+        #   A[r] = (Σ_i lfy_i·Y_i) ⊗ (Σ_j lfx_j·X_j) = ay[r] ⊗ ax[r]
+        # — one broadcast multiply (a batch of spp²-deep products lowers to
+        # convolutions that run at 0.4 % of the MXU).  The one-hot compares
+        # run over H and W separately, spp deep, for all bins at once.
         # Grouped (batch-major) rois additionally make the plane matmul
         # block-diagonal: (B, Rb, H·W) per-image blocks instead of one
         # (R, B·H·W) matrix — O(B), not O(B²), in batch.
         hw = H * W
-        bhw = B * hw
         NB = K * PH * PW
+        lead = (B, Rb) if grouped else (R,)
+
+        # remat here too: AD would save the (NB, R, spp, size) one-hot
+        # masks; the samples they are rebuilt from are spp numbers a bin
+        @functools.partial(jax.checkpoint, static_argnums=(4,))
+        def axis_weights(i0, i1, l, lf, size):
+            """(R,K,PH,PW,spp) samples -> (NB,) + lead + (size,): each
+            bin's live bilinear weight on every index of the axis."""
+            def bins(a):
+                return a.transpose(1, 2, 3, 0, 4).reshape((NB,) + lead + (spp, 1))
+            iota = jnp.arange(size, dtype=jnp.int32)
+            return (bins(lf * (1.0 - l)) * (bins(i0) == iota)
+                    + bins(lf * l) * (bins(i1) == iota)).sum(axis=-2)
 
         if grouped:
-            def to_bins(a, dt):  # (R=B·Rb,K,PH,PW,spp,spp) -> (NB,B,Rb,spp2)
-                return (a.astype(dt).reshape(B, Rb, K, PH, PW, spp2)
-                        .transpose(2, 3, 4, 0, 1, 5).reshape(NB, B, Rb, spp2))
+            ay = axis_weights(y0, y1, ly, lfy, H)
         else:
-            def to_bins(a, dt):  # -> (NB, R, spp2)
-                return (a.astype(dt).reshape(R, K, PH, PW, spp2)
-                        .transpose(1, 2, 3, 0, 4).reshape(NB, R, spp2))
-
-        # ungrouped: the batch offset rides in the row index (gy = b·H + y,
-        # flat position gy·W + x ≡ b·hw + y·W + x — matches plane layout)
-        yoff = (0 if grouped
-                else batch_idx[:, None, None, None, None, None] * H)
-        ybins0 = to_bins(y0 + yoff, jnp.int32)
-        ybins1 = to_bins(y1 + yoff, jnp.int32)
-        xbins0 = to_bins(x0, jnp.int32)
-        xbins1 = to_bins(x1, jnp.int32)
-        lybins = to_bins(ly, f32)
-        lxbins = to_bins(lx, f32)
-        lfbins = to_bins(lf, f32)
+            # the batch offset rides in the row index (gy = b·H + y, flat
+            # position gy·W + x ≡ b·hw + y·W + x — matches plane layout)
+            yoff = (batch_idx * H)[:, None, None, None, None]
+            ay = axis_weights(y0 + yoff, y1 + yoff, ly, lfy, B * H)
+        ax = axis_weights(x0, x1, lx, lfx, W)
 
         # per-bin flattened planes from the position-sensitive channel map:
         # grouped (NB, B, H·W, cpc), ungrouped (NB, B·H·W, cpc)
@@ -559,37 +527,23 @@ def deformable_psroi_pooling(
         planes = planes[kb, gb]  # (NB, B, hw, cpc)
         if not grouped:
             # B already precedes hw, so the flat index stays b·hw + y·W + x
-            planes = planes.reshape(NB, bhw, ch_per_class)
+            planes = planes.reshape(NB, B * hw, ch_per_class)
 
-        iota_y = jnp.arange(H if grouped else B * H, dtype=jnp.int32)
-        iota_x = jnp.arange(W, dtype=jnp.int32)
         # fp32 inputs must not silently drop to the TPU's default bf16
-        # matmul passes (~5e-3 pooled-score error, measured); the A-build
-        # einsum always runs HIGHEST — its cost is trivial and the old
-        # compare-select formulation accumulated exactly in f32
+        # matmul passes (~5e-3 pooled-score error, measured); ay, ax and
+        # their product are always f32
         prec = (jax.lax.Precision.HIGHEST
                 if datag.dtype == jnp.float32 else None)
 
-        # remat: without it, AD saves each bin's A (and yv/xv) as residuals
-        # (~0.5 GB over 49 bins at north-star shapes); rebuilding them in
-        # the backward is a handful of fused element ops + tiny matmuls
+        # remat: without it, AD saves each bin's A as a residual (~0.7 GB
+        # over 147 bins at north-star shapes); rebuilding it in the backward
+        # is one fused multiply
         @jax.checkpoint
         def one_bin(args):
-            yb0, yb1, xb0, xb1, lyb, lxb, lfb, plane = args
-            yv = ((1.0 - lyb)[..., None] * (yb0[..., None] == iota_y)
-                  + lyb[..., None] * (yb1[..., None] == iota_y))
-            xv = lfb[..., None] * (
-                (1.0 - lxb)[..., None] * (xb0[..., None] == iota_x)
-                + lxb[..., None] * (xb1[..., None] == iota_x))
-            if grouped:
-                # (B,Rb,spp2,H) ⊗ (B,Rb,spp2,W) -> (B,Rb,hw) block-diagonal
-                a = _abuild(yv.reshape(B * Rb, spp2, H),
-                            xv.reshape(B * Rb, spp2, W), datag.dtype)
-                a = a.reshape(B, Rb, hw)
-                return jnp.einsum("brp,bpc->brc", a, plane, precision=prec)
-            a = _abuild(yv, xv, datag.dtype)  # (R, B·H or H, W)
-            a = a.reshape(a.shape[0], bhw)
-            return jnp.matmul(a, plane, precision=prec)
+            ayb, axb, plane = args
+            a = (ayb[..., :, None] * axb[..., None, :]).astype(datag.dtype)
+            # grouped (B,Rb,hw) @ (B,hw,cpc); ungrouped (R,B·hw) @ (B·hw,cpc)
+            return jnp.matmul(a.reshape(lead + (-1,)), plane, precision=prec)
 
         # full unroll for typical bin counts (NB=49): measured A/B at the
         # batch-8 north star — unroll=NB 33.8 img/s vs unroll=7 32.8 (~3%;
@@ -598,16 +552,13 @@ def deformable_psroi_pooling(
         # Unusual group sizes keep a partial unroll to bound code size.
         unroll = NB if NB <= 64 else 7
         _, s = jax.lax.scan(
-            lambda _, args: (None, one_bin(args)), None,
-            (ybins0, ybins1, xbins0, xbins1, lybins, lxbins, lfbins, planes),
-            unroll=unroll)  # grouped (NB, B, Rb, cpc) / ungrouped (NB, R, cpc)
-        if grouped:
-            s = (s.reshape(K, PH, PW, B, Rb, ch_per_class)
-                 .transpose(3, 4, 0, 1, 2, 5).reshape(R, K, PH, PW, ch_per_class))
-        else:
-            s = s.reshape(K, PH, PW, R, ch_per_class).transpose(3, 0, 1, 2, 4)
+            lambda _, args: (None, one_bin(args)), None, (ay, ax, planes),
+            unroll=unroll)  # (NB,) + lead + (cpc,)
+        s = (s.reshape(K, PH, PW, R, ch_per_class)
+             .transpose(3, 0, 1, 2, 4))  # (R, K, PH, PW, cpc)
     else:
         # -- gather path (small problems / CPU) ---------------------------
+        # one gather per corner of each of the spp × spp samples; the
         # batch index rides in the gather (a vmapped ``data[b]`` would
         # materialize an (R, C, H, W) copy — 11.6 GB at COCO eval scale).
         # With the grouped hint the index comes from the layout (r // Rb),
@@ -619,15 +570,17 @@ def deformable_psroi_pooling(
         b_idx = row_img[:, None, None, None, None, None]
         k_idx = jnp.arange(K)[None, :, None, None, None, None]
         g_idx = ghw[None, None, :, :, None, None]
-        lyn = ly[..., None]
-        lxn = lx[..., None]
+        # rows of the sample grid on axis 4, columns on axis 5
+        yy0, yy1, lyn = y0[..., :, None], y1[..., :, None], ly[..., :, None, None]
+        xx0, xx1, lxn = x0[..., None, :], x1[..., None, :], lx[..., None, :, None]
         v = (
-            datag[b_idx, k_idx, g_idx, y0, x0] * (1 - lyn) * (1 - lxn)
-            + datag[b_idx, k_idx, g_idx, y0, x1] * (1 - lyn) * lxn
-            + datag[b_idx, k_idx, g_idx, y1, x0] * lyn * (1 - lxn)
-            + datag[b_idx, k_idx, g_idx, y1, x1] * lyn * lxn
+            datag[b_idx, k_idx, g_idx, yy0, xx0] * (1 - lyn) * (1 - lxn)
+            + datag[b_idx, k_idx, g_idx, yy0, xx1] * (1 - lyn) * lxn
+            + datag[b_idx, k_idx, g_idx, yy1, xx0] * lyn * (1 - lxn)
+            + datag[b_idx, k_idx, g_idx, yy1, xx1] * lyn * lxn
         )  # (R, K, PH, PW, spp, spp, cpc)
-        s = (v * lf[..., None]).sum(axis=(4, 5))  # (R, K, PH, PW, cpc)
+        lf = lfy[..., :, None, None] * lfx[..., None, :, None]
+        s = (v * lf).sum(axis=(4, 5))  # (R, K, PH, PW, cpc)
 
     out = jnp.where(cnt > 0, s.astype(f32) / jnp.maximum(cnt, 1.0),
                     jnp.zeros((), f32))

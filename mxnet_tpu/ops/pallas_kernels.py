@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["quantize_int8_pallas", "dequantize_int8_pallas", "supported",
-           "nms_alive_pallas", "psroi_abuild_pallas", "dconv_col_pallas",
+           "nms_alive_pallas", "dconv_col_pallas",
            "register_cost", "cost_fns", "registered_custom_calls",
            "traced_costs", "reset_traced_costs"]
 
@@ -167,25 +167,6 @@ def cost_nms_alive(batch, n_boxes):
     flops = pair_tiles * T * T * 18
     # cols (8, Np) + colst (Np, 8) fp32 in, alive (1, Np) fp32 out, per image
     bytes_accessed = int(batch) * (2 * 8 * np_ * 4 + np_ * 4)
-    return {"flops": flops, "bytes_accessed": bytes_accessed}
-
-
-@register_cost("psroi_abuild_pallas_fwd",
-               aliases=("psroi_abuild", "abuild_fwd"))
-def cost_psroi_abuild_fwd(n, s, h, w, out_itemsize=4):
-    # per roi: (H,S)@(S,W) dot
-    flops = 2 * n * s * h * w
-    bytes_accessed = 4 * n * s * (h + w) + out_itemsize * n * h * w
-    return {"flops": flops, "bytes_accessed": bytes_accessed}
-
-
-@register_cost("psroi_abuild_pallas_bwd", aliases=("abuild_bwd",))
-def cost_psroi_abuild_bwd(n, s, h, w, g_itemsize=4):
-    # two dots per roi: dy = x @ g^T and dx = y @ g
-    flops = 4 * n * s * h * w
-    bytes_accessed = (4 * n * s * (h + w)          # yv, xv in
-                      + g_itemsize * n * h * w     # g in
-                      + 4 * n * s * (h + w))       # dy, dx out
     return {"flops": flops, "bytes_accessed": bytes_accessed}
 
 
@@ -613,164 +594,6 @@ def nms_alive_pallas(boxes, valid, ids, *, thresh, plus_one=1.0,
 
 
 # ---------------------------------------------------------------------------
-# Deformable-PSROI accumulation-matrix build (round-5 north-star kernel)
-# ---------------------------------------------------------------------------
-#
-# The pooling's separable one-hot path builds, per bin, a dense accumulation
-# matrix A[r, h, w] = sum_s yv[r, s, h] * xv[r, s, w] (rank-spp2 outer
-# product; ops/detection.py deformable_psroi_pooling).  XLA lowers that
-# einsum as a convolution whose K=spp2(=16) contraction pads to 128 lanes —
-# the round-5 batch-8 chip trace showed those kernels at ~48 GB/s, ~33
-# ms/step of a 227 ms step (15%), against a ~6 us/bin write-bound floor.
-# Here the contraction runs as one small MXU dot per roi with the block
-# resident in VMEM; measured ~10 us vs ~35-60 us for the einsum at
-# north-star shapes (B=8, Rb=128, spp2=16, 38x64 map).
-
-_ABUILD_RB = 64  # rois per grid step; 64 measured >> 32 (grid overhead)
-
-
-def abuild_vmem_bytes(S, H, W, itemsize, rb=_ABUILD_RB):
-    """Estimated per-grid-step VMEM working set of the abuild BACKWARD
-    kernel (the larger pass): the yv/xv input blocks plus the dy/dx
-    output blocks (all f32, (rb, S, H|W)), and the incoming g block with
-    its f32 upcast ((rb, H, W)).  Shares dconv's calibrated 24 MB
-    budget; overcounting stance as ``dconv_bwd_vmem_bytes``."""
-    return rb * (8 * int(S) * (int(H) + int(W))
-                 + (int(itemsize) + 4) * int(H) * int(W))
-
-
-def abuild_fits_vmem(S, H, W, itemsize, rb=_ABUILD_RB):
-    """True when a candidate roi block fits the shared VMEM budget — the
-    autotuner's admission guard for the ``psroi_abuild_pallas`` space
-    (ISSUE 18) and the adoption-time re-check in :func:`_abuild_rb`."""
-    return abuild_vmem_bytes(S, H, W, itemsize, rb=rb) <= _vmem_limit()
-
-
-def _abuild_rb(N, S, H, W, itemsize):
-    """Roi-block size for one abuild problem (trace time only, the
-    ``_dconv_grid`` adoption idiom): hand-tuned ``_ABUILD_RB`` unless
-    ``MXNET_AUTOTUNE`` holds a winner for this (device kind, shape
-    signature), re-validated against the VMEM guard at its EFFECTIVE
-    size (caps at N).  Gate unset = one env read, byte-identical."""
-    rb = _ABUILD_RB
-    from ..base import env_flag
-
-    if env_flag("MXNET_AUTOTUNE"):
-        from .. import autotune
-
-        cfg = autotune.config_for(
-            "psroi_abuild_pallas",
-            autotune.psroi_shape_sig(N, S, H, W, itemsize))
-        if cfg:
-            try:
-                adopted = int(cfg["rb"])
-            except (KeyError, TypeError, ValueError):
-                adopted = None  # malformed winner: keep the default
-            if adopted is not None and adopted >= 1 and abuild_fits_vmem(
-                    S, H, W, itemsize, rb=min(adopted, N)):
-                rb = adopted
-    return min(rb, N)
-
-
-def _abuild_fwd_kernel_factory(rb, out_dtype):
-    def kern(y_ref, x_ref, o_ref):
-        for r in range(rb):
-            # (H, S) @ (S, W) with exact f32 accumulation: A feeds box
-            # scores, bf16 products shift pooled values ~5e-3 (measured;
-            # see the einsum's HIGHEST note in ops/detection.py)
-            o_ref[r] = jnp.dot(
-                y_ref[r].T, x_ref[r], precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32).astype(out_dtype)
-    return kern
-
-
-def _abuild_bwd_kernel_factory(rb):
-    def kern(y_ref, x_ref, g_ref, dy_ref, dx_ref):
-        for r in range(rb):
-            g = g_ref[r].astype(jnp.float32)
-            # d_yv[s, h] = sum_w g[h, w] xv[s, w];  d_xv[s, w] = yv @ g
-            dy_ref[r] = jnp.dot(
-                x_ref[r], g.T, precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)
-            dx_ref[r] = jnp.dot(
-                y_ref[r], g, precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)
-    return kern
-
-
-def _abuild_pad(a, n_pad):
-    return a if n_pad == a.shape[0] else jnp.pad(
-        a, ((0, n_pad - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def psroi_abuild_pallas(yv, xv, out_dtype, interpret=False):
-    """A[n, h, w] = sum_s yv[n, s, h] * xv[n, s, w] on the MXU via Pallas.
-
-    yv: (N, S, H) f32, xv: (N, S, W) f32 -> (N, H, W) ``out_dtype``; exact
-    f32 accumulation (== the einsum-HIGHEST formulation), differentiable via
-    custom VJP (both directions are the same per-roi small-dot pattern).
-    """
-    return _abuild_impl(yv, xv, out_dtype, interpret)
-
-
-def _abuild_impl(yv, xv, out_dtype, interpret):
-    from jax.experimental import pallas as pl
-
-    N, S, H = yv.shape
-    W = xv.shape[2]
-    _record_cost(
-        "psroi_abuild_pallas_fwd",
-        cost_psroi_abuild_fwd(N, S, H, W, jnp.dtype(out_dtype).itemsize),
-        yv.shape)
-    rb = _abuild_rb(N, S, H, W, jnp.dtype(out_dtype).itemsize)
-    n_pad = -(-N // rb) * rb
-    out = pl.pallas_call(
-        _abuild_fwd_kernel_factory(rb, out_dtype),
-        out_shape=jax.ShapeDtypeStruct((n_pad, H, W), out_dtype),
-        grid=(n_pad // rb,),
-        in_specs=[pl.BlockSpec((rb, S, H), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((rb, S, W), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((rb, H, W), lambda i: (i, 0, 0)),
-        interpret=interpret,
-    )(_abuild_pad(yv, n_pad), _abuild_pad(xv, n_pad))
-    return out[:N]
-
-
-def _abuild_fwd(yv, xv, out_dtype, interpret):
-    return _abuild_impl(yv, xv, out_dtype, interpret), (yv, xv)
-
-
-def _abuild_bwd(out_dtype, interpret, res, g):
-    from jax.experimental import pallas as pl
-
-    yv, xv = res
-    N, S, H = yv.shape
-    W = xv.shape[2]
-    _record_cost("psroi_abuild_pallas_bwd",
-                 cost_psroi_abuild_bwd(N, S, H, W, jnp.dtype(g.dtype).itemsize),
-                 yv.shape)
-    rb = _abuild_rb(N, S, H, W, jnp.dtype(g.dtype).itemsize)
-    n_pad = -(-N // rb) * rb
-    dy, dx = pl.pallas_call(
-        _abuild_bwd_kernel_factory(rb),
-        out_shape=(jax.ShapeDtypeStruct((n_pad, S, H), jnp.float32),
-                   jax.ShapeDtypeStruct((n_pad, S, W), jnp.float32)),
-        grid=(n_pad // rb,),
-        in_specs=[pl.BlockSpec((rb, S, H), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((rb, S, W), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((rb, H, W), lambda i: (i, 0, 0))],
-        out_specs=(pl.BlockSpec((rb, S, H), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((rb, S, W), lambda i: (i, 0, 0))),
-        interpret=interpret,
-    )(_abuild_pad(yv, n_pad), _abuild_pad(xv, n_pad), _abuild_pad(g, n_pad))
-    return dy[:N], dx[:N]
-
-
-psroi_abuild_pallas.defvjp(_abuild_fwd, _abuild_bwd)
-
-
-# ---------------------------------------------------------------------------
 # Fused deformable-conv sampling matmul (round-5 north-star kernel)
 # ---------------------------------------------------------------------------
 #
@@ -898,8 +721,8 @@ def dconv_band_share(y0, y1, hw, nblk=_DCONV_NBLK):
 
 def _dconv_prec(dot_dtype):
     # f32 kernels must not silently drop to the MXU's default bf16
-    # multiplies — the XLA formulation pins HIGHEST for f32 (detection.py)
-    # and so does the sibling psroi_abuild kernel; bf16 stays single-pass
+    # multiplies — the XLA formulation pins HIGHEST for f32 (detection.py);
+    # bf16 stays single-pass
     return (jax.lax.Precision.HIGHEST
             if jnp.dtype(dot_dtype) == jnp.float32 else None)
 

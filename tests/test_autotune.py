@@ -808,17 +808,6 @@ class TestNewSpaces:
         tiles = {c["tile"] for c in sp.configs(N=1024)}
         assert 1024 not in tiles and {128, 256, 512} <= tiles
 
-    def test_abuild_vmem_prunes_big_blocks(self):
-        sp = autotune.get_space("psroi_abuild_pallas")
-        # big bin maps: 256 rois/step ≈ 151 MB backward working set
-        rbs = {c["rb"] for c in sp.configs(N=512, S=16, H=256, W=256,
-                                           itemsize=4)}
-        assert 256 not in rbs and 128 not in rbs
-        assert 16 in rbs and 32 in rbs
-        assert 64 in rbs   # the default is always admitted
-        # tiny bin maps admit the whole grid
-        assert len(sp.configs(N=512, S=4, H=7, W=7, itemsize=4)) == 5
-
     def test_quant_constraint(self):
         assert not sps._quant_constraint({"block": 0})
         # uncapped huge block blows the budget...
@@ -845,7 +834,6 @@ class TestNewKernelWiring:
                             lambda *a, **k: pytest.fail("store read on the "
                                                         "off path"))
         assert pk._nms_tile(1, 512) == pk._NMS_TILE
-        assert pk._abuild_rb(96, 4, 7, 7, 4) == pk._ABUILD_RB
         assert pk._quant_block("quantize_int8_pallas", 1024, 4, 1) == 512
         assert pk._quant_block(None, 100, 4, 1) == 100  # un-keyed: rows cap
 
@@ -864,22 +852,6 @@ class TestNewKernelWiring:
         assert pk._nms_tile(1, 1024) == pk._NMS_TILE
         autotune.record("nms_alive_pallas", sig, {"tile": "garbage"})
         assert pk._nms_tile(1, 1024) == pk._NMS_TILE
-
-    def test_abuild_rb_adoption_caps_at_n(self, at_on):
-        from mxnet_tpu.ops import pallas_kernels as pk
-
-        autotune.record("psroi_abuild_pallas",
-                        autotune.psroi_shape_sig(256, 4, 7, 7, 4),
-                        {"rb": 128})
-        assert pk._abuild_rb(256, 4, 7, 7, 4) == 128
-        autotune.record("psroi_abuild_pallas",
-                        autotune.psroi_shape_sig(96, 4, 7, 7, 4),
-                        {"rb": 128})
-        assert pk._abuild_rb(96, 4, 7, 7, 4) == 96   # effective block
-        autotune.record("psroi_abuild_pallas",
-                        autotune.psroi_shape_sig(96, 4, 7, 7, 4),
-                        {"rb": "garbage"})
-        assert pk._abuild_rb(96, 4, 7, 7, 4) == pk._ABUILD_RB
 
     def test_quant_block_adoption(self, at_on):
         from mxnet_tpu.ops import pallas_kernels as pk

@@ -67,6 +67,19 @@ def test_dconv_leg_interpret():
         "band_share"] <= regimes["uniform"]["band_share"] == 1.0
 
 
+def test_psroi_leg_toy():
+    # 2 x 40 rois, 6 channels a class: the classes pooling is over the
+    # one-hot threshold, so the leg's check compares the two paths
+    facts = chip_smoke.psroi_leg(batch=2, rois=40, hw=(12, 16), k=3, calls=1,
+                                 poolings=(("offsets", 2, True),
+                                           ("classes", 6, False)))
+    assert facts["rois"] == 80 and list(facts["psroi"]) == ["offsets",
+                                                            "classes"]
+    for fact in facts["psroi"].values():
+        assert fact["rel_err"] <= 2.0 ** -6
+        assert fact["fwd_ms"] > 0 and fact["fwd_bwd_ms"] > 0
+
+
 def test_module_fit_leg_toy():
     facts = chip_smoke.module_fit_leg(num_layers=8, image=16, classes=10,
                                       batch=4, batches=3)

@@ -754,35 +754,182 @@ def test_grouped_roi_hint_misuse_raises_in_debug_mode():
         engine.naive_engine(False)
 
 
-def test_psroi_abuild_pallas_matches_einsum():
-    """Round-5 A-build kernel: the Pallas MXU formulation must equal the
-    einsum-HIGHEST formulation (values and grads) — interpret mode here;
-    the chip consistency tier covers the compiled kernel."""
+# -- the rank-one one-hot path of DeformablePSROIPooling (ISSUE 29) ----------
+# rois (x1, y1, x2, y2) on a 7 x 9 map at spatial_scale 1, pooled 3, spp 2:
+# one hangs over each edge, one lies wholly outside (every sample dead), one
+# puts samples exactly at -0.5 and one at W - 0.5 / H - 0.5 (all live), one
+# is narrower than the 0.1 floor in both directions, one sits inside.  Sizes
+# are multiples of 3 so that sample positions are exact in float32 and in
+# the reference's float64 alike (a third would round across a boundary)
+_PSROI_EDGE_ROIS = np.array([
+    [-5, 1, 3, 6], [5, 2, 13, 4], [2, -4, 7, 4], [1, 4, 6, 12],
+    [20, 15, 31, 23], [0, 0, 11, 8], [3, 1, 14, 12], [4, 3, 3, 2],
+    [2, 1, 7, 6]], np.float32)
+_PSROI_MAP = (7, 9)
+
+
+def _psroi_edge_case(grouped, dtype, tiles=20, seed=0):
+    """-> (data, unique rois, trans of the unique rois, tiled rois, tiled
+    trans, kw, rows of the tiled run that hold the unique rois, the tiled
+    run's ``rois_per_image``).  The tiled run is over the one-hot
+    threshold, the unique rois under it."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    B, OD, g = 2, 6, 3
+    H, W = _PSROI_MAP
+    U = len(_PSROI_EDGE_ROIS)
+    uniq = np.zeros((B * U, 5), np.float32)
+    uniq[:, 0] = np.repeat(np.arange(B), U)
+    uniq[:, 1:] = np.tile(_PSROI_EDGE_ROIS, (B, 1))
+    trans = (0.3 * rng.randn(B * U, 2, g, g)).astype(np.float32)
+    if grouped:   # batch-major: image b's rows are its unique rois, tiled
+        big = uniq.reshape(B, 1, U, 5).repeat(tiles, 1).reshape(-1, 5)
+        big_t = trans.reshape(B, 1, U, 2, g, g).repeat(tiles, 1).reshape(
+            -1, 2, g, g)
+        rows = (np.arange(B)[:, None] * tiles * U + np.arange(U)).reshape(-1)
+    else:         # interleaved images: only the batch_idx column says which
+        big, big_t = np.tile(uniq, (tiles, 1)), np.tile(trans, (tiles, 1, 1, 1))
+        rows = np.arange(B * U)
+    data = jnp.asarray(rng.randn(B, OD * g * g, H, W).astype(np.float32)
+                       ).astype(dtype)
+    kw = dict(spatial_scale=1.0, output_dim=OD, group_size=g, pooled_size=g,
+              part_size=g, sample_per_part=2, trans_std=0.1)
+    assert len(big) * g * g * 4 * OD >= 1 << 16 > len(uniq) * g * g * 4 * OD
+    return (data, jnp.asarray(uniq), jnp.asarray(trans).astype(dtype),
+            jnp.asarray(big), jnp.asarray(big_t).astype(dtype), kw,
+            rows, tiles * U if grouped else 0)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("no_trans", [True, False])
+@pytest.mark.parametrize("grouped", [True, False])
+def test_deformable_psroi_onehot_matches_per_sample_reference(
+        grouped, no_trans, dtype):
+    """The one-hot path builds each bin's accumulation matrix as an outer
+    product of a per-row and a per-column weight vector; the reference walks
+    every sample and its four corners in a plain loop."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import detection as D
+
+    data, uniq, trans, big, big_t, kw, rows, rpi = _psroi_edge_case(
+        grouped, jnp.dtype(dtype))
+    out = D.deformable_psroi_pooling(data, big, big_t, no_trans=no_trans,
+                                     rois_per_image=rpi, **kw)
+    assert out.dtype == data.dtype
+    got = np.asarray(out.astype(jnp.float32))[rows]
+    exp = np_deformable_psroi(
+        np.asarray(data.astype(jnp.float32), np.float64), np.asarray(uniq),
+        np.asarray(trans.astype(jnp.float32), np.float64), 1.0, 6, 3, 3, 3,
+        2, 0.1, no_trans)
+    tol = 1e-5 if dtype == "float32" else 2e-2   # bf16: A, data and output
+    assert_almost_equal(got, exp, rtol=tol, atol=tol)
+    outside = np.flatnonzero(np.asarray(uniq)[:, 1] == 20)
+    assert len(outside) == 2 and not got[outside].any()   # cnt 0 -> 0
+    if no_trans:
+        # the inclusive boundary: bin (0, 0) of the roi at (0, 0) averages
+        # samples at -0.5 and 1.5 on both axes, all four live
+        d = np.asarray(data.astype(jnp.float32), np.float64)
+        exp00 = np.mean([np_bilinear(d[0, 0], y, x)
+                         for y in (-0.5, 1.0) for x in (-0.5, 1.5)])
+        assert abs(got[5, 0, 0, 0] - exp00) <= tol * max(1.0, abs(exp00))
+
+
+@pytest.mark.parametrize("grouped", [True, False])
+def test_deformable_psroi_onehot_grads_match_gather_path(grouped):
+    """Gradients to the data and to the offsets: the rank-one one-hot path
+    (tiled rois, over the threshold) against the gather path (the unique
+    rois alone), on rois that hang over every edge."""
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.ops.pallas_kernels import psroi_abuild_pallas
+    from mxnet_tpu.ops import detection as D
 
-    rng = np.random.RandomState(3)
-    N, S, H, W = 70, 16, 13, 21   # N deliberately not a block multiple
-    yv = jnp.asarray(rng.rand(N, S, H).astype(np.float32))
-    xv = jnp.asarray(rng.rand(N, S, W).astype(np.float32))
+    data, uniq, trans, big, big_t, kw, rows, rpi = _psroi_edge_case(
+        grouped, jnp.float32, seed=1)
+    cot = jnp.asarray(np.random.RandomState(2).randn(len(uniq), 6, 3, 3)
+                      .astype(np.float32))
+    U = len(_PSROI_EDGE_ROIS)
+    f_small = lambda d, t: jnp.sum(cot * D.deformable_psroi_pooling(
+        d, uniq, t, rois_per_image=U if grouped else 0, **kw))
+    f_big = lambda d, t: jnp.sum(cot * D.deformable_psroi_pooling(
+        d, big, t, rois_per_image=rpi, **kw)[rows])
+    gs = jax.grad(f_small, argnums=(0, 1))(data, trans)
+    gb = jax.grad(f_big, argnums=(0, 1))(data, big_t)
+    assert float(jnp.abs(gs[1]).max()) > 1.0      # the offsets matter
+    np.testing.assert_allclose(np.asarray(gb[0]), np.asarray(gs[0]),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(gb[1])[rows], np.asarray(gs[1]),
+                               rtol=1e-4, atol=2e-5)
 
-    ref = jnp.einsum("nsh,nsw->nhw", yv, xv,
-                     precision=jax.lax.Precision.HIGHEST)
-    out = psroi_abuild_pallas(yv, xv, jnp.float32, True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-6, atol=1e-6)
 
-    g = jnp.asarray(rng.rand(N, H, W).astype(np.float32))
-    f_ref = lambda y, x: jnp.sum(jnp.einsum(
-        "nsh,nsw->nhw", y, x, precision=jax.lax.Precision.HIGHEST) * g)
-    f_pal = lambda y, x: jnp.sum(psroi_abuild_pallas(y, x, jnp.float32, True) * g)
-    gy_r, gx_r = jax.grad(f_ref, argnums=(0, 1))(yv, xv)
-    gy_p, gx_p = jax.grad(f_pal, argnums=(0, 1))(yv, xv)
-    np.testing.assert_allclose(np.asarray(gy_p), np.asarray(gy_r),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(gx_p), np.asarray(gx_r),
-                               rtol=1e-5, atol=1e-6)
+def _psroi_structure_case(grouped):
+    """A one-hot-path call whose every axis length differs from spp² = 16
+    and whose roi count exceeds the data's channels."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import detection as D
+
+    rng = np.random.RandomState(0)
+    B, OD, g, H, W, Rb = 2, 6, 3, 12, 20, 120
+    R = B * Rb
+    rois = np.zeros((R, 5), np.float32)
+    rois[:, 0] = np.repeat(np.arange(B), Rb)
+    rois[:, 1:3] = rng.rand(R, 2) * 100
+    rois[:, 3:5] = rois[:, 1:3] + rng.rand(R, 2) * 120 + 8
+    rois = jnp.asarray(rois)
+    data = jnp.asarray(rng.rand(B, OD * g * g, H, W).astype(np.float32))
+    trans = jnp.asarray(0.3 * rng.randn(R, 2, g, g).astype(np.float32))
+    fn = lambda d, t: D.deformable_psroi_pooling(
+        d, rois, t, spatial_scale=1 / 8, output_dim=OD, group_size=g,
+        pooled_size=g, part_size=g, sample_per_part=4, trans_std=0.1,
+        rois_per_image=Rb if grouped else 0)
+    return fn, data, trans, R * H * W
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _all_eqns(sub)
+
+
+@pytest.mark.parametrize("grouped", [True, False])
+def test_deformable_psroi_onehot_contracts_no_sample_axis(grouped):
+    """The accumulation matrix is an outer product: forward and backward
+    hold no matrix product or convolution that contracts over the spp²
+    samples of a bin (the rank-16 build that ran at 0.4 % of the MXU)."""
+    import jax
+    import jax.numpy as jnp
+
+    fn, data, trans, _ = _psroi_structure_case(grouped)
+    loss = lambda d, t: jnp.sum(fn(d, t) ** 2)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(
+        data, trans)
+    dots = 0
+    for eqn in _all_eqns(jaxpr.jaxpr):
+        assert not eqn.primitive.name.startswith("conv_general"), eqn
+        if eqn.primitive.name == "dot_general":
+            dots += 1
+            (lc, _), _ = eqn.params["dimension_numbers"]
+            shape = eqn.invars[0].aval.shape
+            assert 16 not in [shape[i] for i in lc], eqn
+    assert dots >= 3    # the plane product and its two transposes
+
+
+@pytest.mark.parametrize("grouped", [True, False])
+def test_deformable_psroi_onehot_saves_no_accumulation_matrix(grouped):
+    """No (R, H·W) array is a residual of the forward: each bin's matrix is
+    rebuilt in the backward (jax.checkpoint), so device memory does not grow
+    with bins x rois x map."""
+    import jax
+
+    fn, data, trans, rhw = _psroi_structure_case(grouped)
+    _, f_vjp = jax.vjp(fn, data, trans)
+    sizes = [int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(f_vjp)
+             if hasattr(x, "shape")]
+    assert sizes and max(sizes) < rhw, (sorted(sizes)[-3:], rhw)
 
 
 def test_dconv_col_pallas_matches_xla_formulation():

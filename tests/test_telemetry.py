@@ -397,11 +397,9 @@ class TestCostRegistry:
 
         fns = pk.cost_fns()
         assert {"quantize_int8_pallas", "nms_alive_pallas",
-                "psroi_abuild_pallas_fwd", "dconv_col_pallas_fwd",
-                "dconv_col_pallas_bwd"} <= set(fns)
+                "dconv_col_pallas_fwd", "dconv_col_pallas_bwd"} <= set(fns)
         for cost in (fns["quantize_int8_pallas"]((8, 128)),
                      fns["nms_alive_pallas"](2, 6000),
-                     fns["psroi_abuild_pallas_fwd"](128, 16, 38, 64),
                      fns["dconv_col_pallas_fwd"](8, 1024, 2432, 256, 2)):
             assert cost["flops"] > 0 and cost["bytes_accessed"] > 0
 
@@ -523,12 +521,12 @@ class TestTraceSummary:
             {"name": "custom_call_costs", "ph": "M", "pid": 0, "args": {
                 "quantize_int8_pallas": {"flops": 50, "bytes_accessed": 10},
                 "dequantize_int8_pallas": {"flops": 20, "bytes_accessed": 10},
-                "psroi_abuild_pallas_fwd": {"flops": 100, "bytes_accessed": 10},
-                "psroi_abuild_pallas_bwd": {"flops": 200, "bytes_accessed": 10},
+                "dconv_col_pallas_fwd": {"flops": 100, "bytes_accessed": 10},
+                "dconv_col_pallas_bwd": {"flops": 200, "bytes_accessed": 10},
             }},
             {"name": "custom-call.dequantize_int8", "ph": "X", "ts": 0,
              "dur": 10, "pid": 0, "tid": 1},
-            {"name": "psroi_abuild_pallas_bwd", "ph": "X", "ts": 20,
+            {"name": "dconv_col_pallas_bwd", "ph": "X", "ts": 20,
              "dur": 10, "pid": 0, "tid": 1},
         ]}
         f = tmp_path / "t.json"
@@ -537,17 +535,17 @@ class TestTraceSummary:
         assert res.returncode == 0, res.stderr[-500:]
         rows = {r["op"]: r for r in json.loads(res.stdout)["rows"]}
         assert rows["custom-call.dequantize_int8"]["flops"] == 20
-        assert rows["psroi_abuild_pallas_bwd"]["flops"] == 200
+        assert rows["dconv_col_pallas_bwd"]["flops"] == 200
 
     def test_costs_from_telemetry_jsonl(self, tmp_path):
         trace = tmp_path / "trace.json"
         trace.write_text(json.dumps({"traceEvents": [
-            {"name": "psroi_abuild_pallas_fwd", "ph": "X", "ts": 0,
+            {"name": "dconv_col_pallas_fwd", "ph": "X", "ts": 0,
              "dur": 100, "pid": 0, "tid": 1}]}))
         jl = tmp_path / "tel.jsonl"
         jl.write_text(json.dumps(
             {"ts": 1, "kind": "custom_call_cost",
-             "name": "psroi_abuild_pallas_fwd", "flops": 1000,
+             "name": "dconv_col_pallas_fwd", "flops": 1000,
              "bytes_accessed": 2000}) + "\n")
         res = self._run(str(trace), "--costs", str(jl), "--json")
         assert res.returncode == 0, res.stderr[-500:]

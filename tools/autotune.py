@@ -357,52 +357,6 @@ def _search_nms(args):
                            emit_extra={"interpret": interpret})
 
 
-def _search_abuild(args):
-    """Measured search over the PSROI accumulation-build roi-block space
-    (fwd + bwd through jax.grad — the backward is the pass the VMEM guard
-    prunes on)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from mxnet_tpu import autotune
-    from mxnet_tpu.ops.pallas_kernels import psroi_abuild_pallas
-
-    kernel = "psroi_abuild_pallas"
-    N, S, H, W = args.ab_n, args.ab_s, args.ab_h, args.ab_w
-    sig = autotune.psroi_shape_sig(N, S, H, W, 4)
-    if _warm_hit(kernel, sig, "abuild", args):
-        return 0
-    rng = np.random.RandomState(args.seed)
-    yv = jnp.asarray(rng.rand(N, S, H).astype(np.float32))
-    xv = jnp.asarray(rng.rand(N, S, W).astype(np.float32))
-    g = jnp.asarray(rng.randn(N, H, W).astype(np.float32))
-    interpret = jax.default_backend() != "tpu"
-
-    def build():
-        @jax.jit
-        def step(yv, xv):
-            def loss(yv, xv):
-                A = psroi_abuild_pallas(yv, xv, jnp.float32, interpret)
-                return jnp.sum(A * g)
-
-            return jax.grad(loss, argnums=(0, 1))(yv, xv)
-
-        return step
-
-    def measure(cfg):
-        return autotune.measure_candidate(kernel, cfg, build, (yv, xv),
-                                          warmup=args.warmup,
-                                          repeat=args.repeat)
-
-    ctx = {"N": N, "S": S, "H": H, "W": W, "itemsize": 4}
-    return _run_and_finish(kernel, sig, "abuild", autotune.get_space(kernel),
-                           ctx, measure, args,
-                           meta_extra={"backend": jax.default_backend(),
-                                       "interpret": interpret},
-                           emit_extra={"interpret": interpret})
-
-
 def _search_quant(args, kernel):
     """Measured search over one tiled-elementwise int8 row-block space."""
     import jax
@@ -563,7 +517,6 @@ def _search_fused_step(args):
 _KERNEL_RUNNERS = {
     "dconv_col_pallas": _search_dconv,
     "nms_alive_pallas": _search_nms,
-    "psroi_abuild_pallas": _search_abuild,
     "quantize_int8_pallas": _search_quantize,
     "dequantize_int8_pallas": _search_dequantize,
     "fused_step_layout": _search_fused_step,
@@ -680,11 +633,6 @@ def main(argv=None):
     # nms_alive_pallas problem shape
     s.add_argument("--nms-boxes", type=int, default=512,
                    help="boxes per image for the NMS tile search")
-    # psroi_abuild_pallas problem shape (north-star-ish small map)
-    s.add_argument("--ab-n", type=int, default=96, help="rois")
-    s.add_argument("--ab-s", type=int, default=4, help="sample points/bin")
-    s.add_argument("--ab-h", type=int, default=7)
-    s.add_argument("--ab-w", type=int, default=7)
     # quantize/dequantize_int8_pallas problem shape
     s.add_argument("--q-rows", type=int, default=1024,
                    help="(rows, 128) flattened tiles for the int8 kernels")
