@@ -26,7 +26,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 import numpy as np
 
 import mxnet_tpu as mx
-from mxnet_tpu.gluon.functional import functionalize
+from mxnet_tpu.gluon.functional import build_train_step
 from mxnet_tpu.gluon.model_zoo.detection import DeformableRFCN, rfcn_resnet101
 
 
@@ -101,8 +101,13 @@ def _smooth_l1(pred, target, weight, sigma):
 
 def make_rfcn_train_step(net, batch, learning_rate=5e-4, momentum=0.9,
                          compute_dtype=None):
-    """→ (step, state): ``step(state, data, im_info, gt, key) ->
-    (state, loss, parts)``, fully jittable, state donate-able.
+    """→ (step, state): ``step(state, data, im_info, gt, key, lr) ->
+    (state, loss, parts)``, fully jittable, state donate-able; the step
+    itself (learn/aux split, grad, momentum SGD, per-step ``lr``) is
+    ``gluon.functional.build_train_step``'s, the loss below is the recipe's.
+    It serves every two-stage detector whose net returns these eleven
+    outputs: Faster R-CNN's class-specific regression only widens
+    ``bbox_pred`` (examples/rcnn/train_fused.py).
 
     Mixed precision (``compute_dtype='bfloat16'``): parameters and image in
     bf16 for the conv trunk (MXU dtype, halved HBM traffic); box/coordinate
@@ -113,27 +118,18 @@ def make_rfcn_train_step(net, batch, learning_rate=5e-4, momentum=0.9,
     import jax
     import jax.numpy as jnp
 
-    apply, names, vals, aux_names = functionalize(net, train=True)
-    aux_set = set(aux_names)
-    learn_idx = [i for i, n in enumerate(names) if n not in aux_set]
-    aux_idx = [i for i, n in enumerate(names) if n in aux_set]
     Hf, Wf = net.feat_shape
     A = net.num_anchors
     a_total = Hf * Wf * A
     ncand = net.rpn_post_nms + net.max_gts
-    cdtype = jnp.dtype(compute_dtype) if compute_dtype is not None else None
 
-    def loss_fn(learn, aux, data, im_info, gt, key):
-        merged = [None] * len(names)
-        for i, v in zip(learn_idx, learn):
-            merged[i] = v.astype(cdtype) if cdtype is not None else v
-        for i, v in zip(aux_idx, aux):
-            merged[i] = v
+    def forward_loss(run, inputs, key):
+        data, im_info, gt = inputs
         k1, k2, k3 = jax.random.split(key, 3)
         nz_rpn = jax.random.uniform(k1, (batch, a_total, 2), jnp.float32)
         nz_prop = jax.random.uniform(k2, (batch, ncand, 2), jnp.float32)
-        x = data.astype(cdtype) if cdtype is not None else data
-        outs, new_aux = apply(merged, (x, im_info, gt, nz_rpn, nz_prop), k3)
+        x = data.astype(compute_dtype) if compute_dtype is not None else data
+        outs = run((x, im_info, gt, nz_rpn, nz_prop), k3)
         (rpn_cls, rpn_bbox, rpn_label, rpn_bt, rpn_bw,
          _rois, label, bbox_target, bbox_weight, cls_score, bbox_pred) = (
             jnp.asarray(o).astype(jnp.float32) for o in outs)
@@ -164,29 +160,15 @@ def make_rfcn_train_step(net, batch, learning_rate=5e-4, momentum=0.9,
             total = rpn_cls_loss + rpn_bbox_loss + rcnn_cls_loss + rcnn_bbox_loss
             parts = jnp.stack([rpn_cls_loss, rpn_bbox_loss, rcnn_cls_loss,
                                rcnn_bbox_loss])
-        return total, (new_aux, parts)
+        return total, parts
 
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    core, state, _ = build_train_step(net, forward_loss, learning_rate,
+                                      momentum, compute_dtype)
 
     def step(state, data, im_info, gt, key, lr=learning_rate):
-        # ``lr`` defaults to the baked constant; schedules pass it per step
-        # as a traced scalar — decays then cost zero recompiles
-        learn, mom, aux = state
-        (loss, (new_aux, parts)), grads = grad_fn(learn, aux, data, im_info, gt, key)
-        with jax.named_scope("optimizer"):
-            if momentum:
-                mom = [momentum * m + g for m, g in zip(mom, grads)]
-                upd = mom
-            else:
-                upd = grads
-            learn = [p - lr * g for p, g in zip(learn, upd)]
-        return (learn, mom, new_aux), loss, parts
+        return core(state, (data, im_info, gt), key, lr)
 
-    learn_vals = [vals[i] for i in learn_idx]
-    aux_vals = [vals[i] for i in aux_idx]
-    # zeros_like on the jax arrays: shapes/dtypes only, no D2H transfer
-    mom_vals = [jnp.zeros_like(v) for v in learn_vals] if momentum else []
-    return step, (learn_vals, mom_vals, aux_vals)
+    return step, state
 
 
 def build_net(resnet101, image_shape=None, classes=None, frozen_bn=True):

@@ -31,7 +31,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 import numpy as np
 
 import mxnet_tpu as mx
-from mxnet_tpu.gluon.functional import functionalize
 from mxnet_tpu.gluon.model_zoo.detection import FasterRCNN, faster_rcnn_vgg16
 from mxnet_tpu.test_utils import load_module_by_path
 
@@ -45,12 +44,6 @@ synthetic_voc = _rfcn.synthetic_coco
 synthetic_voc_device = _rfcn.synthetic_coco_device
 
 
-def _smooth_l1(pred, target, weight, sigma):
-    from mxnet_tpu.ops.elemwise import smooth_l1
-
-    return smooth_l1((pred - target) * weight, scalar=sigma)
-
-
 def make_frcnn_train_step(net, batch, learning_rate=1e-3, momentum=0.9,
                           compute_dtype=None):
     """→ (step, state): ``step(state, data, im_info, gt, key, lr) ->
@@ -59,80 +52,12 @@ def make_frcnn_train_step(net, batch, learning_rate=1e-3, momentum=0.9,
     Loss heads follow the reference e2e symbol (symbol_vgg.py get_vgg_train):
     RPN softmax CE over sampled anchors + smooth-L1(σ=3)/RPN_BATCH; R-CNN
     softmax CE over the 128 sampled rois + class-specific
-    smooth-L1(σ=1)/BATCH_ROIS with normalized targets (BBOX_STDS).
+    smooth-L1(σ=1)/BATCH_ROIS with normalized targets (BBOX_STDS).  They
+    are the north star's heads over a wider ``bbox_pred`` (4·(C+1) deltas a
+    roi), so the step is its recipe's.
     """
-    import jax
-    import jax.numpy as jnp
-
-    apply, names, vals, aux_names = functionalize(net, train=True)
-    aux_set = set(aux_names)
-    learn_idx = [i for i, n in enumerate(names) if n not in aux_set]
-    aux_idx = [i for i, n in enumerate(names) if n in aux_set]
-    Hf, Wf = net.feat_shape
-    A = net.num_anchors
-    a_total = Hf * Wf * A
-    ncand = net.rpn_post_nms + net.max_gts
-    cdtype = jnp.dtype(compute_dtype) if compute_dtype is not None else None
-
-    def loss_fn(learn, aux, data, im_info, gt, key):
-        merged = [None] * len(names)
-        for i, v in zip(learn_idx, learn):
-            merged[i] = v.astype(cdtype) if cdtype is not None else v
-        for i, v in zip(aux_idx, aux):
-            merged[i] = v
-        k1, k2, k3 = jax.random.split(key, 3)
-        nz_rpn = jax.random.uniform(k1, (batch, a_total, 2), jnp.float32)
-        nz_prop = jax.random.uniform(k2, (batch, ncand, 2), jnp.float32)
-        x = data.astype(cdtype) if cdtype is not None else data
-        outs, new_aux = apply(merged, (x, im_info, gt, nz_rpn, nz_prop), k3)
-        (rpn_cls, rpn_bbox, rpn_label, rpn_bt, rpn_bw,
-         _rois, label, bbox_target, bbox_weight, cls_score, bbox_pred) = (
-            jnp.asarray(o).astype(jnp.float32) for o in outs)
-
-        # RPN losses (anchor order h·(W·A)+w·A+a, as rpn_anchor_target)
-        logits = rpn_cls.reshape(batch, 2, A, Hf, Wf).transpose(0, 3, 4, 2, 1)
-        logits = logits.reshape(batch, a_total, 2)
-        valid = rpn_label >= 0
-        lab = jnp.maximum(rpn_label, 0.0).astype(jnp.int32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ce = -jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
-        rpn_cls_loss = jnp.where(valid, ce, 0.0).sum() / jnp.maximum(valid.sum(), 1)
-        bp = rpn_bbox.reshape(batch, A, 4, Hf, Wf).transpose(0, 3, 4, 1, 2)
-        bp = bp.reshape(batch, a_total, 4)
-        rpn_bbox_loss = _smooth_l1(bp, rpn_bt, rpn_bw, 3.0).sum() / (
-            net.rpn_batch * batch)
-
-        # R-CNN head: class-specific regression (4·(C+1) deltas per roi)
-        logp2 = jax.nn.log_softmax(cls_score, axis=-1)
-        rcnn_cls_loss = -jnp.take_along_axis(
-            logp2, label.astype(jnp.int32)[:, None], axis=1).mean()
-        rcnn_bbox_loss = _smooth_l1(bbox_pred, bbox_target, bbox_weight, 1.0
-                                    ).sum() / label.shape[0]
-
-        total = rpn_cls_loss + rpn_bbox_loss + rcnn_cls_loss + rcnn_bbox_loss
-        parts = jnp.stack([rpn_cls_loss, rpn_bbox_loss, rcnn_cls_loss,
-                           rcnn_bbox_loss])
-        return total, (new_aux, parts)
-
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
-
-    def step(state, data, im_info, gt, key, lr=learning_rate):
-        learn, mom, aux = state
-        (loss, (new_aux, parts)), grads = grad_fn(learn, aux, data, im_info,
-                                                  gt, key)
-        if momentum:
-            mom = [momentum * m + g for m, g in zip(mom, grads)]
-            upd = mom
-        else:
-            upd = grads
-        learn = [p - lr * g for p, g in zip(learn, upd)]
-        return (learn, mom, new_aux), loss, parts
-
-    import jax.numpy as jnp  # noqa: F811  (zeros_like below)
-    learn_vals = [vals[i] for i in learn_idx]
-    aux_vals = [vals[i] for i in aux_idx]
-    mom_vals = [jnp.zeros_like(v) for v in learn_vals] if momentum else []
-    return step, (learn_vals, mom_vals, aux_vals)
+    return _rfcn.make_rfcn_train_step(net, batch, learning_rate, momentum,
+                                      compute_dtype)
 
 
 def build_net(vgg16, image_shape=None, classes=None, rpn_pre_nms=None,

@@ -26,7 +26,8 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import numpy as np
 
 import mxnet_tpu as mx
-from mxnet_tpu.gluon.functional import functionalize
+from mxnet_tpu.gluon.functional import (build_train_step, functionalize,
+                                        merge_params, param_names)
 from vgg_ssd import SSD300, SSD512, VGGSSD
 
 
@@ -48,59 +49,48 @@ def synthetic_voc(rng, batch, size, classes, max_gts=8):
 
 def make_ssd_train_step(net, anchors, batch, learning_rate=1e-3,
                         momentum=0.9, compute_dtype=None):
-    """→ (step, state): one-XLA-module SSD train step; state donate-able."""
+    """→ (step, state): one-XLA-module SSD train step, ``step(state, data,
+    gt, key, lr) -> (state, loss, parts)``; state donate-able.  The loss is
+    the recipe's, the step ``gluon.functional.build_train_step``'s."""
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.ops.detection import multibox_target
     from mxnet_tpu.ops.elemwise import smooth_l1
 
-    apply, names, vals, aux_names = functionalize(net, train=True)
-    aux_set = set(aux_names)
-    learn_idx = [i for i, n in enumerate(names) if n not in aux_set]
-    aux_idx = [i for i, n in enumerate(names) if n in aux_set]
-    cdtype = jnp.dtype(compute_dtype) if compute_dtype is not None else None
     anc = jnp.asarray(anchors)[None]  # (1, A, 4) fp32 — never downcast
 
-    def loss_fn(learn, aux, data, gt, key):
-        merged = [None] * len(names)
-        for i, v in zip(learn_idx, learn):
-            merged[i] = v.astype(cdtype) if cdtype is not None else v
-        for i, v in zip(aux_idx, aux):
-            merged[i] = v
-        x = data.astype(cdtype) if cdtype is not None else data
-        (cls_preds, box_preds), new_aux = apply(merged, (x,), key)
+    def forward_loss(run, inputs, key):
+        data, gt = inputs
+        x = data.astype(compute_dtype) if compute_dtype is not None else data
+        cls_preds, box_preds = run((x,), key)
         cls_preds = cls_preds.astype(jnp.float32)
         box_preds = box_preds.astype(jnp.float32)
-        # on-device targets (reference MultiBoxTarget semantics: bipartite
-        # match + 0.5 IoU, 3:1 negative mining); cls_preds (B, C+1, A)
-        bt, bm, ct = multibox_target(
-            anc, gt, cls_preds.transpose(0, 2, 1),
-            negative_mining_ratio=3.0)
-        valid = (ct >= 0).astype(jnp.float32)
-        logp = jax.nn.log_softmax(cls_preds, axis=-1)
-        ce = -jnp.take_along_axis(
-            logp, jnp.maximum(ct, 0).astype(jnp.int32)[..., None], axis=-1
-        )[..., 0] * valid
-        npos = jnp.maximum(bm.reshape(bm.shape[0], -1, 4)[..., 0].sum(), 1.0)
-        cls_loss = ce.sum() / npos
-        loc_loss = smooth_l1((box_preds - bt) * bm, scalar=1.0).sum() / npos
-        return cls_loss + loc_loss, (new_aux, jnp.stack([cls_loss, loc_loss]))
+        with jax.named_scope("loss"):
+            # on-device targets (reference MultiBoxTarget semantics:
+            # bipartite match + 0.5 IoU, 3:1 negative mining); cls_preds
+            # (B, C+1, A)
+            bt, bm, ct = multibox_target(
+                anc, gt, cls_preds.transpose(0, 2, 1),
+                negative_mining_ratio=3.0)
+            valid = (ct >= 0).astype(jnp.float32)
+            logp = jax.nn.log_softmax(cls_preds, axis=-1)
+            ce = -jnp.take_along_axis(
+                logp, jnp.maximum(ct, 0).astype(jnp.int32)[..., None], axis=-1
+            )[..., 0] * valid
+            npos = jnp.maximum(
+                bm.reshape(bm.shape[0], -1, 4)[..., 0].sum(), 1.0)
+            cls_loss = ce.sum() / npos
+            loc_loss = smooth_l1((box_preds - bt) * bm, scalar=1.0).sum() / npos
+        return cls_loss + loc_loss, jnp.stack([cls_loss, loc_loss])
 
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    core, state, _ = build_train_step(net, forward_loss, learning_rate,
+                                      momentum, compute_dtype)
 
     def step(state, data, gt, key, lr=learning_rate):
-        learn, mom, aux = state
-        (loss, (new_aux, parts)), grads = grad_fn(learn, aux, data, gt, key)
-        mom = [momentum * m + g for m, g in zip(mom, grads)]
-        learn = [p - lr * m for p, m in zip(learn, mom)]
-        return (learn, mom, new_aux), loss, parts
+        return core(state, (data, gt), key, lr)
 
-    learn_vals = [vals[i] for i in learn_idx]
-    aux_vals = [vals[i] for i in aux_idx]
-    import jax.numpy as jnp2
-    mom_vals = [jnp2.zeros_like(v) for v in learn_vals]
-    return step, (learn_vals, mom_vals, aux_vals)
+    return step, state
 
 
 def make_score_step(net, anchors, compute_dtype=None):
@@ -193,11 +183,8 @@ def run_bench(size=300, classes=20, train_batch=8, score_batch=16, iters=10,
 def _merge_vals(net, state):
     """Reassemble functionalize's value list (learnables + aux running
     stats) from a trained train-step state."""
-    from mxnet_tpu.gluon.functional import functionalize, merge_params
-
-    _apply, names, _vals, aux_names = functionalize(net, train=True)
     learn, _mom, aux = state
-    return merge_params(names, aux_names, learn, aux)
+    return merge_params(*param_names(net), learn, aux)
 
 
 def main():

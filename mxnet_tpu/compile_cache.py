@@ -509,8 +509,18 @@ class CachedFunction:
                 return None
             from jax.experimental import serialize_executable
 
+            # on the devices it was compiled for: the loader's default is
+            # every device of the backend, and a one-device executable
+            # loaded over eight refuses its arguments at dispatch
+            devices = payload.get("devices")
+            if devices is not None:
+                import jax
+
+                by_id = {d.id: d for d in jax.devices()}
+                devices = [by_id[i] for i in devices]
             exe = serialize_executable.deserialize_and_load(
-                payload["blob"], payload["in_tree"], payload["out_tree"])
+                payload["blob"], payload["in_tree"], payload["out_tree"],
+                execution_devices=devices)
             os.utime(path, None)  # LRU signal for _evict
             return exe, payload.get("cost")
         except Exception:
@@ -532,6 +542,8 @@ class CachedFunction:
                        "env": _env_fingerprint(self._mesh_desc),
                        "blob": blob, "in_tree": in_tree,
                        "out_tree": out_tree,
+                       "devices": [d.id for d in compiled.
+                                   runtime_executable().local_devices()],
                        # cost identity of the program as compiled (ISSUE
                        # 20): a restore records a ledger row from this, so
                        # a warm pod restart still proves every rank runs

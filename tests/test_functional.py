@@ -1,9 +1,17 @@
 """gluon.functional + driver entry tests."""
+import functools
+import os
+import re
+
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import gluon
 from mxnet_tpu.gluon.functional import functionalize, make_train_step
+
+EXAMPLES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                        "examples")
 
 
 def _small_net():
@@ -141,3 +149,187 @@ class TestGraftEntry:
                      NamedSharding(mesh, P()), v.ndim)]
         assert len(still) == len(sharded)
         assert losses[-1] < losses[0] * 0.8, losses
+
+
+# -- the one builder: gluon.functional.build_train_step ---------------------
+# make_train_step and the three detection recipes are that core with their
+# own loss; what the core gives (state layout, per-step lr, the two operator
+# scopes) is checked once over all of them.
+
+def _scopes(lowered):
+    """The ``jax.named_scope`` names in a lowered step's locations."""
+    text = lowered.as_text(debug_info=True)
+    return {n for n in ("loss", "optimizer")
+            if re.search(r'"jit\(step\)/[^"]*\b%s\b[^"]*"' % n, text)}
+
+
+def _copy(state):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree_util.tree_map(lambda v: jnp.array(v, copy=True), state)
+
+
+def _toy_recipe(kind):
+    """→ (step, state, batch arrays, length of ``parts``) of a detection
+    recipe at the toy size its own tests use."""
+    from mxnet_tpu.test_utils import load_module_by_path
+
+    rng = np.random.RandomState(1)
+    mx.random.seed(1)
+    if kind == "ssd":
+        tf = load_module_by_path(
+            os.path.join(EXAMPLES, "ssd", "train_fused.py"),
+            "_ssd_train_fused_functional_tests")
+        cfg = dict(tf.SSD300, tail=0, sizes=tf.SSD300["sizes"][:4],
+                   ratios=tf.SSD300["ratios"][:4])
+        net = tf.VGGSSD(3, cfg)
+        net.initialize(mx.init.Xavier())
+        net(mx.nd.zeros((1, 3, 128, 128)))
+        step, state = tf.make_ssd_train_step(
+            net, net.make_anchors(128), 2, learning_rate=5e-3)
+        return step, state, tf.synthetic_voc(rng, 2, 128, 3), 2
+    from mxnet_tpu.gluon.model_zoo.detection import DeformableRFCN, FasterRCNN
+
+    common = dict(classes=3, image_shape=(64, 96), scales=(1, 2),
+                  ratios=(0.5, 1, 2), rpn_pre_nms=200, rpn_post_nms=32,
+                  batch_rois=16, rpn_batch=32, max_gts=8)
+    if kind == "rfcn":
+        tf = load_module_by_path(
+            os.path.join(EXAMPLES, "deformable_rfcn", "train_fused.py"),
+            "_rfcn_train_fused_functional_tests")
+        net, make = (DeformableRFCN(units=(1, 1, 1, 1), **common),
+                     tf.make_rfcn_train_step)
+    else:
+        tf = load_module_by_path(
+            os.path.join(EXAMPLES, "rcnn", "train_fused.py"),
+            "_frcnn_train_fused_functional_tests")
+        net, make = (FasterRCNN(filters=(8, 16, 32, 32, 32), fc_hidden=64,
+                                units=(1, 1, 1, 1, 1), **common),
+                     tf.make_frcnn_train_step)
+    net.initialize()
+    batch = tf.synthetic_coco(rng, 1, (64, 96), 3, net.max_gts) \
+        if kind == "rfcn" else tf.synthetic_voc(rng, 1, (64, 96), 3, net.max_gts)
+    net(mx.nd.array(batch[0]), mx.nd.array(batch[1]))  # materialise params
+    step, state = make(net, 1, learning_rate=0.01, momentum=0.9)
+    return step, state, batch, 4
+
+
+@functools.lru_cache(maxsize=None)
+def _recipe_run(kind):
+    """One compile a recipe, shared by the cases below: the jitted, donated
+    step called with ``lr`` as a device scalar, from the same initial state
+    at 0.1 and at 0.01, then a second step."""
+    import jax
+    import jax.numpy as jnp
+
+    step, state0, batch, n_parts = _toy_recipe(kind)
+    jstep = jax.jit(step, donate_argnums=(0,))
+    key = jax.random.PRNGKey(0)
+    r = {"state0": _copy(state0), "n_parts": n_parts,
+         "scopes": _scopes(jstep.lower(state0, *batch, key))}
+    r["s1"], r["loss1"], r["parts"] = jstep(
+        _copy(state0), *batch, key, jnp.float32(0.1))
+    r["s1_small"], _, _ = jstep(
+        _copy(state0), *batch, key, jnp.float32(0.01))
+    r["s2"], r["loss2"], _ = jstep(
+        _copy(r["s1"]), *batch, jax.random.fold_in(key, 1), jnp.float32(0.1))
+    r["compiles"] = jstep._cache_size()
+    return r
+
+
+def _steps(a, b):
+    """Per-leaf parameter change between two states, as float64."""
+    return [np.asarray(x, np.float64) - np.asarray(y, np.float64)
+            for x, y in zip(a[0], b[0])]
+
+
+class TestOneBuilder:
+    @pytest.mark.parametrize("kind", ["rfcn", "frcnn", "ssd"])
+    def test_recipe_is_the_core_with_its_loss(self, kind):
+        r = _recipe_run(kind)
+        assert np.isfinite(float(r["loss1"])) and np.isfinite(float(r["loss2"]))
+        assert np.asarray(r["parts"]).shape == (r["n_parts"],)
+        assert r["scopes"] == {"loss", "optimizer"}
+        # (learn, mom, aux) in functionalize's order, leaf for leaf
+        learn, mom, aux = r["s2"]
+        assert [v.shape for v in learn] == [v.shape for v in r["state0"][0]]
+        assert [v.shape for v in mom] == [v.shape for v in learn]
+        assert [v.shape for v in aux] == [v.shape for v in r["state0"][2]]
+        # momentum starts at zero: after one step it is the gradient
+        g = [np.asarray(m) for m in r["s1"][1]]
+        assert max(np.abs(x).max() for x in g) > 0
+        assert all(np.isfinite(x).all() for x in g)
+
+    @pytest.mark.parametrize("kind", ["rfcn", "frcnn", "ssd"])
+    def test_recipe_lr_is_a_step_argument(self, kind):
+        r = _recipe_run(kind)
+        assert r["compiles"] == 1
+        big, small = _steps(r["state0"], r["s1"]), _steps(r["state0"], r["s1_small"])
+        for w0, d1, d2, g in zip(r["state0"][0], big, small, r["s1"][1]):
+            g = np.asarray(g, np.float64)
+            # a float32 difference of two weights: a few ulps of the weight
+            ulps = 4 * np.finfo(np.float32).eps * max(np.abs(np.asarray(w0)).max(), 1e-6)
+            np.testing.assert_allclose(d1, 0.1 * g, rtol=1e-4, atol=ulps)
+            np.testing.assert_allclose(d2, 0.01 * g, rtol=1e-4, atol=ulps)
+
+    @pytest.mark.parametrize("optimizer", ["sgd", "adam"])
+    def test_make_train_step_lr_and_scopes(self, optimizer):
+        import jax
+        import jax.numpy as jnp
+
+        rng = np.random.RandomState(1)
+        X = rng.randn(32, 8).astype(np.float32)
+        y = rng.randint(0, 4, (32,)).astype(np.float32)
+        mx.random.seed(5)
+        step, state0, _ = make_train_step(
+            _small_net(), gluon.loss.SoftmaxCrossEntropyLoss(),
+            learning_rate=0.5, momentum=0.9, optimizer=optimizer)
+        jstep = jax.jit(step, donate_argnums=(0,))
+        key = jax.random.PRNGKey(0)
+        assert _scopes(jstep.lower(state0, X, y, key)) == {"loss", "optimizer"}
+        keep = _copy(state0)
+        s1, _ = jstep(_copy(state0), X, y, key, jnp.float32(0.1))
+        s2, _ = jstep(_copy(state0), X, y, key, jnp.float32(0.01))
+        assert jstep._cache_size() == 1
+        s3, _ = jax.jit(step)(_copy(state0), X, y, key)
+        for w0, d1, d2, d3 in zip(keep[0], _steps(keep, s1), _steps(keep, s2),
+                                  _steps(keep, s3)):
+            assert np.abs(d1).max() > 0
+            ulps = 40 * np.finfo(np.float32).eps * np.abs(np.asarray(w0)).max()
+            np.testing.assert_allclose(d1, 10 * d2, rtol=2e-3, atol=ulps)
+            # without lr the baked rate applies: five times the step at 0.1
+            np.testing.assert_allclose(d3, 5 * d1, rtol=2e-3, atol=ulps)
+
+    def test_core_takes_any_batch_and_any_extra(self):
+        """The core hands the caller's batch through untouched (integer
+        leaves keep their type under a bf16 compute type) and returns
+        whatever pytree the loss adds, an array as well as a dict."""
+        import jax
+        import jax.numpy as jnp
+
+        from mxnet_tpu.gluon.functional import build_train_step
+
+        seen = {}
+
+        def forward_loss(run, batch, key):
+            seen["ids"], seen["x"] = batch["ids"].dtype, batch["x"].dtype
+            out = run(batch["x"].astype(jnp.bfloat16), key)
+            seen["out"] = out.dtype
+            logp = jax.nn.log_softmax(out.astype(jnp.float32), axis=-1)
+            rows = -jnp.take_along_axis(logp, batch["ids"][:, None], 1)[:, 0]
+            return rows.mean(), rows
+
+        rng = np.random.RandomState(2)
+        batch = {"x": rng.randn(16, 8).astype(np.float32),
+                 "ids": rng.randint(0, 4, (16,)).astype(np.int32)}
+        step, state, (names, learn_idx, aux_idx) = build_train_step(
+            _small_net(), forward_loss, learning_rate=0.1, momentum=0.9,
+            compute_dtype="bfloat16")
+        assert len(state[0]) == len(learn_idx) and len(state[2]) == len(aux_idx)
+        state, loss, rows = jax.jit(step)(state, batch, jax.random.PRNGKey(0))
+        assert seen == {"ids": jnp.int32, "x": jnp.float32, "out": jnp.bfloat16}
+        assert rows.shape == (16,) and rows.dtype == jnp.float32
+        np.testing.assert_allclose(float(loss), np.asarray(rows).mean(), rtol=1e-6)
+        # fp32 masters, momentum and BatchNorm statistics under bf16 compute
+        assert {v.dtype for part in state for v in part} == {jnp.dtype("float32")}

@@ -390,18 +390,27 @@ class TestIncidents:
             p1.note_step(0.01)  # push -> response carries the incident
             deadline = time.monotonic() + 10.0
             dumps = []
+
+            def rank1_dumps():
+                # both planes live in this process and share its recorder:
+                # rank 0's detector thread dumps its own observation of the
+                # incident into the same directory (observer_rank 0)
+                metas = [json.load(open(f))["flightrec"] for f in
+                         glob.glob(str(r1_dir / "*pod_incident*.json"))]
+                return [m for m in metas if m["observer_rank"] == 1]
+
             while time.monotonic() < deadline and not dumps:
-                dumps = glob.glob(str(r1_dir / "*pod_incident*.json"))
+                dumps = rank1_dumps()
                 time.sleep(0.05)
             assert dumps, "rank 1 never dumped the broadcast incident"
-            meta = json.load(open(dumps[0]))["flightrec"]
+            meta = dumps[0]
             assert meta["incident"] == inc["id"]
             assert meta["why"] == "slo_breach"
             assert p1.push_stats()["incidents_seen"] == 1
             # the same id never re-dumps
             p1.note_step(0.01)
             time.sleep(0.2)
-            assert len(glob.glob(str(r1_dir / "*pod_incident*.json"))) == 1
+            assert len(rank1_dumps()) == 1
         finally:
             p0.close()
             p1.close()
