@@ -358,11 +358,12 @@ def dconv_leg(bg=32, channels=128, hw=(38, 64), interpret=False, calls=20):
         rng = np.random.RandomState(0)
         rows = [jnp.asarray(a) for a in
                 dconv_sample_inputs(rng, bg, hw, offset)]
-        ft = jnp.asarray(rng.randn(bg, H * W, channels).astype(np.float32)
+        # channels-major on both sides of the kernel: ft^T in, col^T out
+        ft = jnp.asarray(rng.randn(bg, channels, H * W).astype(np.float32)
                          ).astype(jnp.bfloat16)
         ints, flts = rows[:4], rows[4:] + [ft]
         cot = jnp.cos(jnp.arange(N * channels, dtype=jnp.float32)
-                      ).reshape(1, N, channels)
+                      ).reshape(1, channels, N)
         errs = {}
         for out, got, want in zip(
                 ("col", "d_ly", "d_lx", "d_lf", "d_ft"),
@@ -377,7 +378,7 @@ def dconv_leg(bg=32, channels=128, hw=(38, 64), interpret=False, calls=20):
         if max(errs.values()) > 2.0 ** -6:
             raise AssertionError("dconv kernel disagrees with the dense "
                                  "formulation (%s): %r" % (name, errs))
-        g = jnp.broadcast_to(cot, (bg, N, channels)).astype(jnp.bfloat16)
+        g = jnp.broadcast_to(cot, (bg, channels, N)).astype(jnp.bfloat16)
         facts[name] = {
             "rel_err": errs,
             "band_share": round(float(pk.dconv_band_share(

@@ -11,11 +11,16 @@ Programs" loop (PAPERS.md 1805.08166), with the grid/greedy searcher in
 
 Registered spaces (this module, at import):
 
-* ``dconv_col_pallas`` — the row-block size ``nblk`` of the fused
-  deformable-conv sampling kernel (`ops/pallas_kernels.py`), constrained by
-  the same ``dconv_bwd_vmem_bytes`` VMEM guard that drives the
-  pallas-vs-XLA auto branch: a candidate whose backward working set would
-  hard-fail Mosaic is never measured.
+* ``dconv_col_pallas`` — the block size ``nblk`` of the fused
+  deformable-conv sampling kernel (`ops/pallas_kernels.py`): samples per
+  grid step, which lie on the LANES of every block the kernels read and
+  write (the per-sample rows, ``col^T (BG, C, N)`` and its cotangent:
+  channels-major since PR 32).  Constrained by the same
+  ``dconv_bwd_vmem_bytes`` VMEM guard that drives the pallas-vs-XLA auto
+  branch: a candidate whose backward working set would hard-fail Mosaic is
+  never measured.  A block under 128 lanes that is not all of N does not
+  compile for the chip (before PR 32 neither: its lane slices are
+  unaligned) and counts as a failed trial; the interpreter takes any.
 * ``nms_alive_pallas`` — the box-tile size ``tile`` of the blocked greedy
   NMS kernel (lane-aligned multiples of 128; ``nms_fits_vmem`` prunes
   tiles whose per-image working set would blow VMEM at the problem's N).
@@ -147,7 +152,8 @@ def _dconv_constraint(config, N=None, HW=None, C=None, itemsize=4, **_):
 
 register_space(TuningSpace(
     "dconv_col_pallas",
-    # multiples of the f32 sublane tile; 128 is the shipped _DCONV_NBLK
+    # 128 is the shipped _DCONV_NBLK; 32 and 64 run in the interpreter and
+    # as the whole of a small N only (module docstring)
     params={"nblk": (32, 64, 128, 256, 512)},
     default={"nblk": 128},
     constraint=_dconv_constraint))

@@ -588,6 +588,46 @@ def deformable_psroi_pooling(
     return out.transpose(0, 1, 4, 2, 3).reshape(R, OD, PH, PW).astype(data.dtype)
 
 
+@jax.custom_vjp
+def _grouped_col_product(wmat, col):
+    """``out[b, g, f, p] = sum_k wmat[g, f, k] * col[b, g, k, p]``, the
+    deformable convolution's grouped product over its channels-major
+    columns.
+
+    The products are the einsum's own; what is fixed here is how the two
+    cotangents are formed, so that the columns (179 MB a res5 layer) are
+    never relaid (PR 32; read off the step's executable compiled for a
+    v5e).  JAX's transpose rule for a ``dot_general`` puts the contracted
+    axis last, ``(g, b, p, k)``, and transposes that into ``col``'s
+    ``(b, g, k, p)``, which XLA's TPU compiler does not fold into the
+    product (a ``copy`` of its own): with the image as a batch axis of the
+    product the columns' cotangent comes out as ``(b, g, k, p)`` to begin
+    with.  And with the columns as the RIGHT operand of the weights'
+    cotangent the compiler relays them twice, ``(k, b, p)`` and then
+    channels-minor; as the left operand it reads them as they lie."""
+    return jnp.einsum("gfk,bgkp->bgfp", wmat, col)
+
+
+def _grouped_col_product_fwd(wmat, col):
+    return _grouped_col_product(wmat, col), (wmat, col)
+
+
+def _grouped_col_product_bwd(res, ct):
+    wmat, col = res
+    # d_wmat[g, f, k] = sum over b, p of col[b, g, k, p] * ct[b, g, f, p],
+    # formed as (g, k, f): the transpose is weights-sized
+    d_wmat = jax.lax.dot_general(
+        col, ct, (((0, 3), (0, 3)), ((1,), (1,)))).transpose(0, 2, 1)
+    # d_col[b, g, k, p] = sum over f of wmat[g, f, k] * ct[b, g, f, p]
+    wb = jnp.broadcast_to(wmat, col.shape[:1] + wmat.shape)
+    d_col = jax.lax.dot_general(wb, ct, (((2,), (2,)), ((0, 1), (0, 1))))
+    return d_wmat.astype(wmat.dtype), d_col.astype(col.dtype)
+
+
+_grouped_col_product.defvjp(_grouped_col_product_fwd,
+                            _grouped_col_product_bwd)
+
+
 def _defconv_inputs(attrs):
     base = ["data", "offset", "weight"]
     return base if attrs.get("no_bias") else base + ["bias"]
@@ -669,7 +709,10 @@ def deformable_convolution(
         # sample matrix A[n, h·W+w] = yw[n,h]·xw[n,w] is a rank-1 product
         # of one-hot lerp factors and ``col = A @ feat`` rides the MXU —
         # both directions are matmuls, no gather/scatter.  A is rebuilt in
-        # the backward (remat) instead of saved.
+        # the backward (remat) instead of saved.  Features and columns stay
+        # channels-major, the data's own order: ``col^T = feat^T @ A^T`` is
+        # (cpg, N) per (image, group), so the (B, C, K2, Ho, Wo) columns are
+        # a reshape of it and nothing columns-sized is transposed (PR 32).
         off = offset.reshape(B, DG, K2, 2, Ho, Wo)
         sy = grid_y[None, None, None] + tap_dy[None, None, :, None, None] + off[:, :, :, 0]
         sx = grid_x[None, None, None] + tap_dx[None, None, :, None, None] + off[:, :, :, 1]
@@ -684,14 +727,13 @@ def deformable_convolution(
         ly = syc - y0.astype(cf)          # lerp factors stay fp32; only A
         lx = sxc - x0.astype(cf)          # downcasts for the plane matmul
         lf = live.reshape(B, DG, N).astype(cf)
-        feat = data.reshape(B, DG, cpg, H * W).transpose(0, 1, 3, 2)
         iota_y = jnp.arange(H, dtype=jnp.int32)
         iota_x = jnp.arange(W, dtype=jnp.int32)
         prec = jax.lax.Precision.HIGHEST if f32 == jnp.float32 else None
 
         @jax.checkpoint
         def one_bg(args):
-            yb0, yb1, xb0, xb1, lyb, lxb, lfb, ft = args
+            yb0, yb1, xb0, xb1, lyb, lxb, lfb, ftt = args
             yv = ((1.0 - lyb)[:, None] * (yb0[:, None] == iota_y)
                   + lyb[:, None] * (yb1[:, None] == iota_y))      # (N, H)
             xv = lfb[:, None] * (
@@ -704,13 +746,13 @@ def deformable_convolution(
             # accumulate bf16).  NOT testable via the consistency tier —
             # its bf16 variant of this path is excluded for the unrelated
             # floor()-bin-flip reason (test_consistency_tpu.py case note).
-            return jnp.matmul(a.reshape(N, H * W).astype(f32), ft,
-                              precision=prec,
-                              preferred_element_type=jnp.float32
-                              ).astype(f32)                       # (N, cpg)
+            return jax.lax.dot_general(
+                ftt, a.reshape(N, H * W).astype(f32),
+                (((1,), (1,)), ((), ())), precision=prec,
+                preferred_element_type=jnp.float32).astype(f32)   # (cpg, N)
 
         flat = lambda a: a.reshape(B * DG, N)
-        ftm = feat.reshape(B * DG, H * W, cpg)
+        ftm = data.reshape(B * DG, cpg, H * W)
 
         def xla_col():
             _, col = jax.lax.scan(
@@ -754,8 +796,7 @@ def deformable_convolution(
                     tpu=lambda: pallas_col(False), default=xla_col)
             else:
                 col = xla_col()
-        col = (col.reshape(B, DG, K2, Ho * Wo, cpg)
-               .transpose(0, 1, 4, 2, 3).reshape(B, C, K2, Ho, Wo))
+        col = col.reshape(B, C, K2, Ho, Wo)  # from (B·DG, cpg, N)
     else:
         # -- gather path (small problems / CPU) ---------------------------
         def one_image(img, off):
@@ -776,7 +817,7 @@ def deformable_convolution(
     # grouped matmul on the MXU
     wmat = weight.reshape(G, F // G, (C // G) * K2)
     col = col.reshape(B, G, (C // G) * K2, Ho * Wo)
-    out = jnp.einsum("gfk,bgkp->bgfp", wmat, col).reshape(B, F, Ho, Wo)
+    out = _grouped_col_product(wmat, col).reshape(B, F, Ho, Wo)
     if bias is not None and not no_bias:
         out = out + bias[None, :, None, None]
     return out

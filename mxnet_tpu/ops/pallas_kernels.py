@@ -628,11 +628,22 @@ def nms_alive_pallas(boxes, valid, ids, *, thresh, plus_one=1.0,
 #     f_k = y*W + x of corner k,  w_k = its lerp weight times lf
 #     (corners that coincide at the last row / column add their weights)
 # Forward:   col^T = sum over the band's steps of  ft^T[:, step] @ A_T[step]
-#   (ft^T comes from XLA, col^T is transposed once per grid step)
 # Backward:  dA_T[step] = ft[step] @ g^T stays in VMEM; the four corner
 #   values of dA are masked column sums of it, and d_ly / d_lx / d_lf are
 #   their lerp combinations; d_ft[step] += A_T[step] @ g for the band's
 #   steps only (the accumulator is zeroed once per bg).
+#
+# The columns cross the kernel boundary CHANNELS-MAJOR (PR 32): the forward
+# writes its ``(C, nblk)`` accumulator as it is into ``col^T (BG, C, n_pad)``
+# and the backward reads ``g^T`` in ``(C, nblk)`` blocks, samples on the
+# lanes on both sides, so the operator's ``(B, C, K2, Ho, Wo)`` columns are a
+# reshape of what the kernels write and read and no columns-sized array is
+# transposed anywhere (on the chip that reshape is still a copy: the taps
+# move from the lanes to the sublanes of a tiled array, PERF.md section 7
+# row 18).  The features come in as ``ft^T (BG, C, HW)``, the data's own
+# view: the forward reads that; the backward contracts over ``ft (HW, C)``
+# and accumulates ``d_ft (HW, C)``, which XLA transposes on the way in and
+# out (features-sized: a ninth of the columns under a 3x3 kernel).
 
 _DCONV_NBLK = 128
 # the band's unit: positions of the flat feature axis per chunk (the 128
@@ -671,7 +682,7 @@ def dconv_bwd_vmem_bytes(HW, C, itemsize, nblk=_DCONV_NBLK):
     (the larger of the two passes) at its widest, a band of the whole
     (padded) map in one step: dA_T, A_T and the four corner masks with
     their selects (seven f32 ``(HW, nblk)`` planes), the ft block and the
-    f32 dft accumulator (``(HW, C)``), and the g block (``(nblk, C)``).  A
+    f32 dft accumulator (``(HW, C)``), and the g^T block (``(C, nblk)``).  A
     narrow band needs ``_DCONV_STEP`` chunks of the planes only, but which
     a block gets is data.  Drives the auto-branch guard in ``detection.py
     deformable_convolution`` — above ``_DCONV_VMEM_LIMIT`` (override:
@@ -680,7 +691,7 @@ def dconv_bwd_vmem_bytes(HW, C, itemsize, nblk=_DCONV_NBLK):
     HW = _dconv_hw_pad(HW)
     return (7 * 4 * nblk * HW          # dA_T + A_T + masks and selects, f32
             + HW * C * (itemsize + 4)  # ft block + f32 dft accumulator
-            + nblk * C * (itemsize + 4))  # g block + col block
+            + nblk * C * (itemsize + 4))  # g^T block + col^T block
 
 
 def dconv_fits_vmem(HW, C, itemsize, nblk=_DCONV_NBLK):
@@ -782,7 +793,7 @@ def _dconv_fwd_kernel_factory(W, nblk, dot_dtype):
             y0_ref, y1_ref, x0_ref, x1_ref, ly_ref, lx_ref, lf_ref)), W)
 
         # col^T += ft^T[:, step] @ A_T[step]: with the samples on the lanes
-        # nothing is transposed inside the loop, col^T once after it
+        # nothing is transposed, in the loop or after it
         def step(rows, hit, a_t, acc):
             return acc + jnp.dot(
                 ftt_ref[0, :, rows], a_t.astype(dot_dtype),
@@ -791,14 +802,14 @@ def _dconv_fwd_kernel_factory(W, nblk, dot_dtype):
 
         acc = _dconv_band_loop(
             lo_ref, hi_ref, nblk, ftt_ref.shape[2] // _DCONV_CHUNK, f, w,
-            step, jnp.zeros(col_ref.shape[:0:-1], jnp.float32))
-        col_ref[0] = acc.T.astype(col_ref.dtype)
+            step, jnp.zeros(col_ref.shape[1:], jnp.float32))
+        col_ref[0] = acc.astype(col_ref.dtype)
     return kern
 
 
 def _dconv_bwd_kernel_factory(W, nblk, dot_dtype):
     def kern(lo_ref, hi_ref, y0_ref, y1_ref, x0_ref, x1_ref, ly_ref, lx_ref,
-             lf_ref, ft_ref, g_ref, dly_ref, dlx_ref, dlf_ref, dft_ref):
+             lf_ref, ft_ref, gt_ref, dly_ref, dlx_ref, dlf_ref, dft_ref):
         import jax.experimental.pallas as pl
 
         i = pl.program_id(1)
@@ -807,7 +818,7 @@ def _dconv_bwd_kernel_factory(W, nblk, dot_dtype):
         ly, lx, lf = sl(ly_ref), sl(lx_ref), sl(lf_ref)
         f, w = _dconv_corners(sl(y0_ref), sl(y1_ref), sl(x0_ref),
                               sl(x1_ref), ly, lx, lf, W)
-        g = g_ref[0].astype(dot_dtype)
+        g_t = gt_ref[0].astype(dot_dtype)                      # (C, nblk)
 
         @pl.when(i == 0)
         def _init():
@@ -815,13 +826,15 @@ def _dconv_bwd_kernel_factory(W, nblk, dot_dtype):
 
         def step(rows, hit, a_t, corner_sums):
             # dA_T = ft[step] @ g^T — contraction over channels, in VMEM
-            da_t = jax.lax.dot_general(
-                ft_ref[0, rows, :], g, (((1,), (1,)), ((), ())),
-                precision=_dconv_prec(dot_dtype),
+            da_t = jnp.dot(
+                ft_ref[0, rows, :], g_t, precision=_dconv_prec(dot_dtype),
                 preferred_element_type=jnp.float32)
-            # d_ft[step] += A_T[step] @ g: the band's rows only
-            dft_ref[0, rows, :] += jnp.dot(
-                a_t.astype(dot_dtype), g, precision=_dconv_prec(dot_dtype),
+            # d_ft[step] += A_T[step] @ g, the band's rows only: g^T's
+            # samples lie on the lanes as A_T's do, so the two lane axes
+            # contract (the q @ k^T form)
+            dft_ref[0, rows, :] += jax.lax.dot_general(
+                a_t.astype(dot_dtype), g_t, (((1,), (1,)), ((), ())),
+                precision=_dconv_prec(dot_dtype),
                 preferred_element_type=jnp.float32)
             return tuple(
                 s + jnp.where(h, da_t, 0.0).sum(axis=0, keepdims=True)
@@ -848,15 +861,18 @@ def _dconv_pad(a, n_pad, mode="constant"):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def dconv_col_pallas(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret=False):
-    """col[bg, n, :] = A[bg, n, :] @ ft[bg] with A built in VMEM (above).
+def dconv_col_pallas(y0, y1, x0, x1, ly, lx, lf, ftt, hw, interpret=False):
+    """col^T[bg] = ft^T[bg] @ A^T[bg] with A built in VMEM (above):
+    ``col^T[bg, c, n] = sum_p A[bg, n, p] * ft^T[bg, c, p]``, channels-major
+    on both sides.
 
     y0..x1: (BG, N) int32, inside the map; ly/lx/lf: (BG, N) f32;
-    ft: (BG, H*W, C); ``hw`` = (H, W) static.  Returns (BG, N, C) in ft's
-    dtype with f32 accumulation (== the XLA path's a.astype(ft.dtype) @ ft
-    contract).
+    ftt: (BG, C, H*W), the NCHW data's own view; ``hw`` = (H, W) static.
+    Returns (BG, C, N) in ftt's dtype with f32 accumulation (== the XLA
+    path's ft^T @ a.astype(ft.dtype)^T contract); its cotangent comes back
+    in the same layout, and d_ftt is (BG, C, H*W).
     """
-    return _dconv_impl(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret)
+    return _dconv_impl(y0, y1, x0, x1, ly, lx, lf, ftt, hw, interpret)
 
 
 def _dconv_grid(N, HW=None, C=None, itemsize=4):
@@ -894,87 +910,92 @@ def _dconv_grid(N, HW=None, C=None, itemsize=4):
     return nblk, -(-N // nblk) * nblk
 
 
-def _dconv_operands(y0, y1, x0, x1, ly, lx, lf, ft, W):
+def _dconv_operands(y0, y1, x0, x1, ly, lx, lf, ftt, W):
     """What both kernels take: the grid, then the band (scalar prefetch)
-    and the seven per-sample rows padded to the grid, then ft padded to
-    whole steps, and the spec of a per-sample row."""
+    and the seven per-sample rows padded to the grid, then ft^T padded to
+    whole steps, and the specs of a per-sample row and of a ``(C, nblk)``
+    block of columns."""
     from jax.experimental import pallas as pl
 
     N = y0.shape[1]
-    HW, C = ft.shape[1], ft.shape[2]
-    nblk, n_pad = _dconv_grid(N, HW, C, jnp.dtype(ft.dtype).itemsize)
+    C, HW = ftt.shape[1], ftt.shape[2]
+    nblk, n_pad = _dconv_grid(N, HW, C, jnp.dtype(ftt.dtype).itemsize)
     # padded rows carry lf=0 => A row = 0 => no effect anywhere; their
     # corners repeat a live row of the block, so they never widen its band
     ints = [_dconv_pad(a, n_pad, "edge") for a in (y0, y1, x0, x1)]
     flts = [_dconv_pad(a, n_pad) for a in (ly, lx, lf)]
     band = _dconv_band(ints[0][:, 0], ints[1][:, 0], W, HW, nblk)
-    ft = jnp.pad(ft, ((0, 0), (0, _dconv_hw_pad(HW) - HW), (0, 0)))
+    ftt = jnp.pad(ftt, ((0, 0), (0, 0), (0, _dconv_hw_pad(HW) - HW)))
     row_spec = pl.BlockSpec((1, 1, n_pad), lambda bg, i, lo, hi: (bg, 0, 0))
-    return nblk, n_pad, (*band, *ints, *flts), ft, row_spec
+    col_spec = pl.BlockSpec((1, C, nblk), lambda bg, i, lo, hi: (bg, 0, i))
+    return nblk, n_pad, (*band, *ints, *flts), ftt, row_spec, col_spec
 
 
-def _dconv_impl(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret):
+def _dconv_impl(y0, y1, x0, x1, ly, lx, lf, ftt, hw, interpret):
     return _per_batch_shard(
         lambda *a: _dconv_fwd_call(*a, hw, interpret),
-        y0, y1, x0, x1, ly, lx, lf, ft)
+        y0, y1, x0, x1, ly, lx, lf, ftt)
 
 
-def _dconv_fwd_call(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret):
+def _dconv_fwd_call(y0, y1, x0, x1, ly, lx, lf, ftt, hw, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     W = hw[1]
     BG, N = y0.shape
-    HW, C = ft.shape[1], ft.shape[2]
+    C, HW = ftt.shape[1], ftt.shape[2]
     _record_cost(
         "dconv_col_pallas_fwd",
-        cost_dconv_col_fwd(BG, N, HW, C, jnp.dtype(ft.dtype).itemsize),
-        ft.shape)
-    nblk, n_pad, rows, ft, row_spec = _dconv_operands(
-        y0, y1, x0, x1, ly, lx, lf, ft, W)
+        cost_dconv_col_fwd(BG, N, HW, C, jnp.dtype(ftt.dtype).itemsize),
+        ftt.shape)
+    nblk, n_pad, rows, ftt, row_spec, col_spec = _dconv_operands(
+        y0, y1, x0, x1, ly, lx, lf, ftt, W)
     out = pl.pallas_call(
-        _dconv_fwd_kernel_factory(W, nblk, ft.dtype),
-        out_shape=jax.ShapeDtypeStruct((BG, n_pad, C), ft.dtype),
+        _dconv_fwd_kernel_factory(W, nblk, ftt.dtype),
+        out_shape=jax.ShapeDtypeStruct((BG, C, n_pad), ftt.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(BG, n_pad // nblk),
             in_specs=[row_spec] * 7 + [pl.BlockSpec(
-                (1, C, ft.shape[1]), lambda bg, i, lo, hi: (bg, 0, 0))],
-            out_specs=pl.BlockSpec((1, nblk, C),
-                                   lambda bg, i, lo, hi: (bg, i, 0))),
+                (1, C, ftt.shape[2]), lambda bg, i, lo, hi: (bg, 0, 0))],
+            out_specs=col_spec),
         interpret=interpret,
         name="dconv_col_pallas_fwd",
-    )(*rows, ft.transpose(0, 2, 1))
-    return out[:, :N]
+    )(*rows, ftt)
+    return out[:, :, :N]
 
 
-def _dconv_fwd(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret):
-    out = _dconv_impl(y0, y1, x0, x1, ly, lx, lf, ft, hw, interpret)
-    return out, (y0, y1, x0, x1, ly, lx, lf, ft)
+def _dconv_fwd(y0, y1, x0, x1, ly, lx, lf, ftt, hw, interpret):
+    out = _dconv_impl(y0, y1, x0, x1, ly, lx, lf, ftt, hw, interpret)
+    return out, (y0, y1, x0, x1, ly, lx, lf, ftt)
 
 
 def _dconv_bwd(hw, interpret, res, g):
     import numpy as _np
 
-    dly, dlx, dlf, dft = _per_batch_shard(
+    dly, dlx, dlf, dftt = _per_batch_shard(
         lambda *a: _dconv_bwd_call(*a, hw, interpret), *res, g)
     f0 = lambda a: _np.zeros(a.shape, jax.dtypes.float0)
-    return (*(f0(a) for a in res[:4]), dly, dlx, dlf, dft)
+    return (*(f0(a) for a in res[:4]), dly, dlx, dlf, dftt)
 
 
-def _dconv_bwd_call(y0, y1, x0, x1, ly, lx, lf, ft, g, hw, interpret):
+def _dconv_bwd_call(y0, y1, x0, x1, ly, lx, lf, ftt, g_t, hw, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     W = hw[1]
     BG, N = y0.shape
-    HW, C = ft.shape[1], ft.shape[2]
+    C, HW = ftt.shape[1], ftt.shape[2]
     _record_cost(
         "dconv_col_pallas_bwd",
-        cost_dconv_col_bwd(BG, N, HW, C, jnp.dtype(ft.dtype).itemsize),
-        ft.shape)
-    nblk, n_pad, rows, ft, row_spec = _dconv_operands(
-        y0, y1, x0, x1, ly, lx, lf, ft, W)
-    gp = jnp.pad(g, ((0, 0), (0, n_pad - N), (0, 0)))
+        cost_dconv_col_bwd(BG, N, HW, C, jnp.dtype(ftt.dtype).itemsize),
+        ftt.shape)
+    nblk, n_pad, rows, ftt, row_spec, col_spec = _dconv_operands(
+        y0, y1, x0, x1, ly, lx, lf, ftt, W)
+    # the backward contracts over and accumulates (HW, C): the two
+    # features-sized transposes left to XLA
+    ft = ftt.transpose(0, 2, 1)
+    # padded columns of g^T meet rows of A that are zero (lf = 0)
+    gp = jnp.pad(g_t, ((0, 0), (0, 0), (0, n_pad - N)))
     row_out = jax.ShapeDtypeStruct((BG, 1, n_pad), jnp.float32)
     map_spec = pl.BlockSpec((1, ft.shape[1], C),
                             lambda bg, i, lo, hi: (bg, 0, 0))
@@ -984,14 +1005,13 @@ def _dconv_bwd_call(y0, y1, x0, x1, ly, lx, lf, ft, g, hw, interpret):
                    jax.ShapeDtypeStruct(ft.shape, jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(BG, n_pad // nblk),
-            in_specs=[row_spec] * 7 + [map_spec, pl.BlockSpec(
-                (1, nblk, C), lambda bg, i, lo, hi: (bg, i, 0))],
+            in_specs=[row_spec] * 7 + [map_spec, col_spec],
             out_specs=(row_spec, row_spec, row_spec, map_spec)),
         interpret=interpret,
         name="dconv_col_pallas_bwd",
     )(*rows, ft, gp)
     return (dly[:, 0, :N], dlx[:, 0, :N], dlf[:, 0, :N],
-            dft[:, :HW].astype(ft.dtype))
+            dft[:, :HW].astype(ft.dtype).transpose(0, 2, 1))
 
 
 dconv_col_pallas.defvjp(_dconv_fwd, _dconv_bwd)

@@ -377,11 +377,13 @@ def dconv_sample_inputs(rng, bg, hw, offset=None, kernel=3, dilate=2,
     return y0, y1, x0, x1, sy - y0, sx - x0, live.astype(np.float32)
 
 
-def dconv_dense_reference(y0, y1, x0, x1, ly, lx, lf, ft, hw):
+def dconv_dense_reference(y0, y1, x0, x1, ly, lx, lf, ftt, hw):
     """The dense one-hot formulation of ``dconv_col_pallas``, what
-    ``deformable_convolution``'s XLA scan computes: the whole sample matrix
-    A, rounded to ft's dtype, times ft with f32 accumulation.  A is
-    ``(BG, N, H*W)`` f32: for toy sizes, or one (image, group)."""
+    ``deformable_convolution``'s XLA scan computes, in the kernel's
+    channels-major layout: ``ftt (BG, C, H*W)`` times the whole sample
+    matrix A transposed, rounded to ftt's dtype, with f32 accumulation:
+    ``(BG, C, N)``.  A is ``(BG, N, H*W)`` f32: for toy sizes, or one
+    (image, group)."""
     import jax
     import jax.numpy as jnp
 
@@ -392,6 +394,6 @@ def dconv_dense_reference(y0, y1, x0, x1, ly, lx, lf, ft, hw):
     xv = lf[..., None] * ((1 - lx)[..., None] * (x0[..., None] == ix)
                           + lx[..., None] * (x1[..., None] == ix))
     a = (yv[..., :, None] * xv[..., None, :]).reshape(*y0.shape, H * W)
-    return jnp.einsum("bnp,bpc->bnc", a.astype(ft.dtype), ft,
+    return jnp.einsum("bcp,bnp->bcn", ftt, a.astype(ftt.dtype),
                       precision=jax.lax.Precision.HIGHEST,
-                      preferred_element_type=jnp.float32).astype(ft.dtype)
+                      preferred_element_type=jnp.float32).astype(ftt.dtype)

@@ -227,8 +227,8 @@ class TestDconvWiring:
         ly = jnp.asarray(rng.rand(BG, N).astype(np.float32))
         lx = jnp.asarray(rng.rand(BG, N).astype(np.float32))
         lf = jnp.asarray((rng.rand(BG, N) > 0.2).astype(np.float32))
-        ft = jnp.asarray(rng.randn(BG, HW, C).astype(np.float32))
-        g = jnp.asarray(rng.randn(BG, N, C).astype(np.float32))
+        ft = jnp.asarray(rng.randn(BG, C, HW).astype(np.float32))
+        g = jnp.asarray(rng.randn(BG, C, N).astype(np.float32))
 
         def run(nblk):
             with autotune.override("dconv_col_pallas", {"nblk": nblk}):

@@ -934,12 +934,14 @@ def test_deformable_psroi_onehot_saves_no_accumulation_matrix(grouped):
 
 def test_dconv_col_pallas_matches_xla_formulation():
     """Round-5 fused dconv sampling kernel: VMEM-resident A (and dA) must
-    equal the XLA one-hot-matmul formulation, values and all grads —
-    interpret mode here; the chip consistency tier covers the compiled
-    kernel and `bench.py` the in-module win (33.8 → 35.3 img/s)."""
+    equal the XLA one-hot-matmul formulation, values and all four grads
+    (d_ly, d_lx, d_lf, d_ft), channels-major on both sides (PR 32:
+    ``ft^T (BG, C, HW)`` in, ``col^T (BG, C, N)`` out) — interpret mode
+    here; the chip consistency tier covers the compiled kernel."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops.pallas_kernels import dconv_col_pallas
+    from mxnet_tpu.test_utils import dconv_dense_reference
 
     BG, N, H, W, C = 3, 70, 9, 11, 16   # N not a block multiple
     HW = H * W
@@ -951,30 +953,25 @@ def test_dconv_col_pallas_matches_xla_formulation():
     ly = jnp.asarray(rng.rand(BG, N).astype(np.float32))
     lx = jnp.asarray(rng.rand(BG, N).astype(np.float32))
     lf = jnp.asarray((rng.rand(BG, N) > 0.2).astype(np.float32))
-    ft = jnp.asarray(rng.randn(BG, HW, C).astype(np.float32))
+    ft = jnp.asarray(rng.randn(BG, C, HW).astype(np.float32))
 
-    def ref(y0, y1, x0, x1, ly, lx, lf, ft):
-        iy = jnp.arange(H)
-        ix = jnp.arange(W)
-        yv = ((1 - ly)[..., None] * (y0[..., None] == iy)
-              + ly[..., None] * (y1[..., None] == iy))
-        xv = lf[..., None] * ((1 - lx)[..., None] * (x0[..., None] == ix)
-                              + lx[..., None] * (x1[..., None] == ix))
-        a = jnp.einsum("bnh,bnw->bnhw", yv, xv).reshape(BG, N, HW)
-        return jnp.einsum("bnp,bpc->bnc", a, ft)
+    def ref(*a):
+        return dconv_dense_reference(*a, (H, W))
 
     r = ref(y0, y1, x0, x1, ly, lx, lf, ft)
     o = dconv_col_pallas(y0, y1, x0, x1, ly, lx, lf, ft, (H, W), True)
+    assert o.shape == r.shape == (BG, C, N)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r),
                                rtol=1e-5, atol=1e-5)
 
-    g = jnp.asarray(rng.randn(BG, N, C).astype(np.float32))
+    g = jnp.asarray(rng.randn(BG, C, N).astype(np.float32))
     fr = lambda *a: jnp.sum(ref(y0, y1, x0, x1, *a) * g)
     fp = lambda *a: jnp.sum(
         dconv_col_pallas(y0, y1, x0, x1, *a, (H, W), True) * g)
     gr = jax.grad(fr, argnums=(0, 1, 2, 3))(ly, lx, lf, ft)
     gp = jax.grad(fp, argnums=(0, 1, 2, 3))(ly, lx, lf, ft)
     for i in range(4):
+        assert gp[i].shape == gr[i].shape
         np.testing.assert_allclose(np.asarray(gp[i]), np.asarray(gr[i]),
                                    rtol=1e-4, atol=1e-4)
 
@@ -982,6 +979,7 @@ def test_dconv_col_pallas_matches_xla_formulation():
 def test_deformable_conv_impl_env_override():
     """MXNET_DCONV_IMPL=pallas runs the fused kernel (interpret on CPU)
     and must match the default XLA path on the big-path shapes."""
+    import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops import detection as D
 
@@ -995,11 +993,88 @@ def test_deformable_conv_impl_env_override():
     wt = jnp.asarray(rng.randn(F, C, 3, 3).astype(np.float32) * 0.1)
     kw = dict(kernel=(3, 3), num_filter=F, pad=(1, 1),
               num_deformable_group=2, no_bias=True)
-    base = D.deformable_convolution(data, off, wt, **kw)
+    cot = jnp.asarray(rng.randn(B, F, H, W).astype(np.float32))
+
+    def out_and_grads():
+        def loss(*a):
+            return jnp.sum(D.deformable_convolution(*a, **kw) * cot)
+        return (D.deformable_convolution(data, off, wt, **kw),
+                *jax.grad(loss, argnums=(0, 1, 2))(data, off, wt))
+
+    base = out_and_grads()
     os.environ["MXNET_DCONV_IMPL"] = "pallas"
     try:
-        pal = D.deformable_convolution(data, off, wt, **kw)
+        pal = out_and_grads()
     finally:
         del os.environ["MXNET_DCONV_IMPL"]
-    np.testing.assert_allclose(np.asarray(pal), np.asarray(base),
-                               rtol=1e-4, atol=1e-4)
+    # the XLA scan and the kernel hand over the same channels-major columns
+    # (PR 32): values, then the gradients to data, offsets and weights
+    for name, p, b in zip(("out", "d_data", "d_offset", "d_weight"), pal,
+                          base):
+        np.testing.assert_allclose(np.asarray(p), np.asarray(b), rtol=1e-4,
+                                   atol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+def test_grouped_col_product_vjp_matches_einsum(groups):
+    """``_grouped_col_product`` writes its own cotangents (the columns' one
+    as ``(b, g, k, p)``, no transpose): value and both gradients equal the
+    plain einsum's, grouped or not."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.detection import _grouped_col_product
+
+    rng = np.random.RandomState(4)
+    B, G, Fg, K, Pn = 3, groups, 5, 18, 40
+    wmat = jnp.asarray(rng.randn(G, Fg, K).astype(np.float32))
+    col = jnp.asarray(rng.randn(B, G, K, Pn).astype(np.float32))
+    cot = jnp.asarray(rng.randn(B, G, Fg, Pn).astype(np.float32))
+    plain = lambda w, c: jnp.einsum("gfk,bgkp->bgfp", w, c)
+    got = jax.vjp(_grouped_col_product, wmat, col)
+    want = jax.vjp(plain, wmat, col)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=1e-6, atol=1e-6)
+    for g, w in zip(got[1](cot), want[1](cot)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_deformable_conv_transposes_no_columns(impl, monkeypatch):
+    """The mechanism of PR 32: features and columns stay channels-major from
+    the data to the grouped product, so the traced forward + backward of
+    ``deformable_convolution`` on the one-hot path holds no ``transpose`` of
+    an array as large as the columns (``B x C x N``), through the kernel
+    pair and through the XLA scan alike.  Before, the kernel's ``(BG, N,
+    cpg)`` columns were transposed into ``(B, C, K2, Ho, Wo)`` and their
+    gradient back: two per layer.  What is left are features-sized
+    (``B x C x HW``, a ninth of the columns for a 3x3 kernel)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import detection as D
+
+    monkeypatch.setenv("MXNET_DCONV_IMPL", impl)
+    B, C, H, W, F, DG = 2, 8, 24, 32, 8, 2
+    N = 9 * H * W
+    assert N * H * W >= 1 << 22  # the one-hot path, where the kernels are
+    kw = dict(kernel=(3, 3), num_filter=F, pad=(1, 1),
+              num_deformable_group=DG, no_bias=True)
+    shapes = ((B, C, H, W), (B, 2 * 9 * DG, H, W), (F, C, 3, 3))
+
+    def loss(data, off, wt):
+        return jnp.sum(D.deformable_convolution(data, off, wt, **kw))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+        *(jnp.zeros(s, jnp.float32) for s in shapes))
+    eqns = list(_all_eqns(jaxpr.jaxpr))
+    if impl == "pallas":
+        kernels = {e.params["name"] for e in eqns
+                   if e.primitive.name == "pallas_call"}
+        assert kernels == {"dconv_col_pallas_fwd", "dconv_col_pallas_bwd"}
+    sizes = [int(np.prod(e.invars[0].aval.shape)) for e in eqns
+             if e.primitive.name == "transpose"]
+    assert sizes, "the features' transposes of the backward are still there"
+    # columns-sized is B*C*N; the threshold is half of that, the features
+    # B*C*HW = 2/9 of it
+    assert max(sizes) < N * C, sorted(sizes)[-4:]

@@ -197,7 +197,7 @@ def test_batched_kernels_run_per_dp_shard_under_a_visible_mesh():
     y0, x0 = np.floor(sy).astype(np.int32), np.floor(sx).astype(np.int32)
     y1, x1 = np.minimum(y0 + 1, H - 1), np.minimum(x0 + 1, W - 1)
     lf = (rng.rand(BG, n) > 0.1).astype(np.float32)
-    ft = rng.randn(BG, H * W, C).astype(np.float32)
+    ft = rng.randn(BG, C, H * W).astype(np.float32)
 
     def loss(ly, lx, lf, ft):
         col = pk.dconv_col_pallas(y0, y1, x0, x1, ly, lx, lf, ft, (H, W),
@@ -218,20 +218,26 @@ def test_batched_kernels_run_per_dp_shard_under_a_visible_mesh():
 
 
 # -- the band-limited dconv kernel pair (PR 27) ------------------------------
-# name: (map, offsets' magnitude or None for map-wide, row block or None)
+# name: (map, offsets' magnitude or None for map-wide, row block or None,
+# channels)
 _DCONV_REGIMES = {
     # the base grid of a dilated 3x3 alone: every lerp weight 0 or 1
-    "zero_offsets": ((24, 32), 0.0, None),
-    "small_offsets": ((24, 32), 0.7, None),
+    "zero_offsets": ((24, 32), 0.0, None, 16),
+    "small_offsets": ((24, 32), 0.7, None, 16),
     # band = the whole map: the dense step
-    "map_wide": ((24, 32), None, None),
+    "map_wide": ((24, 32), None, None, 16),
     # most samples pushed outside: clipped to the edge, lf = 0
-    "outside": ((10, 16), 12.0, None),
+    "outside": ((10, 16), 12.0, None, 16),
     # N = 891 is no multiple of 64, and blocks of 64 straddle taps of 99
     # rows; W no power of two, HW no multiple of 128
-    "ragged_straddling": ((9, 11), 1.5, 64),
+    "ragged_straddling": ((9, 11), 1.5, 64, 16),
     # wider than one step of the loop, narrower than half the map
-    "two_steps": ((40, 32), 2.5, None),
+    "two_steps": ((40, 32), 2.5, None, 16),
+    # the channels-major layout's edges (PR 32): N = 1728 is 13.5 blocks of
+    # the default 128, so the last block's padded lanes of col^T are sliced
+    # off and the padded columns of g^T meet lf = 0; C = 20 is no multiple
+    # of the sublane tile, let alone of 128
+    "ragged_lanes": ((12, 16), 1.0, None, 20),
 }
 
 
@@ -239,19 +245,21 @@ _DCONV_REGIMES = {
 @pytest.mark.parametrize("regime", sorted(_DCONV_REGIMES))
 def test_dconv_band_matches_dense(regime, dtype, monkeypatch):
     """Forward and all four gradients of the band-limited kernels against
-    the dense formulation, whatever the band: one step, several, the whole
-    map; padded rows; a block that straddles two taps; a ragged map."""
+    the dense formulation in the channels-major layout (``col^T``, ``g^T``,
+    ``ft^T`` and ``d_ft^T``), whatever the band: one step, several, the
+    whole map; padded rows; a block that straddles two taps; a ragged map;
+    a ragged last block of lanes."""
     import jax
 
     from mxnet_tpu.ops import pallas_kernels as pk
     from mxnet_tpu.test_utils import (dconv_dense_reference,
                                       dconv_sample_inputs)
 
-    hw, offset, nblk = _DCONV_REGIMES[regime]
+    hw, offset, nblk, C = _DCONV_REGIMES[regime]
     if nblk:
         monkeypatch.setattr(pk, "_DCONV_NBLK", nblk)
     rng = np.random.RandomState(3)
-    BG, C = 2, 16
+    BG = 2
     y0, y1, x0, x1, ly, lx, lf = map(
         jnp.asarray, dconv_sample_inputs(rng, BG, hw, offset))
     share = float(pk.dconv_band_share(y0, y1, hw, pk._DCONV_NBLK))
@@ -260,9 +268,11 @@ def test_dconv_band_matches_dense(regime, dtype, monkeypatch):
     elif regime == "two_steps":
         n_chunks = pk._dconv_chunks(hw[0] * hw[1])
         assert pk._DCONV_STEP / n_chunks < share < 0.5
-    ft = jnp.asarray(rng.randn(BG, hw[0] * hw[1], C).astype(np.float32)
+    if regime == "ragged_lanes":
+        assert y0.shape[1] % pk._DCONV_NBLK and C % 8
+    ft = jnp.asarray(rng.randn(BG, C, hw[0] * hw[1]).astype(np.float32)
                      ).astype(dtype)
-    cot = jnp.asarray(rng.randn(BG, y0.shape[1], C).astype(np.float32))
+    cot = jnp.asarray(rng.randn(BG, C, y0.shape[1]).astype(np.float32))
 
     def run(fn):
         def loss(*a):
@@ -276,6 +286,8 @@ def test_dconv_band_matches_dense(regime, dtype, monkeypatch):
     # bf16: one rounding of the output / of d_ft, and the dense path's AD
     # rounds dA where the kernel keeps it f32
     tol = 2.0 ** -7 if dtype == "bfloat16" else 1e-5
+    assert got[0].shape == (BG, C, y0.shape[1])
+    assert got[4].shape == ft.shape
     for name, g, w in zip(("col", "d_ly", "d_lx", "d_lf", "d_ft"), got, want):
         assert g.shape == w.shape, name
         assert np.abs(g - w).max() <= tol * max(np.abs(w).max(), 1.0), name

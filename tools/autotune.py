@@ -210,8 +210,9 @@ def _search_dconv(args):
                                                   args.offset))
     y0, y1, x0, x1, ly, lx, lf = map(jnp.asarray,
                                      (y0, y1, x0, x1, ly, lx, lf))
-    ft = jnp.asarray(rng.randn(BG, HW, C)).astype(dtype)
-    g = jnp.asarray(rng.randn(BG, N, C).astype(np.float32))
+    # channels-major on both sides of the kernel: ft^T in, col^T out
+    ft = jnp.asarray(rng.randn(BG, C, HW)).astype(dtype)
+    g = jnp.asarray(rng.randn(BG, C, N).astype(np.float32))
     # the compiled kernel exists only on TPU; elsewhere measure the
     # interpreter (relative ordering only — label the numbers honestly)
     interpret = jax.default_backend() != "tpu"
