@@ -39,5 +39,6 @@ from .transformer_layers import (
     RotaryEmbedding,
     GatedFFN,
     IndexerSparseAttention,
+    LatentAttention,
     SparseMoE,
 )

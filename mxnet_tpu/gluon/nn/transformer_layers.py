@@ -1,17 +1,23 @@
 """Transformer layers over ``ops/transformer.py``: RMSNorm, rotary embedding
 with sections, the gated feed-forward, an attention block whose keys an
-indexer selects, and a mixture-of-experts block that holds a share of its
-layer's experts.  One sequence, token-major: activations are ``(S, units)``.
+indexer selects, a latent (compressed key-value) attention block, and a
+mixture-of-experts block that holds a share of its layer's experts.
+Token-major: activations are ``(S, units)``, one sequence, or ``(N, S,
+units)``, N documents that never see each other (``LatentAttention``,
+``SparseMoE``).
 """
 from __future__ import annotations
 
 import math
 
+import jax
+
+from ... import autograd
 from ..block import HybridBlock, remat
 from .basic_layers import Dense, LayerNorm
 
 __all__ = ["RMSNorm", "RotaryEmbedding", "GatedFFN", "IndexerSparseAttention",
-           "SparseMoE"]
+           "LatentAttention", "SparseMoE"]
 
 
 class RMSNorm(HybridBlock):
@@ -129,6 +135,45 @@ class IndexerSparseAttention(HybridBlock):
         return [self.o(out[0].reshape((-1, nq * d)))] + list(out[1:])
 
 
+class LatentAttention(HybridBlock):
+    """Multi-head latent attention (DeepSeek-V2), training form, no query
+    latent: keys and values are rebuilt from a ``latent``-wide normed vector
+    a token plus one rotary key of ``qk_rope_dim`` that all heads share;
+    query and key heads are ``qk_nope_dim + qk_rope_dim`` wide, value heads
+    ``v_dim``.  ``forward(a, positions)``: ``a`` (N, S, units) the normed
+    input of N documents (or (S, units)), ``positions`` (S,).  Dense causal
+    attention within each document, the output projection after it
+    (``ops.LatentAttention``).  -> the same shape as ``a``."""
+
+    def __init__(self, units, num_heads, latent, qk_nope_dim, qk_rope_dim,
+                 v_dim, theta=10000.0, latent_epsilon=1e-6, block=256,
+                 span=2048, weight_initializer=None, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._op = {"num_heads": num_heads, "qk_nope_dim": qk_nope_dim,
+                    "qk_rope_dim": qk_rope_dim, "v_dim": v_dim,
+                    "theta": theta, "latent_eps": latent_epsilon,
+                    "block": block, "span": span}
+        init = weight_initializer
+        with self.name_scope():
+            self.q_weight = self.params.get(
+                "q_weight", init=init,
+                shape=(num_heads * (qk_nope_dim + qk_rope_dim), units))
+            self.kv_a_weight = self.params.get(
+                "kv_a_weight", shape=(latent + qk_rope_dim, units), init=init)
+            self.kv_norm_gamma = self.params.get(
+                "kv_norm_gamma", shape=(latent,), init="ones")
+            self.kv_b_weight = self.params.get(
+                "kv_b_weight", init=init,
+                shape=(num_heads * (qk_nope_dim + v_dim), latent))
+            self.o = _proj(units, num_heads * v_dim, init, "o_")
+
+    def hybrid_forward(self, F, a, positions, q_weight, kv_a_weight,
+                       kv_norm_gamma, kv_b_weight):
+        return self.o(F.LatentAttention(a, positions, q_weight, kv_a_weight,
+                                        kv_norm_gamma, kv_b_weight,
+                                        **self._op))
+
+
 class SparseMoE(HybridBlock):
     """A top-k mixture-of-experts layer that holds ``num_held`` of its
     ``num_experts`` experts, from ``first_expert`` (expert parallelism: the
@@ -139,10 +184,24 @@ class SparseMoE(HybridBlock):
     expected count (None: every pair, the worst case); pairs that do not
     fit are counted in ``dropped``, never silently lost.  The expert
     products are recomputed in the backward pass.
-    -> [out, balance, pairs (num_held,), dropped, choice (T, top_k)]."""
+
+    The router's other form (``parallel.moe.route``): ``scoring="sigmoid"``,
+    gates times ``routed_scale``, and with ``bias_update_rate`` a selection
+    bias ``router_bias`` (E,) that chooses and never gates.  The bias is
+    auxiliary state (``grad_req='null'``): under training the forward pass
+    moves it by ``rate x sign(mean load - load)`` over the pairs this call's
+    tokens sent to each of the layer's experts, and a functional train step
+    carries it in ``state[2]``.  ``shared_units`` > 0 adds a gated
+    feed-forward of that width every token passes.  ``sequence_balance``:
+    the balance term is sequence-wise, over the N documents of an ``(N, S,
+    units)`` input (one for ``(T, units)``).
+    -> [out, balance, pairs (num_held,), dropped, choice (T, top_k)] and,
+    with the bias, router_pairs (num_experts,) and gates (T, top_k)."""
 
     def __init__(self, units, hidden_units, num_experts, top_k, num_held=None,
                  first_expert=0, norm_topk_prob=True, capacity_factor=None,
+                 scoring="softmax", routed_scale=1.0, bias_update_rate=None,
+                 shared_units=0, sequence_balance=False,
                  weight_initializer=None, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         held = num_experts if num_held is None else num_held
@@ -152,8 +211,11 @@ class SparseMoE(HybridBlock):
                                 num_experts))
         self._held_share = held * top_k / num_experts
         self._capacity_factor = capacity_factor
+        self._bias_update_rate = bias_update_rate
+        self._sequence_balance = sequence_balance
         self._op = {"top_k": top_k, "first_expert": first_expert,
-                    "norm_topk_prob": norm_topk_prob}
+                    "norm_topk_prob": norm_topk_prob, "scoring": scoring,
+                    "routed_scale": routed_scale}
         init = weight_initializer
         with self.name_scope():
             self.router_weight = self.params.get(
@@ -164,13 +226,41 @@ class SparseMoE(HybridBlock):
                 "up_weight", shape=(held, units, hidden_units), init=init)
             self.down_weight = self.params.get(
                 "down_weight", shape=(held, hidden_units, units), init=init)
+            if bias_update_rate is not None:
+                self.router_bias = self.params.get(
+                    "router_bias", shape=(num_experts,), init="zeros",
+                    grad_req="null", differentiable=False)
+            if shared_units:
+                for name, shape in (("gate", (shared_units, units)),
+                                    ("up", (shared_units, units)),
+                                    ("down", (units, shared_units))):
+                    setattr(self, "shared_%s_weight" % name, self.params.get(
+                        "shared_%s_weight" % name, shape=shape, init=init))
 
     def hybrid_forward(self, F, x, router_weight, gate_weight, up_weight,
-                       down_weight):
-        capacity = None
+                       down_weight, router_bias=None, shared_gate_weight=None,
+                       shared_up_weight=None, shared_down_weight=None):
+        shape = x.shape
+        tokens = x.reshape((-1, shape[-1]))
+        op = dict(self._op, capacity=None, sequences=0)
         if self._capacity_factor is not None:
-            capacity = math.ceil(x.shape[0] * self._held_share
-                                 * self._capacity_factor)
-        return remat(lambda *a: F.MoEExperts(*a, capacity=capacity,
-                                             **self._op))(
-            x, router_weight, gate_weight, up_weight, down_weight)
+            op["capacity"] = math.ceil(tokens.shape[0] * self._held_share
+                                       * self._capacity_factor)
+        if self._sequence_balance:
+            op["sequences"] = shape[0] if len(shape) == 3 else 1
+        optional = {k: v for k, v in (
+            ("router_bias", router_bias),
+            ("shared_gate_weight", shared_gate_weight),
+            ("shared_up_weight", shared_up_weight),
+            ("shared_down_weight", shared_down_weight)) if v is not None}
+        out = remat(lambda *a: F.MoEExperts(
+            *a[:5], **dict(zip(optional, a[5:])), **op))(
+            tokens, router_weight, gate_weight, up_weight, down_weight,
+            *optional.values())
+        if router_bias is not None and autograd.is_training():
+            with autograd.pause(), jax.named_scope("moe.bias_update"):
+                load = out[5].astype("float32")
+                self.router_bias.data()._rebind(
+                    (router_bias + self._bias_update_rate
+                     * F.sign(F.mean(load) - load))._data)
+        return [out[0].reshape(shape)] + list(out[1:])

@@ -1,11 +1,15 @@
 """Transformer operators: RMSNorm, rotary embedding with sections, the gated
-feed-forward, indexer-selected sparse attention and the expert layer.
+feed-forward, indexer-selected sparse attention, dense causal attention, the
+latent (compressed key-value) attention around it, the expert layer and the
+head's log-probabilities in row blocks.
 
 None has a counterpart in the reference (its attention lived in user code
-over ``batch_dot`` + ``softmax``).  Layouts are token-major, one sequence:
-activations ``(S, D)``, heads ``(S, heads, head_dim)``; weights are
-``(out, in)`` like ``FullyConnected``'s.  Statistics, softmaxes, index
-scores and the router run in float32 whatever the activations' type.
+over ``batch_dot`` + ``softmax``).  Layouts are token-major: activations
+``(S, D)``, heads ``(S, heads, head_dim)``, one sequence, or with a leading
+axis of documents that never see each other (``CausalAttention``,
+``LatentAttention``); weights are ``(out, in)`` like ``FullyConnected``'s.
+Statistics, softmaxes, index scores and the router run in float32 whatever
+the activations' type.
 
 ``IndexerSparseAttention`` is DeepSeek-V3.2-Exp's sparse attention as a
 training operator: a light indexer scores every causal key for every query,
@@ -14,6 +18,13 @@ KL term teaches the indexer the attention's own distribution.  It walks the
 queries in blocks (no ``(heads, S, S)`` array exists), skips the key blocks
 above the diagonal by spans, and saves each query's threshold for the
 backward pass, which recomputes scores but never the selection.
+
+``CausalAttention`` is the same walk with every causal key selected and
+nothing to index: blocks of queries against the keys up to the end of their
+span, the block's weights recomputed in the backward pass.
+``LatentAttention`` (DeepSeek-V2's multi-head latent attention, training
+form) rebuilds every head's keys and values from one narrow normed latent a
+token plus one rotary key all heads share, and hands them to it.
 """
 from __future__ import annotations
 
@@ -65,11 +76,11 @@ def rotary_embedding(data, positions, *, theta=10000.0, sections=()):
 
 @register("GatedFFN")
 def gated_ffn(data, gate_weight, up_weight, down_weight):
-    """SwiGLU feed-forward ``(silu(x Wg^T) * (x Wu^T)) Wd^T``; ``Wg`` / ``Wu``
-    (F, D), ``Wd`` (D, F)."""
-    g = jnp.einsum("td,fd->tf", data, gate_weight)
-    u = jnp.einsum("td,fd->tf", data, up_weight)
-    return jnp.einsum("tf,df->td", jax.nn.silu(g) * u, down_weight)
+    """SwiGLU feed-forward ``(silu(x Wg^T) * (x Wu^T)) Wd^T`` over the last
+    axis; ``Wg`` / ``Wu`` (F, D), ``Wd`` (D, F)."""
+    g = jnp.einsum("...d,fd->...f", data, gate_weight)
+    u = jnp.einsum("...d,fd->...f", data, up_weight)
+    return jnp.einsum("...f,df->...d", jax.nn.silu(g) * u, down_weight)
 
 
 # -- indexer-selected sparse attention -----------------------------------------
@@ -296,17 +307,291 @@ def indexer_sparse_attention(query, key, value, index_query, index_key,
     return res
 
 
+# -- dense causal attention and the latent projections around it ---------------
+def _causal_block(k, v, q, t):
+    """One block of one document's queries against its keys ``[0, Sk)``: q
+    (B, Hq, d) at positions t (B,), k (Sk, Hkv, d), v (Sk, Hkv, dv).
+    -> o (B, Hq, dv).  :func:`_attend_block` with every causal key selected."""
+    B, Hq, d = q.shape
+    Hkv = k.shape[1]
+    causal = jnp.arange(k.shape[0])[None, :] <= t[:, None]
+    e, z = _weights(q.reshape(B, Hkv, Hq // Hkv, d), k, causal)
+    o = (jnp.einsum("hgqk,khd->qhgd", e, v,
+                    preferred_element_type=jnp.float32)
+         / z.transpose(2, 0, 1)[..., None]).astype(v.dtype)
+    return o.reshape(B, Hq, v.shape[-1])
+
+
+def _causal_block_bwd(k, v, q, t, o, do):
+    """The block's weights recomputed -> (dq (B, Hq, d), dk, dv in float32:
+    the caller sums them over the blocks).  What it holds of (heads, B, Sk)
+    is in the compute type."""
+    B, Hq, d = q.shape
+    Hkv = k.shape[1]
+    qg = q.reshape(B, Hkv, Hq // Hkv, d)
+    heads = (B, Hkv, Hq // Hkv, v.shape[-1])
+    causal = jnp.arange(k.shape[0])[None, :] <= t[:, None]
+    e, z = _weights(qg, k, causal)
+    zt = z.transpose(2, 0, 1)[..., None]                         # (B, h, g, 1)
+    dog = do.reshape(heads).astype(jnp.float32)
+    # o = (e v) / z:  de = (do . v - do . o) / z,  ds = e de / sqrt(d)
+    dov = (dog / zt).astype(v.dtype)
+    shift = (jnp.sum(dog * o.reshape(heads).astype(jnp.float32), -1,
+                     keepdims=True) / zt).transpose(1, 2, 0, 3)
+    ds = (e.astype(jnp.float32) * d ** -0.5
+          * (jnp.einsum("qhgd,khd->hgqk", dov, v,
+                        preferred_element_type=jnp.float32)
+             - shift)).astype(k.dtype)
+    dv = jnp.einsum("hgqk,qhgd->khd", e, dov,
+                    preferred_element_type=jnp.float32)
+    dq = jnp.einsum("hgqk,khd->qhgd", ds, k).reshape(q.shape)
+    dk = jnp.einsum("hgqk,qhgd->khd", ds, qg,
+                    preferred_element_type=jnp.float32)
+    return dq, dk, dv
+
+
+def _tiles(S, block, span):
+    span = min(span, S)
+    block = min(block, span)
+    if S % span or span % block:
+        raise ValueError("sequence %d, span %d and block %d must divide"
+                         % (S, span, block))
+    return block, span
+
+
+def _blocks(x, rows, block):
+    """(N, S, ..) -> the rows' blocks first: (blocks, N, block, ..)."""
+    x = x[:, rows]
+    return x.reshape((x.shape[0], -1, block) + x.shape[2:]).swapaxes(0, 1)
+
+
+def _causal_forward(q, k, v, block, span):
+    """q, k (N, S, H., d), v (N, S, Hkv, dv) -> o (N, S, Hq, dv): ``block``
+    queries of every document at a time against the keys up to the end of
+    their ``span`` of queries."""
+    N, S = q.shape[:2]
+    block, span = _tiles(S, block, span)
+    one_block = jax.vmap(_causal_block, in_axes=(0, 0, 0, None))
+    t_all = jnp.arange(S, dtype=jnp.int32)
+    outs = []
+    for end in range(span, S + 1, span):
+        rows = slice(end - span, end)
+        outs.append(lax.map(
+            lambda blk, end=end: one_block(k[:, :end], v[:, :end], *blk),
+            (_blocks(q, rows, block), t_all[rows].reshape(-1, block))))
+    o = jnp.concatenate(outs)                        # (S / block, N, block, ..)
+    return o.swapaxes(0, 1).reshape((N, S) + o.shape[3:])
+
+
+def _causal_backward(q, k, v, o, do, block, span):
+    """-> (dq, dk, dv): every block's weights recomputed from q, k, v, the
+    keys' and values' gradients summed over the blocks in float32."""
+    N, S = q.shape[:2]
+    block, span = _tiles(S, block, span)
+    one_block = jax.vmap(_causal_block_bwd, in_axes=(0, 0, 0, None, 0, 0))
+    t_all = jnp.arange(S, dtype=jnp.int32)
+    dk = jnp.zeros(k.shape, jnp.float32)
+    dv = jnp.zeros(v.shape, jnp.float32)
+    dqs = []
+    for end in range(span, S + 1, span):
+        rows = slice(end - span, end)
+
+        def body(acc, blk, end=end):
+            dq_b, dk_b, dv_b = one_block(k[:, :end], v[:, :end], *blk)
+            return (acc[0] + dk_b, acc[1] + dv_b), dq_b
+
+        (dk_s, dv_s), dq = lax.scan(
+            body, (jnp.zeros_like(dk[:, :end]), jnp.zeros_like(dv[:, :end])),
+            (_blocks(q, rows, block), t_all[rows].reshape(-1, block),
+             _blocks(o, rows, block), _blocks(do, rows, block)))
+        dk = dk.at[:, :end].add(dk_s)
+        dv = dv.at[:, :end].add(dv_s)
+        dqs.append(dq)
+    dq = jnp.concatenate(dqs).swapaxes(0, 1).reshape(q.shape)
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_causal_attention = jax.custom_vjp(_causal_forward, nondiff_argnums=(3, 4))
+
+
+def _causal_attention_fwd(q, k, v, block, span):
+    o = _causal_forward(q, k, v, block, span)
+    return o, (q, k, v, o)
+
+
+def _causal_attention_bwd(block, span, res, do):
+    return _causal_backward(*res, do, block, span)
+
+
+_causal_attention.defvjp(_causal_attention_fwd, _causal_attention_bwd)
+
+
+@register("CausalAttention")
+def causal_attention(query, key, value, *, block=256, span=2048):
+    """Dense causal softmax attention, each document alone.
+
+    ``query`` (N, S, Hq, d), ``key`` (N, S, Hkv, d), ``value`` (N, S, Hkv,
+    dv), ``Hq % Hkv == 0``, ``dv`` free of ``d``; or one document without
+    the leading axis.  Query t of document n reads that document's keys
+    ``s <= t`` (scale ``d^-1/2``) and no other document's.  -> (N, S, Hq, dv).
+
+    No (heads, S, S) array exists in either pass: ``block`` queries of every
+    document are scored at a time against the keys up to the end of their
+    ``span`` of queries (tiles: they change no result), and the backward pass
+    recomputes each block's weights from the saved queries, keys and values.
+    The softmax is shifted by ``|q| max_s |k_s| d^-1/2`` like
+    ``IndexerSparseAttention``'s: the same result while that bound stays
+    under about 40.
+    """
+    if query.ndim == 3:
+        return _causal_attention(query[None], key[None], value[None],
+                                 block, span)[0]
+    return _causal_attention(query, key, value, block, span)
+
+
+def _latent_project(sizes, data, positions, q_weight, kv_a_weight,
+                    kv_norm_gamma, kv_b_weight):
+    """-> q, k (N, S, H, nope + rope), v (N, S, H, v_dim)."""
+    H, nope, rope, v_dim, theta, latent_eps = sizes
+    N, S, _ = data.shape
+    rotary = lambda x: rotary_embedding(x, positions, theta=theta)  # noqa: E731
+    q = jnp.einsum("nsd,od->nso", data, q_weight).reshape(N, S, H, nope + rope)
+    ckr = jnp.einsum("nsd,od->nso", data, kv_a_weight)
+    latent = kv_b_weight.shape[1]
+    c = rms_norm(ckr[..., :latent], kv_norm_gamma, eps=latent_eps)
+    kr = rotary(ckr[..., latent:].reshape(N, S, 1, rope))
+    kv = jnp.einsum("nsc,oc->nso", c, kv_b_weight).reshape(
+        N, S, H, nope + v_dim)
+    q = jnp.concatenate([q[..., :nope], rotary(q[..., nope:])], -1)
+    k = jnp.concatenate([kv[..., :nope],
+                         jnp.broadcast_to(kr, (N, S, H, rope))], -1)
+    return q, k, kv[..., nope:]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _latent_attention(sizes, block, span, data, positions, *weights):
+    return _latent_attention_fwd(sizes, block, span, data, positions,
+                                 *weights)[0]
+
+
+def _latent_attention_fwd(sizes, block, span, data, positions, *weights):
+    with jax.named_scope("latent_attention.project"):
+        q, k, v = _latent_project(sizes, data, positions, *weights)
+    with jax.named_scope("latent_attention.attend"):
+        o = _causal_forward(q, k, v, block, span)
+    return o, (data, positions, weights, o)
+
+
+def _latent_attention_bwd(sizes, block, span, res, do):
+    data, positions, weights, o = res
+    with jax.named_scope("latent_attention.project"):
+        (q, k, v), project_vjp = jax.vjp(
+            lambda data, *w: _latent_project(sizes, data, positions, *w),
+            data, *weights)
+    with jax.named_scope("latent_attention.attend"):
+        cts = _causal_backward(q, k, v, o, do, block, span)
+    with jax.named_scope("latent_attention.project"):
+        d_data, *d_weights = project_vjp(cts)
+    return (d_data, None) + tuple(d_weights)
+
+
+_latent_attention.defvjp(_latent_attention_fwd, _latent_attention_bwd)
+
+
+@register("LatentAttention")
+def latent_attention(data, positions, q_weight, kv_a_weight, kv_norm_gamma,
+                     kv_b_weight, *, num_heads, qk_nope_dim, qk_rope_dim,
+                     v_dim, theta=10000.0, latent_eps=1e-6, block=256,
+                     span=2048):
+    """Multi-head latent attention (DeepSeek-V2), training form, without a
+    query latent.
+
+    ``data`` (N, S, D) the normed input of N documents (or (S, D), one);
+    ``positions`` (S,).  ``q = data Wq`` gives ``num_heads`` heads of
+    ``qk_nope_dim + qk_rope_dim``; ``[c | kr] = data Wkv_a`` a latent ``c``
+    (``Wkv_b``'s input width) and ONE rotary key ``kr`` of ``qk_rope_dim``
+    for all heads; ``[k_nope | v] = RMSNorm(c; kv_norm_gamma, latent_eps)
+    Wkv_b`` gives each head ``qk_nope_dim + v_dim``.  Rotary embedding
+    (half-rotation form) on each head's last ``qk_rope_dim`` query dims and
+    on ``kr``; a head's key is ``[k_nope | rope(kr)]``.  Dense causal
+    attention (``CausalAttention``: scale ``(qk_nope_dim + qk_rope_dim)^-1/2``)
+    -> (N, S, num_heads x v_dim), before the output projection.
+
+    The forward pass keeps its input and its output: the backward pass
+    rebuilds queries, keys and values from the input (the latent's point: it
+    is what is cheap to hold) and recomputes each block's weights."""
+    sizes = (num_heads, qk_nope_dim, qk_rope_dim, v_dim, theta, latent_eps)
+    one = data.ndim == 2
+    o = _latent_attention(sizes, block, span, data[None] if one else data,
+                          positions, q_weight, kv_a_weight, kv_norm_gamma,
+                          kv_b_weight)
+    o = o.reshape(o.shape[:2] + (num_heads * v_dim,))
+    return o[0] if one else o
+
+
+@register("LMHeadLogProb")
+def lm_head_log_prob(data, weight, labels, *, block=2048):
+    """Log-probability of each position's label under the head's softmax:
+    ``log_softmax(data W^T)[label]`` in float32, 0 where the label is
+    negative (no label).  ``data`` (.., D), ``weight`` (V, D), ``labels`` (..)
+    integer.  -> (..) float32.  The rows are walked ``block`` at a time and
+    each block's logits recomputed in the backward pass: no (rows, V) array
+    outlives a block (at 16 384 rows of 20 480 the float32 logits and their
+    gradient are 1.3 GB each)."""
+    D = data.shape[-1]
+    flat, lab = data.reshape(-1, D), labels.reshape(-1)
+    T = flat.shape[0]
+    block = min(block, T)
+    if T % block:
+        raise ValueError("%d rows do not divide into blocks of %d"
+                         % (T, block))
+
+    @jax.checkpoint
+    def rows(blk):
+        x, y = blk
+        logits = jnp.einsum("td,vd->tv", x, weight,
+                            preferred_element_type=jnp.float32)
+        picked = jnp.take_along_axis(logits, jnp.maximum(y, 0)[:, None], 1)
+        return jnp.where(y >= 0, picked[:, 0]
+                         - jax.nn.logsumexp(logits, axis=-1), 0.0)
+
+    out = lax.map(rows, (flat.reshape(-1, block, D), lab.reshape(-1, block)))
+    return out.reshape(labels.shape)
+
+
 @register("MoEExperts")
-def moe_experts(data, router_weight, gate_weight, up_weight, down_weight, *,
-                top_k, first_expert=0, norm_topk_prob=True, capacity=None):
+def moe_experts(data, router_weight, gate_weight, up_weight, down_weight,
+                router_bias=None, shared_gate_weight=None,
+                shared_up_weight=None, shared_down_weight=None, *, top_k,
+                first_expert=0, norm_topk_prob=True, capacity=None,
+                scoring="softmax", routed_scale=1.0, sequences=0):
     """The held experts' part of a top-k mixture-of-experts layer
     (``parallel.moe.moe_layer``).  ``data`` (T, D), ``router_weight`` (E, D)
     over all E experts, ``gate_weight`` / ``up_weight`` (H, D, F) and
     ``down_weight`` (H, F, D) the H experts held, from ``first_expert``.
-    -> out (T, D), balance (), pairs (H,), dropped (), choice (T, top_k)."""
+    ``scoring`` ``"sigmoid"``, ``router_bias`` (E,) (chooses, never gates)
+    and ``routed_scale`` are the router's other form (``parallel.moe.route``);
+    ``shared_*_weight`` ((Fs, D), (Fs, D), (D, Fs)) a gated feed-forward every
+    token passes, added to the result; ``sequences`` > 0: the tokens are that
+    many documents of equal length and the balance term is sequence-wise.
+    -> out (T, D), balance (), pairs (H,), dropped (), choice (T, top_k) and,
+    given ``router_bias``, router_pairs (E,) int32: the pairs these tokens
+    sent to every expert of the layer, which the bias update reads, and gates
+    (T, top_k) float32: the chosen experts' gates (that no bias entered them
+    is checked on these)."""
     from ..parallel.moe import moe_layer
 
+    shared = None
+    if shared_gate_weight is not None:
+        shared = (shared_gate_weight, shared_up_weight, shared_down_weight)
     y, aux = moe_layer(data, router_weight, gate_weight, up_weight,
                        down_weight, top_k=top_k, first_expert=first_expert,
-                       normalize=norm_topk_prob, capacity=capacity)
-    return y, aux["balance"], aux["pairs"], aux["dropped"], aux["choice"]
+                       normalize=norm_topk_prob, capacity=capacity,
+                       scoring=scoring, router_bias=router_bias,
+                       routed_scale=routed_scale, shared=shared,
+                       sequences=sequences or None)
+    out = (y, aux["balance"], aux["pairs"], aux["dropped"], aux["choice"])
+    if router_bias is not None:
+        out += (aux["router_pairs"].astype(jnp.int32),
+                lax.stop_gradient(aux["gates"]))
+    return out

@@ -7,8 +7,10 @@ The router always has its published width (every expert of the layer); a
 chip holds a contiguous share of the experts and computes its own experts'
 part of the result:
 
-* :func:`route` — softmax over all experts in float32, top-k, gates
-  (normalised over the k chosen experts, held here or not);
+* :func:`route` — scores over all experts in float32 (a softmax, or
+  independent sigmoids with a selection bias that chooses and never gates,
+  DeepSeek-V3's form), top-k, gates (normalised over the k chosen experts,
+  held here or not, and scaled);
 * :func:`held_experts_ffn` — the (token, expert) pairs routed to the experts
   held here, sorted by expert, through grouped matrix products
   (``jax.lax.ragged_dot``: on a TPU a grouped Mosaic product, one pass over
@@ -16,8 +18,10 @@ part of the result:
   is dropped: the buffer holds ``capacity`` pairs (default: every pair, the
   worst case) and the layer counts what did not fit, so a caller that sizes
   it tighter can fail the step on overflow;
-* :func:`moe_layer` — the two together, with the load-balance term.  On one
-  chip it runs without an exchange, and nothing stands in for absent chips;
+* :func:`moe_layer` — the two together, with the load-balance term (over the
+  batch, or sequence-wise over normalised scores) and, where the layer has
+  one, the shared expert every token passes.  On one chip it runs without an
+  exchange, and nothing stands in for absent chips;
 * :func:`moe_ffn` — the same routing over an ``ep`` mesh axis, one expert
   per device, with the two ``all_to_all`` collectives that move fixed-size
   token buffers to their experts and back (GShard dispatch/combine einsums;
@@ -34,22 +38,42 @@ __all__ = ["route", "held_experts_ffn", "moe_layer", "moe_ffn",
 from .pipeline import stack_stage_params as stack_expert_params  # same op
 
 
-def route(x, router_w, top_k, normalize=True):
+def route(x, router_w, top_k, normalize=True, scoring="softmax", bias=None,
+          scale=1.0):
     """Router of a MoE layer.  ``x`` (T, D), ``router_w`` (E, D).
-    -> probs (T, E) float32 softmax over all E experts, choice (T, k) int32,
-    gates (T, k) float32: the chosen experts' probabilities, divided by
-    their sum when ``normalize``.  The product runs in float32 at the
-    highest precision: a top-k choice flips on the last bits."""
+    -> scores (T, E) float32 over all E experts, choice (T, k) int32, gates
+    (T, k) float32.  ``scoring="softmax"`` (the default): the scores are a
+    softmax over the experts, the choice their top-k, the gates the chosen
+    scores, divided by their sum when ``normalize``.  ``"sigmoid"``: each
+    expert's score is its own sigmoid.  ``bias`` (E,) is added to the scores
+    for the choice alone: the gates are the unbiased scores of the chosen
+    (normalised over the chosen plus 1e-20 when ``normalize``), times
+    ``scale``.  The product runs in float32 at the highest precision: a
+    top-k choice flips on the last bits."""
     import jax
     import jax.numpy as jnp
 
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError("scoring %r: 'softmax' or 'sigmoid'" % (scoring,))
     logits = jnp.einsum("td,ed->te", x.astype(jnp.float32),
                         router_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, choice = jax.lax.top_k(probs, top_k)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        probs = jax.nn.sigmoid(logits)
+    if bias is None:
+        gates, choice = jax.lax.top_k(probs, top_k)
+    else:
+        _, choice = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        gates = jnp.take_along_axis(probs, choice, axis=1)
     if normalize:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        # sigmoids can all underflow; the softmax form stays bit for bit
+        gates = gates / (total if scoring == "softmax" else total + 1e-20)
+    if scale != 1.0:
+        gates = gates * scale
     return probs, choice.astype(jnp.int32), gates
 
 
@@ -149,26 +173,55 @@ def held_experts_ffn(x, choice, gates, gate_w, up_w, down_w, first_expert=0,
 
 
 def moe_layer(x, router_w, gate_w, up_w, down_w, *, top_k, first_expert=0,
-              normalize=True, capacity=None):
-    """Router + held experts.  -> (y, aux) with ``aux``: ``balance`` (the
-    load-balance term ``E * sum_e frac_e * mean_t probs[t, e]``, ``frac_e``
-    the pairs routed to expert e over T: Switch / Qwen3-MoE's form, all E
-    experts), ``pairs`` (H,), ``dropped`` (), ``choice`` (T, k)."""
+              normalize=True, capacity=None, scoring="softmax",
+              router_bias=None, routed_scale=1.0, shared=None,
+              sequences=None):
+    """Router + held experts (+ the shared expert).  ``scoring``,
+    ``router_bias`` and ``routed_scale`` are :func:`route`'s.  ``shared``:
+    (gate (Fs, D), up (Fs, D), down (D, Fs)) of a gated feed-forward every
+    token passes, added to the held experts' part (each chip of the group
+    computes it alike).  -> (y, aux) with ``aux``: ``balance``, ``pairs``
+    (H,), ``dropped`` (), ``choice`` / ``gates`` (T, k), ``router_pairs``
+    (E,) float32: the pairs routed to every expert of the layer.  ``balance`` is ``E *
+    sum_e frac_e * mean_t scores[t, e]``, ``frac_e`` the pairs routed to
+    expert e over T (Switch / Qwen3-MoE's form, all E experts); with
+    ``sequences`` = B the tokens are B documents of T / B and it is
+    DeepSeek-V3's sequence-wise term, the mean over the documents of ``sum_e
+    f_e P_e``, ``f_e = E / (k S) x`` the document's pairs to e, ``P_e`` the
+    document's mean of ``scores[t, e] / sum_j scores[t, j]``."""
     import jax
     import jax.numpy as jnp
 
     E = router_w.shape[0]
     with jax.named_scope("moe.route"):
-        probs, choice, gates = route(x, router_w, top_k, normalize)
-        frac = jnp.sum(jax.nn.one_hot(choice.reshape(-1), E,
-                                      dtype=jnp.float32), axis=0) / x.shape[0]
-        balance = E * jnp.sum(jax.lax.stop_gradient(frac)
-                              * jnp.mean(probs, axis=0))
+        probs, choice, gates = route(x, router_w, top_k, normalize, scoring,
+                                     router_bias, routed_scale)
+        if sequences is None:
+            router_pairs = jnp.sum(jax.nn.one_hot(
+                choice.reshape(-1), E, dtype=jnp.float32), axis=0)
+            frac = router_pairs / x.shape[0]
+            balance = E * jnp.sum(jax.lax.stop_gradient(frac)
+                                  * jnp.mean(probs, axis=0))
+        else:
+            per_doc = jnp.sum(jax.nn.one_hot(
+                choice.reshape(sequences, -1), E, dtype=jnp.float32), axis=1)
+            S = x.shape[0] // sequences
+            share = (probs / jnp.sum(probs, axis=-1, keepdims=True)).reshape(
+                sequences, S, E)
+            balance = jnp.mean(jnp.sum(
+                jax.lax.stop_gradient(per_doc * (E / (top_k * S)))
+                * jnp.mean(share, axis=1), axis=-1))
+            router_pairs = jnp.sum(per_doc, axis=0)
     with jax.named_scope("moe.experts"):
         y, pairs, dropped = held_experts_ffn(
             x, choice, gates, gate_w, up_w, down_w, first_expert, capacity)
+    if shared is not None:
+        from ..ops.transformer import gated_ffn
+
+        with jax.named_scope("moe.shared"):
+            y = y + gated_ffn(x, *shared)
     return y, {"balance": balance, "pairs": pairs, "dropped": dropped,
-               "choice": choice}
+               "choice": choice, "router_pairs": router_pairs, "gates": gates}
 
 
 def moe_ffn(x, gate_w, expert_params, expert_fn, *, mesh, axis="ep",
