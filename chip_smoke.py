@@ -13,7 +13,9 @@ It drives the main path once at full width, in this one process:
    state, loss finite and different on every step, and the COMPILED module
    holds the Mosaic custom calls for dconv forward, dconv backward and NMS.
 2. Every Pallas kernel that is default-on for TPU, non-interpreted, against
-   the repo's XLA / jnp formulation of the same operator.
+   the repo's XLA / jnp formulation of the same operator (``sparse_attn_leg``:
+   one layer of the text model's selected-key attention, forward + backward,
+   the kernel pair against the XLA walk, with milliseconds a call).
    Beside them ``psroi_leg``: deformable PS-ROI pooling alone (no kernel:
    XLA's one-hot path) at the step's three shapes, against its gather path,
    with milliseconds a call.
@@ -388,6 +390,69 @@ def dconv_leg(bg=32, channels=128, hw=(38, 64), interpret=False, calls=20):
     return {"bg": bg, "dconv": facts}
 
 
+def sparse_attn_leg(seq=16384, heads=32, kv_heads=4, head_dim=128,
+                    index_heads=16, index_dim=64, topk=2048, block=256,
+                    span=2048, interpret=False, calls=3):
+    """One layer of ``IndexerSparseAttention`` at the text cell's shapes
+    (bf16, per-head normalised queries and keys), forward and forward +
+    backward: the Pallas kernel pair (``ops/pallas_attention.py``) against
+    the XLA walk it replaces on a TPU: milliseconds a call of each, and the
+    largest difference of the output, the KL term and the six gradients over
+    the walk's largest element.  Indexer and selection are in both."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import transformer as tr
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 7)
+    unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+        jnp.mean(x * x, axis=-1, keepdims=True))
+    args = [unit(jax.random.normal(ks[0], (seq, heads, head_dim))),
+            unit(jax.random.normal(ks[1], (seq, kv_heads, head_dim))),
+            jax.random.normal(ks[2], (seq, kv_heads, head_dim)),
+            jax.random.normal(ks[3], (seq, index_heads, index_dim)),
+            jax.random.normal(ks[4], (seq, index_dim)),
+            jax.random.normal(ks[5], (seq, index_heads))]
+    args = [a.astype(jnp.bfloat16) for a in args]
+    cot = jax.random.normal(ks[6], (seq, heads, head_dim))
+
+    def layer(mode):
+        def loss(*a):
+            out = tr._sparse_attention(*a, topk, block, span, False, mode)
+            return (jnp.sum(out[0].astype(jnp.float32) * cot)
+                    + out[1]), out[:3]
+        return (jax.jit(lambda *a: loss(*a)[1]),
+                jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)),
+                                           has_aux=True)))
+
+    facts, values = {"seq": seq}, {}
+    for name, mode in (("kernel", "interpret" if interpret else "auto"),
+                       ("walk", "xla")):
+        forward, both = layer(mode)
+        (_, out), grads = both(*args)
+        values[name] = [np.asarray(v, np.float32)
+                        for v in tuple(out[:2]) + tuple(grads)]
+        facts[name] = {"selected": int(out[2]),
+                       "fwd_ms": per_call_ms(calls, forward, *args),
+                       "fwd_bwd_ms": per_call_ms(calls, both, *args)}
+    errs = {}
+    for n, got, want in zip(("o", "kl", "d_q", "d_k", "d_v", "d_iq", "d_ik",
+                             "d_iw"), values["kernel"], values["walk"]):
+        errs[n] = float("%.3g" % (float(np.abs(got - want).max())
+                                  / max(float(np.abs(want).max()), 1e-6)))
+    facts["rel_err"] = errs
+    # both round the weights to bf16 as MXU operands, the kernel after
+    # normalising them; the keys' gradients are bf16 sums over 64 blocks.
+    # The selection is XLA's in both, but in two programs: a score on its
+    # row's threshold may fall either way (1 of 31 458 308 on the chip)
+    if (max(errs.values()) > 2.0 ** -5
+            or abs(facts["kernel"]["selected"] - facts["walk"]["selected"])
+            > 1e-6 * facts["walk"]["selected"]):
+        raise AssertionError("the attention kernels disagree with the XLA "
+                             "walk: %r" % facts)
+    return {"sparse_attn": facts}
+
+
 # the R-FCN head's three poolings (model_zoo/detection.py ``_head``): name,
 # output_dim, no_trans
 PSROI_POOLINGS = (("offsets", 2, True), ("classes", 81, False),
@@ -531,7 +596,7 @@ def main():
     say("rfcn 1 chip: %s" % json.dumps(single))
     check_step_kernels(single)
 
-    for leg in (quant_leg, nms_leg, dconv_leg, psroi_leg):
+    for leg in (quant_leg, nms_leg, dconv_leg, sparse_attn_leg, psroi_leg):
         say("%s vs XLA/jnp: %s" % (leg.__name__, json.dumps(leg())))
 
     facts = module_fit_leg()

@@ -17,7 +17,12 @@ an exact threshold keeps the ``topk`` best, attention reads only those, and a
 KL term teaches the indexer the attention's own distribution.  It walks the
 queries in blocks (no ``(heads, S, S)`` array exists), skips the key blocks
 above the diagonal by spans, and saves each query's threshold for the
-backward pass, which recomputes scores but never the selection.
+backward pass, which recomputes scores but never the selection.  A block's
+attention over its selected keys runs on one of two paths: lowered for a TPU
+at shapes the kernels take, ``ops/pallas_attention.py``'s pair
+(``sparse_attn_pallas_fwd`` / ``_bwd``: the block's (heads, block, keys)
+weights never leave VMEM); anywhere else (a CPU, toy shapes) the XLA walk
+(``_attend_walk``), which is also the kernels' oracle in the tests.
 
 ``CausalAttention`` is the same walk with every causal key selected and
 nothing to index: blocks of queries against the keys up to the end of their
@@ -169,87 +174,206 @@ def _target(e, z):
     return jnp.mean(e.astype(jnp.float32) / z[..., None], axis=(0, 1))
 
 
-def _block_forward(topk, emit_width, k, v, ik, blk):
-    q, iq, iw, t = blk
+def _attend_walk(q, k, v, sel):
+    """The XLA walk's block: q (B, Hq, d), k / v (Sk, Hkv, d), sel (B, Sk)
+    -> o (B, Hq, d), z (Hkv, g, B) float32, target (B, Sk) float32."""
     B, Hq, d = q.shape
     Hkv = k.shape[1]
+    e, z = _weights(q.reshape(B, Hkv, Hq // Hkv, d), k, sel)
+    o = (jnp.einsum("hgqk,khd->qhgd", e, v,
+                    preferred_element_type=jnp.float32)
+         / z.transpose(2, 0, 1)[..., None]).astype(v.dtype)
+    return o.reshape(B, Hq, d), z, _target(e, z)
+
+
+def _attend_walk_bwd(q, k, v, sel, o, do):
+    """The block's weights recomputed through HBM -> dq, dk, dv, target."""
+    B, Hq, d = q.shape
+    Hkv = k.shape[1]
+    heads = (B, Hkv, Hq // Hkv, d)
+    qg = q.reshape(heads)
+    e, z = _weights(qg, k, sel)
+    zt = z.transpose(2, 0, 1)[..., None]                         # (B, h, g, 1)
+    dog = do.reshape(heads).astype(jnp.float32)
+    # o = (e v) / z:  de = (do . v - do . o) / z,  ds = e de / sqrt(d)
+    dov = (dog / zt).astype(v.dtype)
+    shift = (jnp.sum(dog * o.reshape(heads).astype(jnp.float32), -1,
+                     keepdims=True) / zt).transpose(1, 2, 0, 3)
+    ds = (e.astype(jnp.float32) * d ** -0.5
+          * (jnp.einsum("qhgd,khd->hgqk", dov, v,
+                        preferred_element_type=jnp.float32)
+             - shift)).astype(k.dtype)
+    dv = jnp.einsum("hgqk,qhgd->khd", e, dov)
+    dq = jnp.einsum("hgqk,khd->qhgd", ds, k).reshape(q.shape)
+    dk = jnp.einsum("hgqk,qhgd->khd", ds, qg)
+    return dq, dk, dv, _target(e, z)
+
+
+def _head_rows(x, Hkv):
+    """(B, Hq, d) -> (Hkv, g B, d), the kernels' layout: a key-value head's
+    query heads as one block of rows."""
+    B, Hq, d = x.shape
+    return x.reshape(B, Hkv, Hq // Hkv, d).transpose(1, 2, 0, 3).reshape(
+        Hkv, Hq // Hkv * B, d)
+
+
+def _head_cols(x, B):
+    """:func:`_head_rows` back: (Hkv, g B, d) -> (B, Hq, d)."""
+    Hkv, R, d = x.shape
+    return x.reshape(Hkv, R // B, B, d).transpose(2, 0, 1, 3).reshape(
+        B, Hkv * (R // B), d)
+
+
+def _heads_of(x, d):
+    """The kernels' (keys, Hkv d) view of keys or values -> (keys, Hkv, d)."""
+    return x.reshape(x.shape[0], x.shape[1] // d, d)
+
+
+def _attend_kernel(interpret, tile, walked, q, k, v, kmax, sel, t):
+    """:func:`_attend_walk` through ``sparse_attn_pallas_fwd``: k / v in the
+    kernels' view (Sk, Hkv d), ``kmax`` (Hkv,) the largest key norm a head;
+    in place of z the rows' log-sums (shift included), which is what its
+    backward reads."""
+    from .pallas_attention import sparse_attn_fwd
+
+    B, Hq, d = q.shape
+    Hkv = k.shape[1] // d
+    o, lse, target = sparse_attn_fwd(
+        _head_rows(q, Hkv), k, v, sel.astype(jnp.int8), kmax, t[-1],
+        tile=tile, walked=walked, interpret=interpret)
+    return _head_cols(o, B), lse.reshape(Hkv, Hq // Hkv, B), target
+
+
+def _attend_kernel_bwd(interpret, tile, walked, q, k, v, sel, t, lse, o, do):
+    from .pallas_attention import sparse_attn_bwd
+
+    B, Hq, d = q.shape
+    Hkv = k.shape[1] // d
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+    dq, dk, dv, target = sparse_attn_bwd(
+        _head_rows(q, Hkv), k, v, sel.astype(jnp.int8), lse.reshape(Hkv, -1),
+        delta.reshape(B, Hkv, Hq // Hkv).transpose(1, 2, 0).reshape(Hkv, -1),
+        _head_rows(do, Hkv), t[-1], tile=tile, walked=walked,
+        interpret=interpret)
+    return _head_cols(dq, B), dk, dv, target
+
+
+def _walk_in_kernel_view(q, k, v, kmax, sel, t):
+    d = q.shape[-1]
+    return _attend_walk(q, _heads_of(k, d), _heads_of(v, d), sel)
+
+
+def _walk_bwd_in_kernel_view(q, k, v, sel, t, stat, o, do):
+    d = q.shape[-1]
+    dq, dk, dv, target = _attend_walk_bwd(q, _heads_of(k, d), _heads_of(v, d),
+                                          sel, o, do)
+    return dq, dk.reshape(k.shape), dv.reshape(v.shape), target
+
+
+def _kernel_or_walk(kernel, kernel_fn, walk_fn, *args):
+    """``kernel = (mode, tile, walked)``: the kernel pair interpreted where
+    ``mode`` says so, else whatever the step is lowered for decides: Mosaic
+    on a TPU, the XLA walk anywhere else."""
+    mode, *priced = kernel
+    if mode == "interpret":
+        return kernel_fn(True, *priced, *args)
+    return lax.platform_dependent(
+        *args, tpu=functools.partial(kernel_fn, False, *priced),
+        default=walk_fn)
+
+
+def _block_forward(topk, emit_width, kernel, index_scores, k, v, ik, kmax,
+                   blk):
+    q, iq, iw, t = blk
     with jax.named_scope("sparse_attention.indexer"):
-        scores = _index_scores(iq, ik, iw)
+        scores = index_scores(iq, ik, iw)
     with jax.named_scope("sparse_attention.select"):
         sel, tau, causal = _select(scores, t, topk)
     with jax.named_scope("sparse_attention.attend"):
-        e, z = _weights(q.reshape(B, Hkv, Hq // Hkv, d), k, sel)
-        o = (jnp.einsum("hgqk,khd->qhgd", e, v,
-                        preferred_element_type=jnp.float32)
-             / z.transpose(2, 0, 1)[..., None]).astype(v.dtype)
-        target = _target(e, z)
+        if kernel is None:
+            o, stat, target = _attend_walk(q, k, v, sel)
+        else:
+            o, stat, target = _kernel_or_walk(
+                kernel, _attend_kernel, _walk_in_kernel_view,
+                q, k, v, kmax, sel, t)
     with jax.named_scope("sparse_attention.indexer"):
         logp = jax.nn.log_softmax(jnp.where(sel, scores, -jnp.inf), axis=-1)
         kl = jnp.sum(jnp.where(
             target > 0,
             target * (jnp.log(jnp.where(target > 0, target, 1.0))
                       - jnp.where(sel, logp, 0.0)), 0.0))
-    out = (o.reshape(B, Hq, d), kl, jnp.sum(sel, dtype=jnp.int32),
+    out = (o, kl, jnp.sum(sel, dtype=jnp.int32),
            jnp.sum(causal, dtype=jnp.int32))
     if emit_width:
         out += (_pack_bits(sel, emit_width),)
-    return out, tau
+    return out, (tau, None if kernel is None else stat)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _attend_block(topk, emit_width, k, v, ik, blk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _attend_block(topk, emit_width, kernel, index_scores, k, v, ik, kmax,
+                  blk):
     """One block of queries against the keys ``[0, Sk)``.  ``blk``: q (B,
     Hq, d), iq (B, HI, dI), iw (B, HI), t (B,) the queries' positions.
     -> (o (B, Hq, d), kl, selected, causal[, packed selection]).
 
     The backward pass is written out: the block's thresholds are saved and
     its scores and weights recomputed, so nothing of (heads, B, Sk) outlives
-    the block, and every array of that size is held in the compute type (the
-    float32 scores and their gradient exist only inside the fusions that
-    make them)."""
-    return _block_forward(topk, emit_width, k, v, ik, blk)[0]
+    the block.  ``kernel`` None: the XLA walk, every array of that size held
+    in the compute type (the float32 scores and their gradient exist only
+    inside the fusions that make them).  ``kernel = (mode, tile, walked)``:
+    ``ops/pallas_attention.py``'s pair, in which no such array leaves VMEM;
+    k and v then come in the kernels' view (Sk, Hkv d), relaid once a layer
+    and not once a block, with ``kmax`` (Hkv,) the largest key norm a head
+    (None on the walk, which finds it itself); each row's log-sum is saved
+    besides.  ``mode`` ``"interpret"`` runs the pair interpreted anywhere;
+    ``"auto"`` runs it where the step is lowered for a TPU and the walk
+    everywhere else."""
+    return _block_forward(topk, emit_width, kernel, index_scores, k, v, ik,
+                          kmax, blk)[0]
 
 
-def _attend_block_fwd(topk, emit_width, k, v, ik, blk):
-    out, tau = _block_forward(topk, emit_width, k, v, ik, blk)
-    return out, (k, v, ik, blk, tau, out[0])
+def _attend_block_fwd(topk, emit_width, kernel, index_scores, k, v, ik, kmax,
+                      blk):
+    out, (tau, stat) = _block_forward(topk, emit_width, kernel, index_scores,
+                                      k, v, ik, kmax, blk)
+    return out, (k, v, ik, kmax, blk, tau, stat, out[0])
 
 
-def _attend_block_bwd(topk, emit_width, res, cts):
-    k, v, ik, (q, iq, iw, t), tau, o = res
+def _attend_block_bwd(topk, emit_width, kernel, index_scores, res, cts):
+    k, v, ik, kmax, (q, iq, iw, t), tau, stat, o = res
     do, dkl = cts[0], cts[1]
-    B, Hq, d = q.shape
-    Hkv = k.shape[1]
-    heads = (B, Hkv, Hq // Hkv, d)
     with jax.named_scope("sparse_attention.indexer"):
-        scores, index_vjp = _index_scores(iq, ik, iw, with_vjp=True)
+        scores, index_vjp = index_scores(iq, ik, iw, with_vjp=True)
     with jax.named_scope("sparse_attention.select"):
         sel, _, _ = _select(scores, t, topk, tau)
     with jax.named_scope("sparse_attention.attend"):
-        qg = q.reshape(heads)
-        e, z = _weights(qg, k, sel)
-        zt = z.transpose(2, 0, 1)[..., None]                     # (B, h, g, 1)
-        dog = do.reshape(heads).astype(jnp.float32)
-        # o = (e v) / z:  de = (do . v - do . o) / z,  ds = e de / sqrt(d)
-        dov = (dog / zt).astype(v.dtype)
-        shift = (jnp.sum(dog * o.reshape(heads).astype(jnp.float32), -1,
-                         keepdims=True) / zt).transpose(1, 2, 0, 3)
-        ds = (e.astype(jnp.float32) * d ** -0.5
-              * (jnp.einsum("qhgd,khd->hgqk", dov, v,
-                            preferred_element_type=jnp.float32)
-                 - shift)).astype(k.dtype)
-        dv = jnp.einsum("hgqk,qhgd->khd", e, dov)
-        dq = jnp.einsum("hgqk,khd->qhgd", ds, k).reshape(q.shape)
-        dk = jnp.einsum("hgqk,qhgd->khd", ds, qg)
-        target = _target(e, z)
+        if kernel is None:
+            dq, dk, dv, target = _attend_walk_bwd(q, k, v, sel, o, do)
+        else:
+            dq, dk, dv, target = _kernel_or_walk(
+                kernel, _attend_kernel_bwd, _walk_bwd_in_kernel_view,
+                q, k, v, sel, t, stat, o, do)
     with jax.named_scope("sparse_attention.indexer"):
         # d KL(target || softmax over S_t of I) / dI = softmax - target
         p = jax.nn.softmax(jnp.where(sel, scores, -jnp.inf), axis=-1)
         diq, dik, diw = index_vjp(dkl * jnp.where(sel, p - target, 0.0))
-    return dk, dv, dik, (dq, diq, diw, None)
+    return (dk, dv, dik, None if kmax is None else jnp.zeros_like(kmax),
+            (dq, diq, diw, None))
 
 
 _attend_block.defvjp(_attend_block_fwd, _attend_block_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _attend_span(topk, emit_width, kernel, index_scores, k, v, ik, kmax,
+                 blocks):
+    """A span's blocks, one after the other.  Jitted so that a model's layers
+    share the trace, its linearisation and its lowering of each span's shape:
+    they are paid on every run.  ``index_scores`` (:func:`_index_scores`)
+    rides in the static arguments, so that a cached span is never another
+    scoring function's."""
+    return lax.map(functools.partial(_attend_block, topk, emit_width, kernel,
+                                     index_scores, k, v, ik, kmax), blocks)
 
 
 @register("IndexerSparseAttention")
@@ -280,24 +404,56 @@ def indexer_sparse_attention(query, key, value, index_query, index_key,
     of their ``span`` of queries (tiles: they change no result).  Each
     block's thresholds are saved for the backward pass, which recomputes
     the block's scores but not its selection.
+
+    Which path attends: where the step is lowered for a TPU and the shapes
+    are the kernels' (``pallas_attention.sparse_attn_supported``: head size a
+    multiple of 128, the block whole sublane tiles, the span whole key tiles,
+    float32 or bfloat16), each block runs ``sparse_attn_pallas_fwd`` / ``_bwd``
+    and no (heads, block, keys) array reaches HBM; everywhere else (a CPU,
+    toy shapes) the XLA walk, which is also the tests' oracle.  Indexer and
+    selection are XLA's on both.
     """
-    S = query.shape[0]
+    return _sparse_attention(query, key, value, index_query, index_key,
+                             index_weight, topk, block, span, emit_selection,
+                             "auto")
+
+
+def _sparse_attention(query, key, value, index_query, index_key,
+                      index_weight, topk, block, span, emit_selection, mode):
+    """``mode`` ``"auto"`` (the operator's), ``"interpret"`` (the kernel pair
+    interpreted, for tests on a CPU: shapes it cannot take still walk) or
+    ``"xla"`` (the walk alone)."""
+    from .pallas_attention import (sparse_attn_supported, sparse_attn_tile,
+                                   sparse_attn_walked)
+
+    S, Hq, d = query.shape
     span = min(span, S)
     block = min(block, span)
     if S % span or span % block or (emit_selection and span % 32):
         raise ValueError("sequence %d, span %d and block %d must divide "
                          "(and the span by 32 to emit the selection)"
                          % (S, span, block))
+    takes = mode != "xla" and sparse_attn_supported(
+        block, Hq, key.shape[1], d, span, query.dtype)
+    if takes:
+        # once a layer, not once a block: each head's key norms, and keys
+        # and values in the kernels' view (on the chip that reshape moves
+        # the heads from the sublanes to the lanes of a tiled array: a copy)
+        norms = lax.stop_gradient(jnp.sqrt(jnp.sum(jnp.square(
+            key.astype(jnp.float32)), -1)))                        # (S, Hkv)
+        key, value = (a.reshape(S, -1) for a in (key, value))
     t_all = jnp.arange(S, dtype=jnp.int32)
     outs = []
     for end in range(span, S + 1, span):
         rows = slice(end - span, end)
-        fn = functools.partial(
-            _attend_block, topk, S if emit_selection else 0, key[:end],
-            value[:end], index_key[:end])
-        outs.append(lax.map(fn, tuple(
-            a[rows].reshape((span // block, block) + a.shape[1:])
-            for a in (query, index_query, index_weight, t_all))))
+        kernel = (mode, sparse_attn_tile(span),
+                  sparse_attn_walked(end, span, block)) if takes else None
+        outs.append(_attend_span(
+            topk, S if emit_selection else 0, kernel, _index_scores,
+            key[:end], value[:end], index_key[:end],
+            jnp.max(norms[:end], 0) if takes else None,
+            tuple(a[rows].reshape((span // block, block) + a.shape[1:])
+                  for a in (query, index_query, index_weight, t_all))))
     o, kl, selected, causal = (jnp.concatenate([r[i] for r in outs])
                                for i in range(4))
     res = (o.reshape(query.shape), jnp.sum(kl), jnp.sum(selected),

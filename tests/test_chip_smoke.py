@@ -67,6 +67,20 @@ def test_dconv_leg_interpret():
         "band_share"] <= regimes["uniform"]["band_share"] == 1.0
 
 
+def test_sparse_attn_leg_interpret():
+    # two spans of 256 keys, blocks of 32 queries, one key-value head of 8
+    facts = chip_smoke.sparse_attn_leg(
+        seq=512, heads=8, kv_heads=1, index_heads=4, index_dim=16, topk=96,
+        block=32, span=256, interpret=True, calls=1)["sparse_attn"]
+    assert facts["seq"] == 512
+    assert facts["kernel"]["selected"] == facts["walk"]["selected"] > 0
+    assert set(facts["rel_err"]) == {"o", "kl", "d_q", "d_k", "d_v", "d_iq",
+                                     "d_ik", "d_iw"}
+    assert max(facts["rel_err"].values()) <= 2.0 ** -5
+    for path in ("kernel", "walk"):
+        assert facts[path]["fwd_ms"] > 0 and facts[path]["fwd_bwd_ms"] > 0
+
+
 def test_psroi_leg_toy():
     # 2 x 40 rois, 6 channels a class: the classes pooling is over the
     # one-hot threshold, so the leg's check compares the two paths
