@@ -22,7 +22,7 @@ from ..ndarray.ndarray import NDArray, _wrap
 from ..ndarray import _invoke_raw
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 
-__all__ = ["Block", "HybridBlock", "SymbolBlock", "remat"]
+__all__ = ["Block", "HybridBlock", "SymbolBlock", "remat", "loop"]
 
 # per-capture invocation counts (thread-local, reset by _get_graph): lets
 # a block invoked several times WITHIN one capture (weight sharing —
@@ -594,6 +594,7 @@ class HybridBlock(Block):
 
 class _TracingFlag(threading.local):
     active = False
+    params = ()     # the (name, Parameter) items swapped in by the open trace
 
 
 _TRACING = _TracingFlag()
@@ -609,15 +610,15 @@ def _swap_trace_call(params, param_vals, call, key, train):
     for (_, p), v in zip(params, param_vals):
         swapped.append((p, p._data))
         p._data = NDArray(v)
-    prev_tracing = _TRACING.active
-    _TRACING.active = True
+    prev_tracing, prev_params = _TRACING.active, _TRACING.params
+    _TRACING.active, _TRACING.params = True, params
     try:
         with autograd.pause(train_mode=train), _rnd.key_provider(key):
             out = call()
         post = [p._data._data for _, p in params]
         return out, post
     finally:
-        _TRACING.active = prev_tracing
+        _TRACING.active, _TRACING.params = prev_tracing, prev_params
         for p, old in swapped:
             p._data = old
 
@@ -627,7 +628,10 @@ def remat(fn):
     closed over) -> the same function, recomputed in the backward pass of a
     jitted train step instead of keeping its intermediates
     (``jax.checkpoint``).  Outside a trace, eager or symbolic, it is ``fn``
-    itself: the autograd tape keeps what it records."""
+    itself: the autograd tape keeps what it records.  Composes with
+    :func:`loop` either way round: a rematted function called inside a loop's
+    body is recomputed once per pass of the backward loop, and only its
+    arguments are kept for each pass."""
     import jax
     from jax.core import Tracer
 
@@ -646,6 +650,69 @@ def remat(fn):
         if isinstance(out, tuple):
             return [NDArray(o) for o in out]
         return NDArray(out)
+
+    return wrapped
+
+
+def loop(fn, times):
+    """Apply ``fn`` ``times`` times, each pass starting from what the last
+    one left: ``fn(*carry) -> (carry, outputs)`` over NDArrays (child blocks
+    called inside, their parameters closed over, so every pass runs the SAME
+    weights and a weight's gradient is the sum over the passes); ``carry`` a
+    list whose shapes and types no pass changes, ``outputs`` a list (may be
+    empty) of what each pass hands out.  ``loop(fn, times)(*carry) -> (carry,
+    outputs)``, each output stacked over the passes on a new leading axis.
+
+    Under a jitted train step (a CachedOp, ``gluon.functional``) the passes
+    are ONE ``lax.scan``: the body is traced and compiled once however many
+    passes there are, and the backward pass is a loop too.  What the body
+    keeps for the backward pass it keeps once per pass; wrap the expensive
+    part in :func:`remat` to keep only its inputs.  Outside a trace, eager
+    or symbolic, it is a Python loop with the same results.
+
+    Auxiliary state (a ``grad_req='null'`` parameter the forward pass moves:
+    BatchNorm's running statistics, a router's selection bias) cannot be
+    moved inside the compiled loop: the body raises ``ValueError`` naming
+    the parameter."""
+    import jax
+    from jax.core import Tracer
+
+    def unrolled(*carry):
+        from .. import ndarray as nd_mod, symbol as sym_mod
+
+        passes = []
+        for _ in range(times):
+            carry, outs = fn(*carry)
+            passes.append(outs)
+        F = nd_mod if all(isinstance(a, NDArray) for a in carry) else sym_mod
+        return list(carry), [F.stack(*each, axis=0) for each in zip(*passes)]
+
+    def wrapped(*carry):
+        if not any(isinstance(a, NDArray) and isinstance(a._data, Tracer)
+                   for a in carry):
+            return unrolled(*carry)
+        aux = [(n, p) for n, p in _TRACING.params if p.grad_req == "null"]
+
+        def body(vals, _):
+            before = [p._data._data for _, p in aux]
+            new, outs = fn(*[NDArray(v) for v in vals])
+            moved = []
+            for (n, p), b in zip(aux, before):
+                if p._data._data is not b:
+                    p._data._rebind(b)
+                    moved.append(n)
+            if moved:
+                raise ValueError(
+                    "gluon.block.loop: the body moved auxiliary state (%s); a "
+                    "grad_req='null' parameter cannot be updated inside the "
+                    "compiled loop: update it outside, or run the passes as "
+                    "a Python loop" % ", ".join(moved))
+            return (tuple(c._data for c in new),
+                    tuple(o._data for o in outs))
+
+        vals, outs = jax.lax.scan(body, tuple(a._data for a in carry), None,
+                                  length=times)
+        return [NDArray(v) for v in vals], [NDArray(o) for o in outs]
 
     return wrapped
 

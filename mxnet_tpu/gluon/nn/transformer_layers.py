@@ -1,10 +1,10 @@
 """Transformer layers over ``ops/transformer.py``: RMSNorm, rotary embedding
-with sections, the gated feed-forward, an attention block whose keys an
-indexer selects, a latent (compressed key-value) attention block, and a
-mixture-of-experts block that holds a share of its layer's experts.
-Token-major: activations are ``(S, units)``, one sequence, or ``(N, S,
-units)``, N documents that never see each other (``LatentAttention``,
-``SparseMoE``).
+with sections, the gated feed-forward, a plain causal self-attention block,
+an attention block whose keys an indexer selects, a latent (compressed
+key-value) attention block, and a mixture-of-experts block that holds a share
+of its layer's experts.  Token-major: activations are ``(S, units)``, one
+sequence, or ``(N, S, units)``, N documents that never see each other
+(``SelfAttention``, ``LatentAttention``, ``SparseMoE``).
 """
 from __future__ import annotations
 
@@ -16,8 +16,8 @@ from ... import autograd
 from ..block import HybridBlock, remat
 from .basic_layers import Dense, LayerNorm
 
-__all__ = ["RMSNorm", "RotaryEmbedding", "GatedFFN", "IndexerSparseAttention",
-           "LatentAttention", "SparseMoE"]
+__all__ = ["RMSNorm", "RotaryEmbedding", "GatedFFN", "SelfAttention",
+           "IndexerSparseAttention", "LatentAttention", "SparseMoE"]
 
 
 class RMSNorm(HybridBlock):
@@ -70,6 +70,42 @@ class GatedFFN(HybridBlock):
 def _proj(units, in_units, init, prefix):
     return Dense(units, use_bias=False, flatten=False, in_units=in_units,
                  weight_initializer=init, prefix=prefix)
+
+
+class SelfAttention(HybridBlock):
+    """Plain multi-head causal self-attention: query, key, value and output
+    projections without bias, rotary embedding over the whole head, dense
+    causal attention within each document (``ops.CausalAttention``: blocks of
+    queries, no (heads, S, S) array in either pass).  ``num_kv_heads`` <
+    ``num_heads`` shares each key-value head among a group of query heads.
+    ``forward(a, positions)``: ``a`` (N, S, units) the normed input of N
+    documents (or (S, units)), ``positions`` (S,).  -> the shape of ``a``."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 theta=10000.0, block=256, span=2048, weight_initializer=None,
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._heads = (num_heads, num_kv_heads, head_dim)
+        self._op = {"block": block, "span": span}
+        init = weight_initializer
+        with self.name_scope():
+            self.q = _proj(num_heads * head_dim, units, init, "q_")
+            self.k = _proj(num_kv_heads * head_dim, units, init, "k_")
+            self.v = _proj(num_kv_heads * head_dim, units, init, "v_")
+            self.o = _proj(units, num_heads * head_dim, init, "o_")
+            self.rope = RotaryEmbedding(theta, prefix="rope_")
+
+    def hybrid_forward(self, F, a, positions):
+        nq, nkv, d = self._heads
+        lead = a.shape[:-1]
+        with jax.named_scope("self_attention.project"):
+            q = self.rope(self.q(a).reshape(lead + (nq, d)), positions)
+            k = self.rope(self.k(a).reshape(lead + (nkv, d)), positions)
+            v = self.v(a).reshape(lead + (nkv, d))
+        with jax.named_scope("self_attention.attend"):
+            out = F.CausalAttention(q, k, v, **self._op)
+        with jax.named_scope("self_attention.project"):
+            return self.o(out.reshape(lead + (nq * d,)))
 
 
 class IndexerSparseAttention(HybridBlock):

@@ -598,6 +598,12 @@ def causal_attention(query, key, value, *, block=256, span=2048):
     The softmax is shifted by ``|q| max_s |k_s| d^-1/2`` like
     ``IndexerSparseAttention``'s: the same result while that bound stays
     under about 40.
+
+    Two blocks stand on it: ``nn.SelfAttention`` calls this operator after
+    its projections and rotary embedding (its vjp keeps q, k, v and o), and
+    ``LatentAttention`` runs the same forward and backward walks
+    (:func:`_causal_forward` / :func:`_causal_backward`) inside its own vjp,
+    which keeps only the layer's input and o.
     """
     if query.ndim == 3:
         return _causal_attention(query[None], key[None], value[None],
