@@ -1,32 +1,32 @@
-"""Pallas TPU kernels for the selected-key attention of one block of queries
-(``ops/transformer.py`` ``IndexerSparseAttention``).
+"""Pallas TPU kernels for attention (``ops/transformer.py``): the selected-
+key pair of ``IndexerSparseAttention`` (PR 34), and below it the dense causal
+pair of ``CausalAttention`` / ``LatentAttention`` (PR 36).  Both run where a
+step is lowered for a TPU at shapes their ``*_supported`` takes; the XLA walks
+run everywhere else (a CPU, toy shapes) and are the kernels' oracles.
 
-The XLA walk writes a block's unnormalised weights ``e`` and their gradient
-``ds``, arrays of (heads, block, keys), to HBM and reads them back some nine
+Selected keys.  The walk writes a block's unnormalised weights ``e`` and their
+gradient ``ds``, (heads, block, keys), to HBM and reads them back some nine
 times a layer.  Here they live in VMEM for one (key tile, key-value head):
-``sparse_attn_pallas_fwd`` and ``sparse_attn_pallas_bwd`` walk a block of
-queries over tiles of ``_TILE`` keys, and only ``(heads, rows, head_dim)``-
-and ``(block, keys)``-shaped arrays cross the kernel boundary.
+``sparse_attn_pallas_fwd`` / ``_bwd`` walk a block of queries over tiles of
+``_TILE`` keys; only ``(heads, rows, head_dim)``- and ``(block, keys)``-shaped
+arrays cross.  ``q`` is ``(Hkv, g * B, d)``, row ``i * B + r`` the ``i``-th
+query head of the group at query ``r``: a head group's scores are one ``(g B,
+d) x (d, tile)`` product.  Keys and values are the operator's ``(keys, Hkv
+d)`` view, the selection ``(B, keys)`` int8 (causal mask folded in).  Per-row
+statistics cross lane-broadcast, ``(Hkv, g B, 128)`` float32.  The grid ends
+a block's walk at the tile of its last query (``nlive``, scalar prefetch).
+The forward sweeps the tiles twice: sums of ``e`` and ``e v``, then, every
+row's sum known, ``target``, the heads' mean probabilities.
 
-Layouts.  The queries of one key-value head come as one row block: ``q``
-``(Hkv, g * B, d)``, row ``i * B + r`` the ``i``-th query head of the group
-at query ``r`` of the block, so a head group's scores are one ``(g B, d) x
-(d, tile)`` product.  Keys and values are the operator's own ``(keys, Hkv
-d)`` view, the selection ``(B, keys)`` int8 (causal mask folded in; 1/64
-of one crossing of ``e`` at 32 heads in bfloat16).  Per-row statistics
-cross the boundary lane-broadcast, ``(Hkv, g B, 128)`` float32: a column
-vector costs a whole lane tile in VMEM either way.
-
-The grid ends each block's walk at the tile of its last query (``nlive``,
-scalar prefetch): tiles above it are not fetched and not computed, their
-outputs written as zeros.
-
-The softmax shift is the walk's: ``|q| max_s |k_s| d^-1/2`` a row, an upper
-bound of the row's scores.  The forward makes two sweeps over the key tiles:
-the first sums ``e`` and ``e v``; the second, with every row's sum known,
-recomputes the scores and writes ``target``, the mean over the heads of the
-probabilities.  The backward recomputes them once more from the saved
-log-sum ``lse = shift + log z``.
+Dense causal.  ``causal_attn_pallas_fwd`` / ``_bwd`` take heads-major ``q``
+(N, Hq, S, d), ``k`` (N, Hkv, S, d), ``v`` (N, Hkv, S, dv) and walk a table of
+the live (query block, key tile) pairs of one (document, head): no step above
+the diagonal, the mask formed from positions in VMEM on the diagonal's tiles
+alone.  The forward is one sweep; a row's log-sum ``lse`` (N, Hq, S) float32
+is all the backward needs besides q, k, v, do and ``do . o``.  The backward
+holds a key tile while the query blocks under it pass: ``dk`` / ``dv`` sum in
+float32 in VMEM, as does one head's whole ``dq``; five products a pair.
+Both pairs shift the softmax by the walk's bound ``|q| max_s |k_s| d^-1/2``.
 """
 from __future__ import annotations
 
@@ -400,3 +400,356 @@ def _bwd_call(q, k, v, mask, lse, delta, do, last, *, tile, interpret):
         **_params(interpret),
     )(nlive, q, do, stats, k, v, mask)
     return dq, dk, dv, target
+
+
+# -- dense causal attention (``CausalAttention`` / ``LatentAttention``) --------
+# Query blocks and key tiles of one (document, query head).  The grid's last
+# axis walks a TABLE of the live (block, tile) pairs (scalar prefetch): a tile
+# wholly above a block's last query is no grid step at all.  Tiles change no
+# result; the chip's sweep is in PERF.md section 6 (PR 36).
+_CAUSAL_BQ = 1024
+_CAUSAL_BK = 1024
+
+
+def causal_attn_tiles(S):
+    """(queries a block, keys a tile) for a sequence of ``S``: the tuned
+    sizes, or the largest halves of them that divide ``S``."""
+    fit = lambda top: next(  # noqa: E731
+        (t for t in (top, top // 2, top // 4) if S % t == 0), _LANE)
+    return fit(_CAUSAL_BQ), fit(_CAUSAL_BK)
+
+
+def _lanes(d):
+    """``d`` rounded up to whole lanes."""
+    return -(-d // _LANE) * _LANE
+
+
+def _pad_lanes(x):
+    """The last axis zero-padded to whole lanes: a 192-wide contraction costs
+    the MXU 256 either way, and the kernels run a tenth faster on operands
+    that come padded than on ones Mosaic has to mask (PERF.md section 6,
+    PR 36)."""
+    d = x.shape[-1]
+    return x if d % _LANE == 0 else jnp.pad(
+        x, [(0, 0)] * (x.ndim - 1) + [(0, _lanes(d) - d)])
+
+
+def causal_attn_vmem_bytes(S, d_qk, d_v, itemsize, tiles=None):
+    """Estimated VMEM working set of the BACKWARD kernel, the larger: one
+    (document, head)'s whole float32 ``dq`` and its two output buffers, the
+    q / do / k / v / dk / dv blocks twice, the ``dk`` / ``dv`` accumulators
+    and six (tile, block) float32 planes; lanes padded to 128."""
+    bq, bk = tiles or causal_attn_tiles(S)
+    dq, dv = _lanes(d_qk), _lanes(d_v)
+    return (S * dq * (4 + 2 * itemsize)
+            + 2 * itemsize * (bq * (dq + dv) + 2 * bk * (dq + dv))
+            + 4 * bk * (dq + dv) + 6 * 4 * bq * bk + 2 * 8 * bq * 4)
+
+
+def causal_attn_why_not(S, Hq, Hkv, d_qk, d_v, dtype):
+    """Why the kernel pair does not take these shapes (they walk), or None:
+    it takes float32 and bfloat16, values whose lanes are full, scores a
+    multiple of 64 wide, query heads in whole groups a key head, sequences
+    of whole 128-key tiles, and a working set within ``_VMEM_LIMIT``."""
+    dtype = jnp.dtype(dtype)
+    if dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
+        return "compute type %s is neither float32 nor bfloat16" % dtype
+    if d_v % _LANE:
+        return "value heads of %d are not whole lanes of %d" % (d_v, _LANE)
+    if d_qk % 64:
+        return "score heads of %d are not a multiple of 64" % d_qk
+    if Hq % Hkv:
+        return "%d query heads do not group over %d key heads" % (Hq, Hkv)
+    if S % _LANE:
+        return "a sequence of %d is not whole tiles of %d keys" % (S, _LANE)
+    need = causal_attn_vmem_bytes(S, d_qk, d_v, dtype.itemsize)
+    if need > _VMEM_LIMIT:
+        return ("a working set of %d MB is over the %d MB the kernels ask "
+                "for" % (need >> 20, _VMEM_LIMIT >> 20))
+    return None
+
+
+def causal_attn_supported(S, Hq, Hkv, d_qk, d_v, dtype):
+    return causal_attn_why_not(S, Hq, Hkv, d_qk, d_v, dtype) is None
+
+
+def _causal_schedule(S, bq, bk, keys_outer):
+    """The live (query block, key tile) pairs in the order a kernel walks
+    them -> (blocks, tiles) int32 tables.  Live: the tile's first key is at
+    or before the block's last query."""
+    import numpy as np
+
+    pairs = [(i, j) for i in range(S // bq) for j in range(S // bk)
+             if j * bk <= i * bq + bq - 1]
+    if keys_outer:
+        pairs.sort(key=lambda p: (p[1], p[0]))
+    qi, kj = np.asarray(pairs, np.int32).T
+    return qi, kj
+
+
+def causal_attn_walked(S, tiles=None):
+    """(query, key) pairs one head of one document walks: whole live tiles."""
+    bq, bk = tiles or causal_attn_tiles(S)
+    return len(_causal_schedule(S, bq, bk, False)[0]) * bq * bk
+
+
+@register_cost("causal_attn_pallas_fwd")
+def cost_causal_attn_fwd(pairs, n, hq, hkv, s, d_qk, d_v, itemsize=2):
+    """Two products a walked (query, key) pair of each of ``n hq`` heads:
+    scores and values; q, k, v and o once, the rows' log-sums."""
+    return {"flops": 2 * n * hq * pairs * (d_qk + d_v),
+            "bytes_accessed": (n * s * itemsize * (hq * (d_qk + d_v)
+                                                   + hkv * (d_qk + d_v))
+                               + n * hq * s * 4)}
+
+
+@register_cost("causal_attn_pallas_bwd")
+def cost_causal_attn_bwd(pairs, n, hq, hkv, s, d_qk, d_v, itemsize=2):
+    """Five products a pair: scores, ``do v``, ``dv``, ``dk``, ``dq``; q, k,
+    v, do and the three gradients once, the rows' log-sums and ``do . o``."""
+    return {"flops": 2 * n * hq * pairs * (3 * d_qk + 2 * d_v),
+            "bytes_accessed": (n * s * itemsize * (hq * (2 * d_qk + d_v)
+                                                   + 2 * hkv * (d_qk + d_v))
+                               + 2 * n * hq * s * 4)}
+
+
+def _causal_mask(x, i, j, bq, bk, keys_first):
+    """Zero where the key's position is after the query's; ``x`` (bq, bk), or
+    (bk, bq) with ``keys_first``.  Positions from the grid, formed in VMEM."""
+    qd, kd = (1, 0) if keys_first else (0, 1)
+    queries = i * bq + lax.broadcasted_iota(jnp.int32, x.shape, qd)
+    keys = j * bk + lax.broadcasted_iota(jnp.int32, x.shape, kd)
+    return jnp.where(keys <= queries, x, 0.0)
+
+
+def _on_diagonal(step, i, j, bq, bk):
+    """Run ``step(masked)``: masked only where the tile's last key is after
+    the block's first query; the tiles below the diagonal form no mask."""
+    from jax.experimental import pallas as pl
+
+    diagonal = (j + 1) * bk - 1 > i * bq
+    pl.when(diagonal)(lambda: step(True))
+    pl.when(jnp.logical_not(diagonal))(lambda: step(False))
+
+
+def _causal_fwd_kernel(scale, bq, bk):
+    from jax.experimental import pallas as pl
+
+    def kern(qi_ref, kj_ref, q_ref, kmax_ref, k_ref, v_ref, o_ref, lse_ref,
+             acc_ref, z_ref, c_ref):
+        t = pl.program_id(2)
+        i, j = qi_ref[t], kj_ref[t]
+
+        @pl.when(j == 0)
+        def _():
+            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+            z_ref[...] = jnp.zeros(z_ref.shape, jnp.float32)
+            # the row's shift, scaled: |q| max|k| / sqrt(d), on every lane
+            qf = q_ref[0, 0].astype(jnp.float32)
+            c_ref[...] = jnp.broadcast_to(
+                jnp.sqrt(jnp.sum(qf * qf, axis=-1, keepdims=True))
+                * (kmax_ref[0, 0][:1, :1] * scale), c_ref.shape)
+
+        def step(masked):
+            s = _dot(q_ref[0, 0], k_ref[0, 0], ((1,), (1,)))    # (bq, bk)
+            e = jnp.exp(s * scale - c_ref[:, :1])
+            if masked:
+                e = _causal_mask(e, i, j, bq, bk, False)
+            z_ref[...] += _lane_chunks(e)
+            acc_ref[...] += _dot(e.astype(v_ref.dtype), v_ref[0, 0],
+                                 ((1,), (0,)))
+
+        _on_diagonal(step, i, j, bq, bk)
+
+        @pl.when(j == (i * bq + bq - 1) // bk)
+        def _():
+            z = jnp.sum(z_ref[...], axis=-1, keepdims=True)     # (bq, 1)
+            o_ref[0, 0] = (acc_ref[...] / z).astype(o_ref.dtype)
+            # a row's log-sum leaves as a ROW: the backward's planes have
+            # the queries on the lanes
+            lse_ref[0, 0] = (c_ref[...] + jnp.log(z)).T[:1]
+
+    return kern
+
+
+def _causal_bwd_kernel(scale, bq, bk):
+    from jax.experimental import pallas as pl
+
+    def kern(qi_ref, kj_ref, q_ref, do_ref, st_ref, k_ref, v_ref,
+             dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+        t = pl.program_id(2)
+        i, j = qi_ref[t], kj_ref[t]
+        cdt = q_ref.dtype
+
+        @pl.when(t == 0)
+        def _():
+            dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+        @pl.when(i == j * bk // bq)
+        def _():
+            dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+            dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+        def step(masked):
+            q, do, k, v = q_ref[0, 0], do_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
+            st = st_ref[0, 0]                       # rows: lse, do . o
+            # the planes are (keys, queries): a query's statistics are a row
+            # that broadcasts over the sublanes, and only dq's product has a
+            # transposed operand
+            s = _dot(k, q, ((1,), (1,)))                        # (bk, bq)
+            p = jnp.exp(s * scale - st[0:1])
+            if masked:
+                p = _causal_mask(p, i, j, bq, bk, True)
+            dv_acc[...] += _dot(p.astype(cdt), do, ((1,), (0,)))
+            # o = p v:  dp = do . v,  ds = p (dp - do . o) / sqrt(d)
+            dp = _dot(v, do, ((1,), (1,)))
+            ds = (p * (dp - st[1:2]) * scale).astype(cdt)
+            dk_acc[...] += _dot(ds, q, ((1,), (0,)))
+            rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
+            dq_acc[rows, :] += _dot(ds, k, ((0,), (0,)))
+
+        _on_diagonal(step, i, j, bq, bk)
+
+        @pl.when(i == dq_acc.shape[0] // bq - 1)
+        def _():
+            dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+        @pl.when(t == pl.num_programs(2) - 1)
+        def _():
+            dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+
+    return kern
+
+
+def _causal_shapes(q, k, v, tiles):
+    N, Hq, S, d = q.shape
+    Hkv, dv = k.shape[1], v.shape[3]
+    bq, bk = tiles
+    if (k.shape != (N, Hkv, S, d) or v.shape != (N, Hkv, S, dv) or Hq % Hkv
+            or S % bq or S % bk or bq % _LANE or bk % _LANE):
+        raise ValueError("q %r, k %r, v %r and tiles %r do not fit together"
+                         % (q.shape, k.shape, v.shape, tiles))
+    return N, Hq, Hkv, S, d, dv
+
+
+def _head_block(rows, width, g=1, of_keys=False):
+    """A (rows, width) block of a heads-major (N, H, S, width) array: the
+    step's query block, or with ``of_keys`` its key tile, of head ``h // g``
+    (``g`` > 1: the key head a query head's group shares)."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec(
+        (1, 1, rows, width),
+        lambda n, h, t, qi, kj: (n, h // g, (kj if of_keys else qi)[t], 0))
+
+
+def _causal_params(interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret:
+        return {"interpret": True}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_VMEM_LIMIT,
+        dimension_semantics=("parallel", "parallel", "arbitrary"))}
+
+
+# jitted like the selected-key pair's calls: layers of one shape share one
+# trace of the kernel (and one record of its cost) and one Mosaic lowering
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+def causal_attn_fwd(q, k, v, kmax, *, tiles=None, interpret=False):
+    """Dense causal attention, heads major: ``q`` (N, Hq, S, d), ``k`` (N,
+    Hkv, S, d), ``v`` (N, Hkv, S, dv), ``kmax`` (N, Hkv) float32 ``max_s
+    |k_s|`` of each document's key head.  Query t reads its own document's
+    keys ``s <= t`` of key head ``h // (Hq / Hkv)``.
+    -> o (N, Hq, S, dv) in q's type; lse (N, Hq, S) float32, each row's
+    shift plus the log of its weights' sum."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tiles = tiles or causal_attn_tiles(q.shape[2])
+    N, Hq, Hkv, S, d, dv = _causal_shapes(q, k, v, tiles)
+    _record_cost("causal_attn_pallas_fwd", cost_causal_attn_fwd(
+        causal_attn_walked(S, tiles), N, Hq, Hkv, S, d, dv,
+        q.dtype.itemsize), q.shape)
+    g, (bq, bk) = Hq // Hkv, tiles
+    scale = d ** -0.5
+    q, k, d = _pad_lanes(q), _pad_lanes(k), _lanes(d)
+    qi, kj = _causal_schedule(S, bq, bk, False)
+    kmax_b = jnp.broadcast_to(kmax.astype(jnp.float32)[:, :, None, None],
+                              (N, Hkv, 8, _LANE))
+    o, lse = pl.pallas_call(
+        _causal_fwd_kernel(scale, bq, bk),
+        out_shape=(jax.ShapeDtypeStruct((N, Hq, S, dv), q.dtype),
+                   jax.ShapeDtypeStruct((N, Hq, 1, S), jnp.float32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(N, Hq, len(qi)),
+            in_specs=[_head_block(bq, d),
+                      pl.BlockSpec((1, 1, 8, _LANE),
+                                   lambda n, h, t, qi, kj: (n, h // g, 0, 0)),
+                      _head_block(bk, d, g, True),
+                      _head_block(bk, dv, g, True)],
+            out_specs=(_head_block(bq, dv),
+                       pl.BlockSpec((1, 1, 1, bq),
+                                    lambda n, h, t, qi, kj: (n, h, 0, qi[t]))),
+            scratch_shapes=[pltpu.VMEM((bq, dv), jnp.float32),
+                            pltpu.VMEM((bq, _LANE), jnp.float32),
+                            pltpu.VMEM((bq, _LANE), jnp.float32)]),
+        name="causal_attn_pallas_fwd",
+        **_causal_params(interpret),
+    )(jnp.asarray(qi), jnp.asarray(kj), q, kmax_b, k, v)
+    return o, lse[:, :, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+def causal_attn_bwd(q, k, v, lse, delta, do, *, tiles=None, interpret=False):
+    """The gradients, every block's probabilities recomputed from ``lse``
+    (:func:`causal_attn_fwd`'s).  ``do`` (N, Hq, S, dv) the output's
+    cotangent, ``delta`` (N, Hq, S) float32 ``sum_d do o``.  A key tile is
+    resident while the query blocks under it pass: ``dk`` / ``dv`` are summed
+    in float32 in VMEM and rounded once; ``dq`` of one (document, head) is
+    whole in VMEM, float32, until its last tile.
+    -> dq (N, Hq, S, d), dk (N, Hkv, S, d), dv (N, Hkv, S, dv) in q's type."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tiles = tiles or causal_attn_tiles(q.shape[2])
+    N, Hq, Hkv, S, d, dv = _causal_shapes(q, k, v, tiles)
+    _record_cost("causal_attn_pallas_bwd", cost_causal_attn_bwd(
+        causal_attn_walked(S, tiles), N, Hq, Hkv, S, d, dv,
+        q.dtype.itemsize), q.shape)
+    do = do.astype(q.dtype)
+    g, (bq, bk) = Hq // Hkv, tiles
+    d_qk, scale = d, d ** -0.5
+    q, k, d = _pad_lanes(q), _pad_lanes(k), _lanes(d)
+    qi, kj = _causal_schedule(S, bq, bk, True)
+    stats = jnp.stack([lse, delta], axis=2)                  # (N, Hq, 2, S)
+    # a key head's gradients gather its group's query heads: each head
+    # writes its own float32 share, summed and rounded once outside
+    kv_type = q.dtype if g == 1 else jnp.float32
+    dq, dk, dv_ = pl.pallas_call(
+        _causal_bwd_kernel(scale, bq, bk),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((N, Hq, S, d), kv_type),
+                   jax.ShapeDtypeStruct((N, Hq, S, dv), kv_type)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(N, Hq, len(qi)),
+            in_specs=[_head_block(bq, d), _head_block(bq, dv),
+                      pl.BlockSpec((1, 1, 2, bq),
+                                   lambda n, h, t, qi, kj: (n, h, 0, qi[t])),
+                      _head_block(bk, d, g, True),
+                      _head_block(bk, dv, g, True)],
+            out_specs=(pl.BlockSpec((1, 1, S, d),
+                                    lambda n, h, t, qi, kj: (n, h, 0, 0)),
+                       _head_block(bk, d, of_keys=True),
+                       _head_block(bk, dv, of_keys=True)),
+            scratch_shapes=[pltpu.VMEM((S, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, dv), jnp.float32)]),
+        name="causal_attn_pallas_bwd",
+        **_causal_params(interpret),
+    )(jnp.asarray(qi), jnp.asarray(kj), q, do, stats, k, v)
+    if g > 1:
+        dk, dv_ = (x.reshape((N, Hkv, g) + x.shape[2:]).sum(2).astype(q.dtype)
+                   for x in (dk, dv_))
+    return dq[..., :d_qk], dk[..., :d_qk], dv_

@@ -24,12 +24,17 @@ at shapes the kernels take, ``ops/pallas_attention.py``'s pair
 weights never leave VMEM); anywhere else (a CPU, toy shapes) the XLA walk
 (``_attend_walk``), which is also the kernels' oracle in the tests.
 
-``CausalAttention`` is the same walk with every causal key selected and
-nothing to index: blocks of queries against the keys up to the end of their
-span, the block's weights recomputed in the backward pass.
+``CausalAttention`` is dense causal attention on one of two paths.  Lowered
+for a TPU at shapes ``pallas_attention.causal_attn_supported`` takes, the
+pair ``causal_attn_pallas_fwd`` / ``_bwd``: blocks of queries against tiles
+of keys of one (document, head), the block's weights, their gradient and
+the float32 ``dk`` / ``dv`` / ``dq`` sums all in VMEM; only q, k, v, o, do,
+the three gradients and each row's log-sum cross.  Anywhere else (a CPU, toy
+shapes) the same walk as above with every causal key selected and nothing to
+index (``_causal_forward`` / ``_causal_backward``), the pair's oracle.
 ``LatentAttention`` (DeepSeek-V2's multi-head latent attention, training
 form) rebuilds every head's keys and values from one narrow normed latent a
-token plus one rotary key all heads share, and hands them to it.
+token plus one rotary key all heads share, and hands them to either path.
 """
 from __future__ import annotations
 
@@ -271,7 +276,8 @@ def _walk_bwd_in_kernel_view(q, k, v, sel, t, stat, o, do):
 
 
 def _kernel_or_walk(kernel, kernel_fn, walk_fn, *args):
-    """``kernel = (mode, tile, walked)``: the kernel pair interpreted where
+    """``kernel = (mode, *what the kernels are priced by)``, the selected-key
+    pair's ``(mode, tile, walked)``: the kernel pair interpreted where
     ``mode`` says so, else whatever the step is lowered for decides: Mosaic
     on a TPU, the XLA walk anywhere else."""
     mode, *priced = kernel
@@ -567,16 +573,95 @@ def _causal_backward(q, k, v, o, do, block, span):
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-_causal_attention = jax.custom_vjp(_causal_forward, nondiff_argnums=(3, 4))
+def _heads_major(x):
+    """(N, S, H, d) <-> (N, H, S, d), the causal kernels' layout."""
+    return x.transpose(0, 2, 1, 3)
 
 
-def _causal_attention_fwd(q, k, v, block, span):
-    o = _causal_forward(q, k, v, block, span)
-    return o, (q, k, v, o)
+def _causal_kernel(interpret, q, k, v):
+    """:func:`_causal_forward` through ``causal_attn_pallas_fwd`` -> (o, lse
+    (N, Hq, S) float32, the rows' log-sums with their shift, which is what
+    its backward reads)."""
+    from .pallas_attention import causal_attn_fwd
+
+    kmax = jnp.max(jnp.sqrt(jnp.sum(jnp.square(k.astype(jnp.float32)), -1)),
+                   axis=1)                                       # (N, Hkv)
+    o, lse = causal_attn_fwd(_heads_major(q), _heads_major(k),
+                             _heads_major(v), kmax, interpret=interpret)
+    return _heads_major(o), lse
 
 
-def _causal_attention_bwd(block, span, res, do):
-    return _causal_backward(*res, do, block, span)
+def _causal_kernel_bwd(interpret, q, k, v, o, lse, do):
+    from .pallas_attention import causal_attn_bwd
+
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+    return tuple(_heads_major(g) for g in causal_attn_bwd(
+        _heads_major(q), _heads_major(k), _heads_major(v), lse,
+        delta.transpose(0, 2, 1), _heads_major(do), interpret=interpret))
+
+
+def _causal_takes(q, k, v, mode):
+    from .pallas_attention import causal_attn_supported
+
+    return mode != "xla" and causal_attn_supported(
+        q.shape[1], q.shape[2], k.shape[2], q.shape[3], v.shape[3], q.dtype)
+
+
+def _causal_attend(q, k, v, block, span, mode):
+    """Dense causal attention on one of two paths -> (o, lse).  At shapes
+    ``pallas_attention.causal_attn_supported`` takes: the kernel pair where
+    the step is lowered for a TPU (``mode`` ``"auto"``, the operators') or
+    interpreted anywhere (``"interpret"``, the CPU tests'), with each row's
+    log-sum for the backward.  Everywhere else, and under ``"xla"``, the walk
+    (:func:`_causal_forward`), whose backward recomputes its own sums: its
+    ``lse`` is a placeholder of the kernels' shape, or None."""
+    if not _causal_takes(q, k, v, mode):
+        return _causal_forward(q, k, v, block, span), None
+    return _causal_either(_causal_forward, block, span, mode, q, k, v)
+
+
+def _causal_attend_bwd(q, k, v, o, lse, do, block, span, mode):
+    """-> (dq, dk, dv) on the path :func:`_causal_attend` took."""
+    if not _causal_takes(q, k, v, mode):
+        return _causal_backward(q, k, v, o, do, block, span)
+    return _causal_either_bwd(_causal_backward, block, span, mode,
+                              q, k, v, o, lse, do)
+
+
+# Jitted so that a model's layers share one trace of both branches (the walk
+# is traced, and dropped, wherever the step is lowered for a TPU) and one
+# lowering: trace + lower are paid on every run.  The walk rides in the
+# static arguments, so that a cached trace is never another walk's.
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _causal_either(walk, block, span, mode, q, k, v):
+    return _kernel_or_walk(
+        (mode,), _causal_kernel,
+        lambda q, k, v: (
+            walk(q, k, v, block, span),
+            jnp.zeros((q.shape[0], q.shape[2], q.shape[1]), jnp.float32)),
+        q, k, v)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _causal_either_bwd(walk_bwd, block, span, mode, q, k, v, o, lse, do):
+    return _kernel_or_walk(
+        (mode,), _causal_kernel_bwd,
+        lambda q, k, v, o, lse, do: walk_bwd(q, k, v, o, do, block, span),
+        q, k, v, o, lse, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _causal_attention(q, k, v, block, span, mode):
+    return _causal_attend(q, k, v, block, span, mode)[0]
+
+
+def _causal_attention_fwd(q, k, v, block, span, mode):
+    o, lse = _causal_attend(q, k, v, block, span, mode)
+    return o, (q, k, v, o, lse)
+
+
+def _causal_attention_bwd(block, span, mode, res, do):
+    return _causal_attend_bwd(*res, do, block, span, mode)
 
 
 _causal_attention.defvjp(_causal_attention_fwd, _causal_attention_bwd)
@@ -591,24 +676,36 @@ def causal_attention(query, key, value, *, block=256, span=2048):
     the leading axis.  Query t of document n reads that document's keys
     ``s <= t`` (scale ``d^-1/2``) and no other document's.  -> (N, S, Hq, dv).
 
-    No (heads, S, S) array exists in either pass: ``block`` queries of every
-    document are scored at a time against the keys up to the end of their
-    ``span`` of queries (tiles: they change no result), and the backward pass
-    recomputes each block's weights from the saved queries, keys and values.
-    The softmax is shifted by ``|q| max_s |k_s| d^-1/2`` like
-    ``IndexerSparseAttention``'s: the same result while that bound stays
-    under about 40.
+    Which path attends: where the step is lowered for a TPU and
+    ``pallas_attention.causal_attn_supported`` holds (float32 or bfloat16,
+    ``dv`` a multiple of 128, ``d`` of 64, ``S`` of 128, one head's float32
+    ``dq`` within the kernels' VMEM), the pair ``causal_attn_pallas_fwd`` /
+    ``_bwd`` (``ops/pallas_attention.py``).  They choose their own tiles
+    (``block`` and ``span`` are the walk's), skip the key tiles above a
+    block's last query, form the mask from positions in VMEM, and keep every
+    block's weights and the float32 sums of ``dk``, ``dv`` and ``dq`` in
+    VMEM: q, k, v, o, do, dq, dk, dv and each row's log-sum (N, Hq, S)
+    float32 are all that crosses.  Everywhere else (a CPU, toy shapes,
+    float16) the XLA walk, which is also the pair's oracle in the tests:
+    ``block`` queries of every document at a time against the keys up to the
+    end of their ``span`` of queries (tiles: they change no result), the
+    block's weights in the compute type through HBM, ``dk`` / ``dv`` summed
+    over the blocks in float32.  No (heads, S, S) array exists on either.
+    Both shift the softmax by ``|q| max_s |k_s| d^-1/2`` like
+    ``IndexerSparseAttention``: the same result while that bound stays under
+    about 40; both round the weights to the compute type before the value
+    product and ``dk`` / ``dv`` once at the end.
 
     Two blocks stand on it: ``nn.SelfAttention`` calls this operator after
-    its projections and rotary embedding (its vjp keeps q, k, v and o), and
-    ``LatentAttention`` runs the same forward and backward walks
-    (:func:`_causal_forward` / :func:`_causal_backward`) inside its own vjp,
-    which keeps only the layer's input and o.
+    its projections and rotary embedding (its vjp keeps q, k, v, o and the
+    rows' log-sums), and ``LatentAttention`` runs the same forward and
+    backward (:func:`_causal_attend` / :func:`_causal_attend_bwd`) inside its
+    own vjp, which keeps only the layer's input, o and the log-sums.
     """
     if query.ndim == 3:
         return _causal_attention(query[None], key[None], value[None],
-                                 block, span)[0]
-    return _causal_attention(query, key, value, block, span)
+                                 block, span, "auto")[0]
+    return _causal_attention(query, key, value, block, span, "auto")
 
 
 def _latent_project(sizes, data, positions, q_weight, kv_a_weight,
@@ -630,28 +727,29 @@ def _latent_project(sizes, data, positions, q_weight, kv_a_weight,
     return q, k, kv[..., nope:]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _latent_attention(sizes, block, span, data, positions, *weights):
-    return _latent_attention_fwd(sizes, block, span, data, positions,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _latent_attention(sizes, block, span, mode, data, positions, *weights):
+    return _latent_attention_fwd(sizes, block, span, mode, data, positions,
                                  *weights)[0]
 
 
-def _latent_attention_fwd(sizes, block, span, data, positions, *weights):
+def _latent_attention_fwd(sizes, block, span, mode, data, positions,
+                          *weights):
     with jax.named_scope("latent_attention.project"):
         q, k, v = _latent_project(sizes, data, positions, *weights)
     with jax.named_scope("latent_attention.attend"):
-        o = _causal_forward(q, k, v, block, span)
-    return o, (data, positions, weights, o)
+        o, lse = _causal_attend(q, k, v, block, span, mode)
+    return o, (data, positions, weights, o, lse)
 
 
-def _latent_attention_bwd(sizes, block, span, res, do):
-    data, positions, weights, o = res
+def _latent_attention_bwd(sizes, block, span, mode, res, do):
+    data, positions, weights, o, lse = res
     with jax.named_scope("latent_attention.project"):
         (q, k, v), project_vjp = jax.vjp(
             lambda data, *w: _latent_project(sizes, data, positions, *w),
             data, *weights)
     with jax.named_scope("latent_attention.attend"):
-        cts = _causal_backward(q, k, v, o, do, block, span)
+        cts = _causal_attend_bwd(q, k, v, o, lse, do, block, span, mode)
     with jax.named_scope("latent_attention.project"):
         d_data, *d_weights = project_vjp(cts)
     return (d_data, None) + tuple(d_weights)
@@ -679,14 +777,18 @@ def latent_attention(data, positions, q_weight, kv_a_weight, kv_norm_gamma,
     attention (``CausalAttention``: scale ``(qk_nope_dim + qk_rope_dim)^-1/2``)
     -> (N, S, num_heads x v_dim), before the output projection.
 
-    The forward pass keeps its input and its output: the backward pass
-    rebuilds queries, keys and values from the input (the latent's point: it
-    is what is cheap to hold) and recomputes each block's weights."""
+    The forward pass keeps its input, its output and each row's log-sum
+    ((N, heads, S) float32; a placeholder where the walk ran): the backward
+    pass rebuilds queries, keys and values from the input (the latent's
+    point: it is what is cheap to hold) and recomputes each block's weights.
+    Attention itself runs on ``CausalAttention``'s two paths: the Pallas pair
+    where the step is lowered for a TPU (its 128 + 64-wide scores are one
+    192-wide contraction in the kernels), the XLA walk elsewhere."""
     sizes = (num_heads, qk_nope_dim, qk_rope_dim, v_dim, theta, latent_eps)
     one = data.ndim == 2
-    o = _latent_attention(sizes, block, span, data[None] if one else data,
-                          positions, q_weight, kv_a_weight, kv_norm_gamma,
-                          kv_b_weight)
+    o = _latent_attention(sizes, block, span, "auto",
+                          data[None] if one else data, positions, q_weight,
+                          kv_a_weight, kv_norm_gamma, kv_b_weight)
     o = o.reshape(o.shape[:2] + (num_heads * v_dim,))
     return o[0] if one else o
 
