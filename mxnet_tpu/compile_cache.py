@@ -74,6 +74,7 @@ in the telemetry registry when ``MXNET_TELEMETRY`` is on, and an
 """
 from __future__ import annotations
 
+import bisect
 import hashlib
 import os
 import pickle
@@ -96,12 +97,26 @@ _stats = {"hits": 0, "misses": 0, "errors": 0,
           # it to MLIR, the backend compile (on a persistent-cache hit: the
           # load), and reading the cache entry, a part of the last
           "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
-          "cache_load_s": 0.0}
+          "cache_load_s": 0.0,
+          # the same stages from their time spans (:func:`_on_jax_span`):
+          # the seconds covered by at least one span of the stage, so a
+          # trace nested in another program's trace counts once
+          "trace_union_s": 0.0, "lower_union_s": 0.0, "backend_union_s": 0.0,
+          # backend events: a program compiled or loaded from the cache
+          "programs": 0,
+          # registered-operator calls made while a program was traced
+          # (:func:`note_op_traced`)
+          "ops_traced": 0}
 _DURATION_KEYS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace_s",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
     "/jax/core/compile/backend_compile_duration": "backend_s",
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+}
+_SPAN_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
 }
 _activated_dir = None
 _listener_registered = False
@@ -138,7 +153,14 @@ def stats():
     seconds JAX reports for its compile stages, summed over the process:
     where set-up time goes below ``xla_hits``.  A jit traced inside another
     reports its trace on its own and inside the outer's, so ``trace_s`` is
-    an upper estimate for nested programs."""
+    an upper estimate for nested programs; ``trace_union_s`` /
+    ``lower_union_s`` / ``backend_union_s`` are the seconds that at least
+    one span of the stage covers, on any thread, which counts such a
+    second once.  ``programs`` counts the backend events (a compile, or a
+    load from JAX's persistent cache; an eager operator's one-primitive
+    program among them); ``ops_traced`` the registered-operator calls made
+    while a program was traced, the same on every run of one program and
+    on every host."""
     with _mu:
         return dict(_stats)
 
@@ -147,6 +169,39 @@ def _reset_stats_for_tests():
     with _mu:
         for k in _stats:
             _stats[k] = 0
+        for k in _unions:
+            _unions[k] = _Union()
+
+
+class _Union:
+    """Length of the union of the intervals added so far, kept as sorted
+    disjoint intervals."""
+
+    __slots__ = ("starts", "ends", "total")
+
+    def __init__(self):
+        self.starts, self.ends, self.total = [], [], 0.0
+
+    def add(self, a, b):
+        i = bisect.bisect_left(self.ends, a)      # first that ends at a or later
+        j = bisect.bisect_right(self.starts, b)   # past the last that starts by b
+        if i < j:                                 # [i, j) meet [a, b]: merge
+            a, b = min(a, self.starts[i]), max(b, self.ends[j - 1])
+            self.total -= sum(e - s for s, e in zip(self.starts[i:j],
+                                                    self.ends[i:j]))
+        self.starts[i:j], self.ends[i:j] = [a], [b]
+        self.total += b - a
+        return self.total
+
+
+_unions = {stage: _Union() for stage in _SPAN_STAGES.values()}
+
+
+def note_op_traced():
+    """A registered operator called while a program is traced (no gate:
+    trace time only, where the operator's ``named_scope`` opens)."""
+    with _mu:
+        _stats["ops_traced"] += 1
 
 
 def _note(kind, reason=None):
@@ -185,6 +240,23 @@ def _on_jax_duration(name, secs, **kw):
             _stats[key] += secs
 
 
+def _on_jax_span(name, start, end, fun_name="", **kw):
+    """Keep each compile stage's union and the backend events in
+    :func:`stats` (no gate); while tracing is on, also put the stage into
+    the span ring as ``compile.<stage>`` named by its program.  JAX calls
+    this when a stage ENDS, so a nested trace arrives before its outer."""
+    stage = _SPAN_STAGES.get(name)
+    if stage is None:
+        return
+    with _mu:
+        _stats[stage + "_union_s"] = _unions[stage].add(start, end)
+        if stage == "backend":
+            _stats["programs"] += 1
+    from .telemetry import tracing
+
+    tracing.record("compile." + stage, start, end, fun_name=fun_name)
+
+
 def _exec_dir():
     return os.path.join(cache_dir(), "exec")
 
@@ -215,7 +287,7 @@ def _platform_hint():
 def place_jax_cache():
     """Decide where JAX's persistent compilation cache lives (module
     docstring), count its hit/miss events and sum jax's compile-stage
-    durations.  MUST run before the first
+    durations and spans.  MUST run before the first
     XLA compile — jax latches the cache directory at first use
     (``mxnet_tpu/__init__.py`` calls this at import) — and must itself not
     trigger backend init, hence :func:`_platform_hint`.  Idempotent."""
@@ -233,6 +305,7 @@ def place_jax_cache():
 
         monitoring.register_event_listener(_on_jax_event)
         monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        monitoring.register_event_time_span_listener(_on_jax_span)
         _listener_registered = True
 
 

@@ -308,11 +308,18 @@ class Executor:
         # profiler trace (the xplane's tf_op stat) and the HLO's op_name
         # attribute device time back to symbolic node names.  Pure
         # trace-time metadata: the jaxpr is unchanged, zero retraces
-        # (tested), and JAX's persistent-cache key leaves it out.
+        # (tested), and JAX's persistent-cache key leaves it out.  Every
+        # caller but the monitor's traces this fn under jit.
         import jax as _jax
+
+        from . import compile_cache
+
+        traced = monitor is None
 
         def run_node(node, args, attrs):
             with _jax.named_scope(node.name):
+                if traced:
+                    compile_cache.note_op_traced()
                 return node.op.fn(*args, **attrs)
 
         def fn(arg_vals, aux_vals, key):  # mxlint: traced

@@ -18,6 +18,7 @@ from jax import named_scope as _named_scope
 from jax.core import Tracer as _Tracer
 
 from ..base import parse_attr, dtype_np
+from .. import compile_cache as _compile_cache
 from ..context import current_context, Context
 from .. import profiler as _prof
 from ..telemetry import tracing as _tracing
@@ -87,6 +88,7 @@ def _invoke_raw(fn, nd_args, attrs, visible=None, ctx=None):
         # into the HLO's op_name and the device trace's tf_op, so device
         # time reads by operator.  Trace-time metadata only.
         with _named_scope(_op_name(fn)):
+            _compile_cache.note_op_traced()
             res = fn(*jargs, **attrs)
     else:
         # an eager operator is a program launched on the device

@@ -33,6 +33,11 @@ Design:
   per-layer metrics) reads;
 - ``count(name)`` adds to a counter attr of the innermost span, so a sum
   over one root says how many happened in that step and under which span;
+- ``record(name, start_s, end_s)`` puts a span that ended elsewhere into
+  the ring, its ``time.time()`` stamps moved onto this module's clock:
+  ``compile_cache`` records each of JAX's compile stages so
+  (``compile.trace`` / ``compile.lower`` / ``compile.backend``, attr
+  ``fun_name``), and a compile inside a traced step names its program;
 - ``export()`` writes Chrome-trace/Perfetto JSON: ``ph:"X"`` duration
   events plus ``ph:"s"``/``ph:"f"`` flow events linking a trace's spans
   across threads, thread-name metadata, and a ``clock_sync`` record
@@ -73,8 +78,8 @@ from .instrument import note_dispatch
 
 __all__ = ["enabled", "session_live", "sample_rate", "trace_path",
            "buffer_cap", "SpanContext", "Span", "NULL_SPAN", "Tracer",
-           "tracer", "start_trace", "span", "current", "count", "snapshot",
-           "export"]
+           "tracer", "start_trace", "span", "current", "record", "count",
+           "snapshot", "export"]
 
 _PID = 0                 # all host spans share one chrome-trace process
 _LANE_BASE = 10_000_000  # synthetic per-trace track ids (lane=True spans)
@@ -437,6 +442,29 @@ def span(name, parent=None, lane=False, **attrs):
     if not enabled():
         return NULL_SPAN
     return tracer().span(name, parent=parent, lane=lane, **attrs)
+
+
+def record(name, start_s, end_s, **attrs):
+    """Put a span that ended elsewhere into the ring: ``start_s`` /
+    ``end_s`` are ``time.time()`` seconds (JAX stamps its compile stages so),
+    moved onto ``_now_us``'s epoch, so they sit on one timeline with every
+    other span here.  It joins the trace of the innermost span entered on
+    this thread (a compile inside a traced step names its program there),
+    else it is a root of its own, sampled as ``start_trace`` samples; on the
+    thread's track, spans nest as their intervals do.  Tracing off:
+    nothing, no tracer."""
+    if not enabled():
+        return
+    t = tracer()
+    parent = current()
+    if parent is None and not session_live() and not t._sample():
+        return
+    shift_us = _now_us() - time.time() * 1e6
+    sp = Span(t, name, parent.trace_id if parent else t._new_id(),
+              parent.span_id if parent else None, attrs=attrs)
+    sp.t0 = start_s * 1e6 + shift_us
+    sp.dur = max(0.0, (end_s - start_s) * 1e6)
+    t._record(sp)
 
 
 def count(name, n=1, path=None):

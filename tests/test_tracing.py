@@ -469,10 +469,132 @@ def test_operator_scopes_reach_the_lowered_op_name(lower, monkeypatch):
     assert any("/%s/" % names[0] in o for o in op_names), sorted(op_names)
 
 
+@pytest.mark.parametrize("lower", [_lowered_gluon, _lowered_symbol])
+def test_ops_traced_counts_operators_traced_and_no_eager_call(lower):
+    """Both front ends add one to ``ops_traced`` for each operator they
+    trace (the helpers' eager forward and binding count nothing); an eager
+    operator counts nothing."""
+    from mxnet_tpu import compile_cache
+
+    n0 = compile_cache.stats()["ops_traced"]
+    _, names = lower()
+    n1 = compile_cache.stats()["ops_traced"]
+    assert n1 - n0 == len(names)
+    x = mx.nd.ones((2, 2))
+    (x + x * 2).wait_to_read()
+    assert compile_cache.stats()["ops_traced"] == n1
+
+
+def _nested_step():
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):
+        return jnp.tanh(x @ x).sum()
+
+    def step(x):
+        return sum(inner(x * i) for i in range(4))
+
+    x = jnp.ones((8, 8)) + 37.0          # its eager programs compile here
+    jax.block_until_ready(x)
+    return jax.jit(step), x
+
+
+def test_union_counts_a_nested_trace_once():
+    """JAX reports the inner jit's traces on their own and inside the outer
+    trace: ``trace_s`` sums them, ``trace_union_s`` is the outer span's
+    length; one program is lowered and compiled."""
+    from jax._src import monitoring
+
+    from mxnet_tpu import compile_cache
+
+    step, x = _nested_step()
+    spans = []
+
+    def listen(name, start, end, fun_name="", **kw):
+        spans.append((name.rsplit("/", 1)[-1], fun_name, end - start))
+
+    monitoring.register_event_time_span_listener(listen)
+    try:
+        s0 = compile_cache.stats()
+        step(x).block_until_ready()
+        s1 = compile_cache.stats()
+    finally:
+        monitoring.unregister_event_time_span_listener(listen)
+    d = {k: s1[k] - s0[k] for k in s1}
+    (outer,) = [t for n, f, t in spans
+                if n == "jaxpr_trace_duration" and f == "step"]
+    assert d["trace_union_s"] == pytest.approx(outer, rel=1e-6, abs=1e-9)
+    assert d["trace_s"] > d["trace_union_s"]
+    assert d["lower_union_s"] == pytest.approx(d["lower_s"])
+    assert d["backend_union_s"] == pytest.approx(d["backend_s"])
+    assert d["programs"] == 1
+
+
+def test_union_of_intervals():
+    from mxnet_tpu.compile_cache import _Union
+
+    u = _Union()
+    for a, b in [(5, 6), (1, 2), (1.5, 1.75), (3, 4), (1.9, 3.1), (0, 0.5),
+                 (10, 11), (0.5, 1)]:
+        u.add(a, b)
+    assert u.total == pytest.approx(1 + 3 + 1 + 1)   # [0, 4], 5-6, 10-11
+    assert u.starts == [0, 5, 10] and u.ends == [4, 6, 11]
+
+
+def test_compile_spans_join_the_ring_on_its_clock(tr_enabled):
+    """Under ``MXNET_TRACE`` each stage of a compile is a finished span in
+    the ring, named by its program, between the ``_now_us`` readings taken
+    around the call, the inner jit's traces inside the outer trace."""
+    from mxnet_tpu.profiler import _now_us
+
+    step, x = _nested_step()
+    tracing._reset_for_tests()
+    t0 = _now_us()
+    step(x).block_until_ready()
+    t1 = _now_us()
+    spans = tracing.snapshot()
+    assert {s["name"] for s in spans} == {"compile.trace", "compile.lower",
+                                          "compile.backend"}
+    for s in spans:
+        assert t0 <= s["start_us"] and s["start_us"] + s["dur_us"] <= t1
+    (outer,) = [s for s in spans if s["name"] == "compile.trace"
+                and s["attrs"]["fun_name"] == "step"]
+    inner = [s for s in spans if s["attrs"]["fun_name"] == "inner"]
+    assert len(inner) == 4 and all(
+        outer["start_us"] <= s["start_us"]
+        and s["start_us"] + s["dur_us"] <= outer["start_us"] + outer["dur_us"]
+        for s in inner)
+    (low,) = [s for s in spans if s["name"] == "compile.lower"]
+    assert low["attrs"]["fun_name"] == "jit(step)"
+    assert low["start_us"] >= outer["start_us"] + outer["dur_us"]
+    with tracing.start_trace("step") as root:   # a compile inside a span
+        import jax
+
+        jax.jit(lambda a: a * 3)(x).block_until_ready()
+    mine = [s for s in tracing.snapshot() if s["trace"] == root.trace_id
+            and s["name"].startswith("compile.")]
+    assert {s["name"] for s in mine} == {"compile.trace", "compile.lower",
+                                         "compile.backend"}
+    assert all(s["parent"] == root.span_id for s in mine)
+
+
+def test_compile_with_no_session_and_no_env_creates_no_ring(tr_disabled):
+    import jax
+
+    from mxnet_tpu import compile_cache
+
+    n = compile_cache.stats()["programs"]
+    jax.jit(lambda a: a * 5 + 1)(np.ones(3, np.float32)).block_until_ready()
+    assert compile_cache.stats()["programs"] == n + 1
+    assert tracing._tracer is None and tracing.snapshot() == []
+
+
 def test_compile_cache_stats_sum_jaxs_stage_durations(tmp_path):
     """trace_s / lower_s / backend_s grow on a fresh compile; on a load from
     the persistent cache backend_s and cache_load_s grow and nothing is
-    compiled."""
+    compiled.  Either is one program."""
     import jax
     import jax.numpy as jnp
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -500,12 +622,14 @@ def test_compile_cache_stats_sum_jaxs_stage_durations(tmp_path):
         s1 = compile_cache.stats()
         assert grown(s1, s0) == {"trace_s", "lower_s", "backend_s"}
         assert s1["xla_misses"] > s0["xla_misses"]
+        assert s1["programs"] == s0["programs"] + 1
         jax.clear_caches()
         jax.jit(f)(x).block_until_ready()
         s2 = compile_cache.stats()
         assert {"backend_s", "cache_load_s"} <= grown(s2, s1)
         assert s2["xla_hits"] > s1["xla_hits"]
         assert s2["xla_misses"] == s1["xla_misses"]
+        assert s2["programs"] == s1["programs"] + 1
         assert s2["cache_load_s"] - s1["cache_load_s"] <= \
             s2["backend_s"] - s1["backend_s"]
     finally:
