@@ -28,9 +28,13 @@ class Executor:
         self._arg_names = symbol.list_arguments()
         self._aux_names = symbol.list_auxiliary_states()
         self._out_names = symbol.list_outputs()
-        self.arg_dict = self._to_dict(args, self._arg_names, "args")
-        self.aux_dict = self._to_dict(aux_states, self._aux_names, "aux_states")
-        self.grad_dict = self._to_dict(args_grad, self._arg_names, "args_grad", allow_none=True) or {}
+        self._arg_dict = self._to_dict(args, self._arg_names, "args")
+        self._aux_dict = self._to_dict(aux_states, self._aux_names, "aux_states")
+        self._grad_dict = self._to_dict(args_grad, self._arg_names, "args_grad", allow_none=True) or {}
+        # whoever holds newer values of these arrays than the arrays do (a
+        # Module's FusedStepper keeps them packed between steps): every
+        # read of the dicts asks it to write them back first
+        self._owner = None
         if isinstance(grad_req, str):
             self._grad_req = {n: grad_req for n in self._arg_names}
         elif isinstance(grad_req, (list, tuple)):
@@ -77,6 +81,24 @@ class Executor:
                 )
             return {n: a for n, a in zip(names, arrays) if a is not None}
         raise TypeError(type(arrays))
+
+    @property
+    def arg_dict(self):
+        if self._owner is not None:
+            self._owner.materialize()
+        return self._arg_dict
+
+    @property
+    def aux_dict(self):
+        if self._owner is not None:
+            self._owner.materialize()
+        return self._aux_dict
+
+    @property
+    def grad_dict(self):
+        if self._owner is not None:
+            self._owner.materialize()
+        return self._grad_dict
 
     @property
     def arg_arrays(self):

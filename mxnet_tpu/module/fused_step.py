@@ -24,6 +24,23 @@ proves in ``gluon.functional.make_train_step``:
 - jax.jit caches per shape signature: ``Module.reshape`` costs exactly one
   retrace, switching back costs none.
 
+**Packed boundary.**  Without a mesh the carried state crosses the jit
+boundary packed: one flat buffer per role and dtype (parameters, gradients,
+optimizer-state slots, auxiliary states), each leaf a segment starting on a
+whole (8, 128) tile, so a ResNet-50 launch moves 14 buffers where one array
+per parameter moved 1152.  Inside, the step slices the
+leaves out at static offsets, runs the per-leaf step unchanged (same
+operations, same order) and concatenates what it returns.  The stepper owns
+the packed buffers from step to step; the Module's per-name NDArrays go
+stale and are brought up to date (one jitted unpack, then ``_rebind``) only
+when something reads them: ``Executor.arg_dict`` / ``grad_dict`` /
+``aux_dict`` and ``Updater.states`` ask their ``_owner`` to
+``materialize`` first.  Before a launch the stepper repacks only if an
+array was rebound since it last synced (a write through any NDArray
+rebinds it).  A mesh keeps one array per leaf (ZeRO-1 shards per leaf), and
+so do Modules that share their arrays with another Module (bucketing,
+``shared_module``): another executor's reads would not ask this owner.
+
 ``Module.forward_backward`` stages the batch, ``Module.update`` dispatches;
 eligibility and the ``MXNET_MODULE_FUSED_STEP`` escape hatch live here (see
 ``fused_ineligible_reason`` and docs/PERF_NOTES.md "Fused Module train
@@ -44,6 +61,8 @@ device updates its 1/dp state shard, and the updated params allgather back
 to replicated — all in the same XLA module.
 """
 from __future__ import annotations
+
+import operator
 
 import numpy as np
 
@@ -133,7 +152,7 @@ def fused_ineligible_reason(module):
     for n in module._param_names:
         if req.get(n, "null") != "write":
             return "grad_req"
-        if module._exec.grad_dict.get(n) is None:
+        if module._exec._grad_dict.get(n) is None:
             return "grad_req"
     opt = module._optimizer
     if opt is None or opt.fused_step_kind() is None:
@@ -141,6 +160,13 @@ def fused_ineligible_reason(module):
     if module._mesh is not None and _DP_AXIS not in module._mesh.axis_names:
         return "mesh_no_dp"
     return None
+
+
+def _packs(module):
+    """True when the Module's fused step carries its state packed: no mesh
+    (ZeRO-1 decides each leaf's sharding) and arrays no other Module's
+    executor reads (``Module.bind(shared_module=)``)."""
+    return module._mesh is None and not module._params_shared
 
 
 def _hp_signature(opt):
@@ -159,14 +185,20 @@ def _hp_signature(opt):
     return sig
 
 
-def _state_leaves(state):
-    """Flatten one Updater state slot (None | NDArray | tuple) to a list of
-    jax arrays for the jitted step."""
+def _state_arrays(state):
+    """Flatten one Updater state slot (None | NDArray | tuple) to its list
+    of NDArrays."""
     if state is None:
         return []
     if isinstance(state, NDArray):
-        return [state._data]
-    return [s._data for s in state]
+        return [state]
+    return list(state)
+
+
+def _state_leaves(state):
+    """Flatten one Updater state slot to a list of jax arrays for the
+    jitted step."""
+    return [s._data for s in _state_arrays(state)]
 
 
 def _commit_state(state, new_leaves):
@@ -251,6 +283,119 @@ def _build_step_fn(graph_fn, arg_names, diff_names, const_names, kind, hp,
     return step
 
 
+_ALIGN = 1024  # elements: a segment starts on a whole (8, 128) f32 tile
+
+
+def _major_to_minor(v):
+    """The order in which the device lays out ``v``'s axes, outermost
+    first (row-major where the array does not say)."""
+    fmt = getattr(v, "format", None)
+    order = getattr(getattr(fmt, "layout", None), "major_to_minor", None)
+    return tuple(order) if order is not None else tuple(range(v.ndim))
+
+
+class _Layout:
+    """Where each leaf of one role lives in that role's flat buffers: one
+    buffer per dtype (in order of first appearance), each leaf a segment
+    padded to a multiple of ``_ALIGN`` elements and holding the leaf's
+    elements in the order the device lays its axes out (a 3x3 convolution's
+    filter on a TPU: taps outermost), so slicing a leaf out and putting it
+    back move dense rows and never a padded tile.  Static: offsets and
+    axis orders are Python ints the compiler sees through."""
+
+    def __init__(self, leaves):
+        leaves = list(leaves)
+        self.avals = tuple((tuple(v.shape), str(v.dtype), _major_to_minor(v))
+                           for v in leaves)
+        self.dtypes = []
+        self.sizes = []
+        self.slots = []  # (buffer index, offset, size, shape, axis order)
+        for shape, dt, order in self.avals:
+            if dt not in self.dtypes:
+                self.dtypes.append(dt)
+                self.sizes.append(0)
+            b = self.dtypes.index(dt)
+            n = int(np.prod(shape, dtype=np.int64))
+            self.slots.append((b, self.sizes[b], n, shape, order))
+            self.sizes[b] += -(-n // _ALIGN) * _ALIGN
+
+    def unpack(self, bufs):
+        from jax import lax
+
+        out = []
+        for b, o, n, shape, order in self.slots:
+            seg = lax.slice(bufs[b], (o,), (o + n,))
+            seg = seg.reshape(tuple(shape[d] for d in order))
+            out.append(seg.transpose(np.argsort(order)) if order != tuple(
+                range(len(order))) else seg)
+        return out
+
+    def pack(self, leaves):
+        """Leaves -> flat buffers; the padding is zeros, a leaf of another
+        dtype is cast as ``NDArray._rebind`` would cast it."""
+        import jax.numpy as jnp
+
+        parts = [[] for _ in self.dtypes]
+        for (b, _o, n, _shape, order), v in zip(self.slots, leaves):
+            if order != tuple(range(len(order))):
+                v = v.transpose(order)
+            parts[b].append(jnp.ravel(v).astype(self.dtypes[b]))
+            if n % _ALIGN:
+                parts[b].append(jnp.zeros((-n % _ALIGN,), self.dtypes[b]))
+        return [jnp.concatenate(p) for p in parts]
+
+
+def _packed_step_fn(step, lay_params, lay_state, lay_aux, state_counts):
+    """The packed twin of ``step``: the carried state enters and leaves as
+    flat buffers (``_Layout``); inside, the leaves are sliced out and
+    ``step`` runs on them unchanged, so the operations and their order are
+    the per-leaf step's.  ``grad_bufs`` are the previous step's gradients,
+    donated so the new ones are written into them."""
+
+    def packed(param_bufs, grad_bufs, state_bufs, aux_bufs, const_vals,
+               key, lr_vec, wd_vec):
+        import jax
+
+        del grad_bufs
+        # the barrier makes each leaf a buffer of its own, as an argument
+        # was: a leaf left a view into the flat buffer cannot be prefetched
+        # on its own, and the TPU compiler prices the whole step 8 % higher
+        # for it (ResNet-50's, compiled for a v5e)
+        params, flat, aux = jax.lax.optimization_barrier(
+            (lay_params.unpack(param_bufs), lay_state.unpack(state_bufs),
+             lay_aux.unpack(aux_bufs)))
+        opt_state, i = [], 0
+        for c in state_counts:
+            opt_state.append(flat[i:i + c])
+            i += c
+        out = step(params, None, opt_state, aux, const_vals, key, lr_vec,
+                   wd_vec)
+        new_params, new_state, new_aux, heads, grads = out[:5]
+        return (lay_params.pack(new_params),
+                lay_state.pack([v for st in new_state for v in st]),
+                lay_aux.pack(new_aux), heads,
+                lay_params.pack(grads)) + tuple(out[5:])
+
+    return packed
+
+
+class _Hold:
+    """What a packed stepper last synced with: the executor and Updater it
+    owns, their NDArrays, and the jax array each held right after the sync
+    (``seen``): an array whose ``_data`` is no longer that one was written."""
+
+    def __init__(self, exec_, updater, params, grads, slots, states, aux):
+        self.exec_ = exec_
+        self.updater = updater
+        self.params = params
+        self.grads = grads
+        self.slots = slots
+        self.states = states
+        self.aux = aux
+        self.arrays = params + states + aux  # what the step reads
+        self.seen = [a._data for a in self.arrays]
+
+
 class FusedStepper:
     """Per-Module fused-step cache: builds the jitted step once (per
     optimizer configuration) and re-dispatches it for every eligible step;
@@ -305,6 +450,7 @@ class FusedStepper:
         self._mesh = module._mesh
         self._zero = self._mesh is not None and fused_zero_enabled()
         self._donate = fused_donate_enabled()
+        self._packed = _packs(module)
         # the executor's bind-time graph-pass snapshot (ISSUE 7): the
         # stepper's step fn closes over the (possibly pass-optimized) train
         # plan, so the snapshot is program identity — it keys the AOT cache
@@ -331,6 +477,10 @@ class FusedStepper:
                 tuple(self._aux_names), self._hp_sig, self._nancheck,
                 self._zero, self._mesh is not None,
                 "donate:0123" if self._donate else "donate:none")
+            if self._packed:
+                # the packed boundary is another program over the same
+                # symbol; the per-leaf key stays as it was
+                self._aot_key = self._aot_key + ("packed",)
             if self._health_groups is not None:
                 # appended (not an always-present flag) so gate-off keys
                 # stay byte-identical to pre-trainhealth entries
@@ -346,6 +496,17 @@ class FusedStepper:
                                   health_groups=self._health_groups)
         self._jit = None
         self._step = None
+        # packed path: the buffers the stepper owns between steps (params,
+        # grads, state slots, aux: a list of flat buffers each), whether
+        # they are newer than the Module's NDArrays, what they were last
+        # synced with (_Hold), the jitted (step, pack, unpack) of each
+        # layout, and the buffers a launch moves (the ``buffers`` counter)
+        self._bufs = None
+        self._newer = False
+        self._hold = None
+        self._fns = None
+        self._layouts = {}
+        self._nbuf = None
         # mesh-path sharding cache, filled on first run (needs the state
         # leaf structure): (repl, [grad/param spec]*P, [[state leaf spec]])
         # — static for the stepper's lifetime (param shapes survive
@@ -392,13 +553,10 @@ class FusedStepper:
         ``_shard_spec``; heads and the nancheck flag compiler-chosen) so the
         layout survives every donated step, and declare the GSPMD-derived
         collectives to telemetry once per build."""
-        import jax
-
         if self._step is not None:
             return
-        donate = (0, 1, 2, 3) if self._donate else ()
         if self._mesh is None:
-            self._jit = jax.jit(self._fn, donate_argnums=donate)
+            self._wrap(self._fn)
         else:
             from ..parallel import note_derived
 
@@ -409,8 +567,6 @@ class FusedStepper:
                 out_sh = out_sh + (None,)
             if self._health_groups is not None:
                 out_sh = out_sh + (None,)  # stats pytree: compiler-chosen
-            self._jit = jax.jit(self._fn, donate_argnums=donate,
-                                out_shardings=out_sh)
             # declared ONCE per stepper build (not per retrace like the
             # explicit lax collectives — a reshape re-specializes the same
             # logical collectives, so one declaration per layout is honest).
@@ -431,6 +587,17 @@ class FusedStepper:
             else:
                 note_derived("psum_grads", diff_vals,
                              mesh=self._mesh, axis=_DP_AXIS)
+            self._wrap(self._fn, out_shardings=out_sh)
+
+    def _wrap(self, fn, layout=(), **jit_kw):
+        """Jit a step (its four state arguments donated) and put the AOT
+        cache or the compile plane, and the step accounting, around it;
+        ``layout`` (the packed path's leaf shapes) joins the logical key.
+        Sets ``_jit`` and ``_step``, returns ``_step``."""
+        import jax
+
+        donate = (0, 1, 2, 3) if self._donate else ()
+        self._jit = jax.jit(fn, donate_argnums=donate, **jit_kw)
         if self._aot_key is not None:
             from .. import compile_cache
 
@@ -441,7 +608,7 @@ class FusedStepper:
             # backends restore normally.  MXNET_FUSED_DONATE=0 makes the
             # restore legal everywhere.  Cache off ⇒ the plain jit above.
             self._jit = compile_cache.CachedFunction(
-                self._jit, self._aot_key, name="fused_step",
+                self._jit, self._aot_key + layout, name="fused_step",
                 mesh_desc=compile_cache.mesh_descriptor(self._mesh),
                 donated=self._donate, passes_on=self._passes_on)
         else:
@@ -461,11 +628,12 @@ class FusedStepper:
                      compile_cache.symbol_fingerprint(self._symbol_ref),
                      tuple(self._diff_names), self._hp_sig, self._nancheck,
                      self._zero, self._mesh is not None, self._passes_on,
-                     self._health_groups is not None),
+                     self._health_groups is not None) + layout,
                     donated=self._donate)
         # compile/steady-state accounting (identity when telemetry is off)
         self._step = telemetry.instrument_step(self._jit,
                                                name="module_fused_step")
+        return self._step
 
     def cache_size(self):
         """Number of compiled executables (one per shape signature)."""
@@ -493,7 +661,9 @@ class FusedStepper:
                 # a re-bind whose executor snapshotted a different
                 # MXNET_GRAPH_PASSES state: the cached step fn closes over
                 # the other plan flavor — rebuild instead of mixing
-                or module._exec._graph_passes != self._passes_on)
+                or module._exec._graph_passes != self._passes_on
+                # the Module began sharing its arrays: per leaf from now on
+                or _packs(module) != self._packed)
 
     def check_nonfinite(self):
         """Raise if the PREVIOUS step's folded isfinite flag tripped.
@@ -550,80 +720,216 @@ class FusedStepper:
                     np.asarray(stats["global_grad_norm"]))
         mon.observe("loss", np.asarray(stats["loss"]))
 
+    # -- packed state (no mesh) ----------------------------------------------
+    def _holds(self, exec_, updater):
+        """True while the packed buffers stand for this executor's and
+        Updater's arrays: the same objects as at the last sync, and none
+        rebound since (a write through an NDArray rebinds it).  Reads the
+        raw storage, so it never materializes."""
+        h = self._hold
+        if h is None or h.exec_ is not exec_ or h.updater is not updater:
+            return False
+        arg, aux, slots = exec_._arg_dict, exec_._aux_dict, updater._states
+        is_ = operator.is_
+        return (all(map(is_, [arg.get(n) for n in self._diff_names],
+                        h.params))
+                and all(map(is_, [aux.get(n) for n in self._aux_names],
+                            h.aux))
+                and all(map(is_, [slots.get(i) for i in range(len(h.params))],
+                            h.slots))
+                and all(map(is_, [a._data for a in h.arrays], h.seen)))
+
+    def _sync(self, exec_, updater):
+        """Pack the Module's arrays into fresh buffers and own them: at the
+        first launch, and at one after an array was rebound (which keeps
+        what was written into it: ``materialize``)."""
+        if self._hold is not None:
+            self.materialize()
+            self._let_go()
+        arg = exec_._arg_dict
+        params = [arg[n] for n in self._diff_names]
+        slots = updater._states
+        for i, w in enumerate(params):
+            if i not in slots:
+                slots[i] = self._opt.create_state(i, w)
+                updater.states_synced[i] = True
+        slots = [slots[i] for i in range(len(params))]
+        per_param = [_state_arrays(st) for st in slots]
+        states = [a for lv in per_param for a in lv]
+        aux = [exec_._aux_dict[n] for n in self._aux_names]
+        grads = [exec_._grad_dict[n] for n in self._diff_names]
+        data = [[a._data for a in arrs] for arrs in (params, grads, states,
+                                                     aux)]
+        self._fns = self._packed_fns(
+            _Layout(data[0]), _Layout(data[2]), _Layout(data[3]),
+            tuple(len(lv) for lv in per_param))
+        self._bufs = self._fns[1](*data)
+        tracing.count("dispatch")
+        self._hold = _Hold(exec_, updater, params, grads, slots, states, aux)
+        self._newer = False
+        self._nbuf = None
+        exec_._owner = self
+        updater._owner = self
+
+    def _packed_fns(self, lay_params, lay_state, lay_aux, state_counts):
+        """(step, pack, unpack, the step's jit) of one layout, built once.
+        Pack and unpack move every role in one program each: the Module's
+        arrays into buffers, and buffers back into arrays."""
+        import jax
+
+        key = (lay_params.avals, lay_state.avals, lay_aux.avals, state_counts)
+        fns = self._layouts.get(key)
+        if fns is None:
+            step = self._wrap(
+                _packed_step_fn(self._fn, lay_params, lay_state, lay_aux,
+                                state_counts), (("layout",) + key,))
+
+            def pack(params, grads, states, aux):
+                return (lay_params.pack(params), lay_params.pack(grads),
+                        lay_state.pack(states), lay_aux.pack(aux))
+
+            def unpack(params, grads, states, aux):
+                return (lay_params.unpack(params), lay_params.unpack(grads),
+                        lay_state.unpack(states), lay_aux.unpack(aux))
+
+            fns = self._layouts[key] = (step, jax.jit(pack), jax.jit(unpack),
+                                        self._jit)
+        self._step, self._jit = fns[0], fns[3]
+        return fns
+
+    def materialize(self):
+        """Write the packed buffers back into the Module's NDArrays when
+        they are newer: one jitted unpack, then ``_rebind`` of each array.
+        An array rebound since the last sync keeps what was written into
+        it, the newer value, and the next launch repacks.  The executor's
+        dicts and the Updater's ``states`` call this before every read."""
+        if not self._newer:
+            return
+        self._newer = False
+        h = self._hold
+        params, grads, states, aux = self._fns[2](*self._bufs)
+        tracing.count("dispatch")
+        for a, v in zip(h.grads, grads):
+            a._rebind(v)
+        for i, (a, v) in enumerate(zip(h.arrays, params + states + aux)):
+            if a._data is h.seen[i]:
+                a._rebind(v)
+                h.seen[i] = a._data
+
+    def _let_go(self):
+        h, self._hold = self._hold, None
+        self._bufs = None
+        if h is not None:
+            if h.exec_._owner is self:
+                h.exec_._owner = None
+            if h.updater._owner is self:
+                h.updater._owner = None
+
+    def release(self):
+        """Hand the state back to the Module's NDArrays and stop owning
+        them: before the Module drops this stepper, re-binds, or lets
+        another Module share its arrays."""
+        self.materialize()
+        self._let_go()
+
+    # -- the launch ----------------------------------------------------------
+    def _leaf_args(self, exec_, updater):
+        """The per-leaf step's state arguments (params, grads, state slots,
+        aux) from the Module's arrays, committed to their mesh layout, and
+        the Updater's slots the step's new state goes back into."""
+        arg = exec_._arg_dict
+        diff_vals = [arg[n]._data for n in self._diff_names]
+        grads_in = [exec_._grad_dict[n]._data for n in self._diff_names]
+        aux_vals = [exec_._aux_dict[n]._data for n in self._aux_names]
+        states, leaves = [], []
+        for i, n in enumerate(self._diff_names):
+            if i not in updater._states:
+                updater._states[i] = self._opt.create_state(i, arg[n])
+                updater.states_synced[i] = True
+            states.append(updater._states[i])
+            leaves.append(_state_leaves(updater._states[i]))
+        if self._mesh is not None:
+            # commit every donated operand to its pinned layout
+            # (params/aux replicated over the mesh, grads + opt state
+            # per _shard_spec — 1/dp shards in ZeRO-1 mode).  Only the
+            # FIRST step actually moves bytes; afterwards the step's
+            # out_shardings return buffers already in layout and _place
+            # is a sharding == check.  The batch feed itself is already
+            # dp-sharded by _stage_batch.
+            if self._shardings is None:
+                self._shardings = (
+                    self._repl(),
+                    [self._shard_spec(v) for v in diff_vals],
+                    [[self._shard_spec(v) for v in lv] for lv in leaves])
+            repl, grad_sh, state_sh = self._shardings
+            diff_vals = [self._place(v, repl) for v in diff_vals]
+            aux_vals = [self._place(v, repl) for v in aux_vals]
+            grads_in = [self._place(g, s)
+                        for g, s in zip(grads_in, grad_sh)]
+            leaves = [[self._place(v, s) for v, s in zip(lv, shl)]
+                      for lv, shl in zip(leaves, state_sh)]
+        self._ensure_jit(diff_vals, leaves)
+        return (diff_vals, grads_in, leaves, aux_vals), states
+
+    def _hyperparams(self):
+        """Host-side hyperparam prep, O(P) python and zero dispatches:
+        update counts first (the legacy Updater order), then lr/wd read
+        through the optimizer's scheduler/multiplier logic; adam's bias
+        correction folds into lr so the in-graph kernel stays
+        schedule-free."""
+        from ..ops.optimizer_ops import adam_bias_corrected_lr
+
+        opt = self._opt
+        for i in range(len(self._diff_names)):
+            opt._update_count(i)
+        lrs, wds = [], []
+        for i in range(len(self._diff_names)):
+            lr, wd = opt._get_lr(i), opt._get_wd(i)
+            if self._kind == "adam":
+                lr = adam_bias_corrected_lr(lr, opt._index_update_count[i],
+                                            opt.beta1, opt.beta2)
+            lrs.append(lr)
+            wds.append(wd)
+        return np.asarray(lrs, np.float32), np.asarray(wds, np.float32)
+
     def run(self, module):
         """Dispatch ONE fused step over the feed already staged in the
         executor's arg buffers, then commit params / optimizer state / aux /
-        outputs / grads.  Consumes exactly one RNG key (like the legacy
-        forward), so seeded runs stay reproducible across paths.
+        outputs / grads: packed, the new buffers replace the stepper's own
+        and the Module's arrays wait for a reader (``materialize``); per
+        leaf, each array is rebound.  Consumes exactly one RNG key (like the
+        legacy forward), so seeded runs stay reproducible across paths.
 
         Three child spans of the caller's ``update`` split the host's time
         (telemetry/tracing.py; a profiler session or ``MXNET_TRACE``):
         ``fused.prepare`` everything before the launch, ``fused.dispatch``
-        the jitted call alone until it returns, ``fused.commit`` rebinding
+        the jitted call alone until it returns (its counter ``buffers``:
+        the arrays the call takes and returns), ``fused.commit`` taking in
         what came back."""
         from .. import random as _rnd
 
         with tracing.span("fused.prepare"):
             exec_ = module._exec
-            opt = self._opt
             updater = module._updater
-            diff_vals = [exec_.arg_dict[n]._data for n in self._diff_names]
-            grads_in = [exec_.grad_dict[n]._data for n in self._diff_names]
-            const_vals = [exec_.arg_dict[n]._data for n in self._const_names]
-            aux_vals = [exec_.aux_dict[n]._data for n in self._aux_names]
-            states, leaves = [], []
-            for i, n in enumerate(self._diff_names):
-                if i not in updater.states:
-                    updater.states[i] = opt.create_state(i, exec_.arg_dict[n])
-                    updater.states_synced[i] = True
-                states.append(updater.states[i])
-                leaves.append(_state_leaves(updater.states[i]))
-            if self._mesh is not None:
-                # commit every donated operand to its pinned layout
-                # (params/aux replicated over the mesh, grads + opt state
-                # per _shard_spec — 1/dp shards in ZeRO-1 mode).  Only the
-                # FIRST step actually moves bytes; afterwards the step's
-                # out_shardings return buffers already in layout and _place
-                # is a sharding == check.  The batch feed itself is already
-                # dp-sharded by _stage_batch.
-                if self._shardings is None:
-                    self._shardings = (
-                        self._repl(),
-                        [self._shard_spec(v) for v in diff_vals],
-                        [[self._shard_spec(v) for v in lv] for lv in leaves])
-                repl, grad_sh, state_sh = self._shardings
-                diff_vals = [self._place(v, repl) for v in diff_vals]
-                aux_vals = [self._place(v, repl) for v in aux_vals]
-                grads_in = [self._place(g, s)
-                            for g, s in zip(grads_in, grad_sh)]
-                leaves = [[self._place(v, s) for v, s in zip(lv, shl)]
-                          for lv, shl in zip(leaves, state_sh)]
-            self._ensure_jit(diff_vals, leaves)
-            # host-side hyperparam prep, O(P) python and zero dispatches:
-            # update counts first (the legacy Updater order), then read
-            # lr/wd through the optimizer's scheduler/multiplier logic;
-            # adam's bias correction folds into lr so the in-graph kernel
-            # stays schedule-free
-            for i in range(len(self._diff_names)):
-                opt._update_count(i)
-            lrs, wds = [], []
-            from ..ops.optimizer_ops import adam_bias_corrected_lr
-
-            for i in range(len(self._diff_names)):
-                lr, wd = opt._get_lr(i), opt._get_wd(i)
-                if self._kind == "adam":
-                    lr = adam_bias_corrected_lr(lr, opt._index_update_count[i],
-                                                opt.beta1, opt.beta2)
-                lrs.append(lr)
-                wds.append(wd)
-            lrs = np.asarray(lrs, np.float32)
-            wds = np.asarray(wds, np.float32)
+            if self._packed:
+                if not self._holds(exec_, updater):
+                    self._sync(exec_, updater)
+                state_args = self._bufs
+            else:
+                state_args, states = self._leaf_args(exec_, updater)
+            const_vals = [exec_._arg_dict[n]._data for n in self._const_names]
+            lrs, wds = self._hyperparams()
             key = _rnd.next_key()
             if self._nancheck:
                 self.check_nonfinite()
         with tracing.span("fused.dispatch"):
-            out = self._step(diff_vals, grads_in, leaves, aux_vals,
-                             const_vals, key, lrs, wds)
+            out = self._step(*state_args, const_vals, key, lrs, wds)
+            if self._nbuf is None:
+                import jax
+
+                self._nbuf = len(jax.tree_util.tree_leaves(
+                    (state_args, const_vals, key, lrs, wds, out)))
+            tracing.count("buffers", self._nbuf)
         with tracing.span("fused.commit"):
             new_params, new_state, new_aux, heads, grads = out[:5]
             extra = list(out[5:])
@@ -635,14 +941,19 @@ class FusedStepper:
                 # sync the in-graph fold avoids): the fit loop drains them
                 # after its metric read has already synced this dispatch
                 self._last_health = (self._nsteps, extra.pop(0))
-            for n, v in zip(self._diff_names, new_params):
-                exec_.arg_dict[n]._rebind(v)
-            for n, g in zip(self._diff_names, grads):
-                exec_.grad_dict[n]._rebind(g)
-            for n, v in zip(self._aux_names, new_aux):
-                exec_.aux_dict[n]._rebind(v)
-            for st, new_leaves in zip(states, new_state):
-                _commit_state(st, new_leaves)
+            if self._packed:
+                self._bufs = (new_params, grads, new_state, new_aux)
+                self._newer = True
+            else:
+                arg, aux = exec_._arg_dict, exec_._aux_dict
+                for n, v in zip(self._diff_names, new_params):
+                    arg[n]._rebind(v)
+                for n, g in zip(self._diff_names, grads):
+                    exec_._grad_dict[n]._rebind(g)
+                for n, v in zip(self._aux_names, new_aux):
+                    aux[n]._rebind(v)
+                for st, new_leaves in zip(states, new_state):
+                    _commit_state(st, new_leaves)
             exec_.outputs = [_wrap(h) for h in heads]
             exec_._last_key = key
             exec_._last_is_train = True

@@ -88,6 +88,10 @@ class Module(BaseModule):
         # stepper and the staged-batch flag forward_backward hands update()
         self._fused = None
         self._fused_pending = False
+        # another Module's executor reads this Module's arrays (bind with
+        # shared_module, either side): its fused step then keeps one array
+        # per leaf, since the other executor would read them stale
+        self._params_shared = False
         # in-graph monitor (ISSUE 12): a pattern-filtered Monitor routed
         # onto the fused step's trainhealth stats instead of the un-jitted
         # executor callback (install_monitor decides the route)
@@ -199,11 +203,18 @@ class Module(BaseModule):
     def bind(self, data_shapes, label_shapes=None, for_training=True,
              inputs_need_grad=False, force_rebind=False, shared_module=None, grad_req="write"):
         if force_rebind:
+            if self._fused is not None:
+                self._fused.release()  # the executor it owns goes
             self._exec = None
             self.binded = False
         if self.binded:
             self.logger.warning("Already bound, ignoring bind()")
             return
+        if shared_module is not None:
+            if getattr(shared_module, "_fused", None) is not None:
+                shared_module._fused.release()
+            shared_module._params_shared = True
+            self._params_shared = True
         self.for_training = for_training
         self.inputs_need_grad = inputs_need_grad
         self._grad_req = grad_req
@@ -297,6 +308,7 @@ class Module(BaseModule):
             return
         if self._fused is not None:  # drain any unread nancheck flag first
             self._fused.check_nonfinite()
+            self._fused.release()
         self._fused = None  # stepper folds optimizer hyperparams: rebuild
 
         kv, update_on_kvstore = _create_kvstore(
@@ -448,8 +460,10 @@ class Module(BaseModule):
             feed = staged[1]
         else:
             feed = self._build_feed(data_batch)
+        # the raw dict: staging the batch must not write the fused step's
+        # packed state back into the parameters (module/fused_step.py)
         for k, v in feed.items():
-            self._exec.arg_dict[k] = v
+            self._exec._arg_dict[k] = v
 
     def prepare(self, data_batch):
         """Pre-stage the UPCOMING batch (ISSUE 5): issue its (sharded)
@@ -545,6 +559,7 @@ class Module(BaseModule):
                 if self._fused is not None and self._fused.stale(self):
                     # don't let a rebuild discard an unread nancheck flag
                     self._fused.check_nonfinite()
+                    self._fused.release()
                     self._fused = None
                 if self._fused is None:
                     self._fused = FusedStepper(self)

@@ -797,8 +797,17 @@ class Updater:
 
     def __init__(self, optimizer):
         self.optimizer = optimizer
-        self.states = {}
+        self._states = {}
         self.states_synced = {}
+        # a FusedStepper that holds newer state values than the slots do
+        # (executor.py's ``_owner``): reading ``states`` writes them back
+        self._owner = None
+
+    @property
+    def states(self):
+        if self._owner is not None:
+            self._owner.materialize()
+        return self._states
 
     def __call__(self, index, grad, weight):
         if index not in self.states:

@@ -314,6 +314,26 @@ class TestFusedStepper:
         _steps(mod)
         assert mod._fused.cache_size() == 1  # one shape signature, once
 
+    def test_packed_and_per_leaf_entries_are_distinct(self, aot_dir):
+        """The same symbol's packed step and per-leaf step (a Module whose
+        arrays another Module shares) are two programs: two keys, apart
+        by the packed marker and the packed layout alone."""
+        from mxnet_tpu import module as mod_mod
+
+        packed = _tiny_module()
+        leaf = _tiny_module()
+        mod_mod.Module(leaf._symbol).bind(
+            data_shapes=[("data", (8, 8))],
+            label_shapes=[("softmax_label", (8,))], shared_module=leaf)
+        mx.random.seed(11)
+        out_packed = _steps(packed)
+        mx.random.seed(11)
+        out_leaf = _steps(leaf)
+        assert packed._fused._packed and not leaf._fused._packed
+        assert packed._fused._aot_key == leaf._fused._aot_key + ("packed",)
+        assert packed._fused._jit._key != leaf._fused._jit._key
+        np.testing.assert_array_equal(out_packed, out_leaf)
+
 
 # -- predictor surface --------------------------------------------------------
 class TestPredictorAOT:

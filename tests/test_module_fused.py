@@ -396,3 +396,251 @@ def test_nancheck_stale_rebuild_does_not_swallow_flag(monkeypatch):
         mod.init_optimizer(optimizer="adam",
                            optimizer_params={"learning_rate": 0.01},
                            force_init=True)
+
+
+# -- packed boundary: the carried state crosses the jit as flat buffers -------
+CONV_DATA = (BATCH, 3, 8, 8)
+
+
+def _conv_sym():
+    data = mx.sym.var("data")
+    x = mx.sym.Convolution(data, name="conv1", num_filter=8, kernel=(3, 3),
+                           pad=(1, 1), no_bias=True)
+    x = mx.sym.BatchNorm(x, name="bn1")
+    x = mx.sym.Activation(x, name="relu1", act_type="relu")
+    x = mx.sym.Pooling(x, name="pool1", kernel=(2, 2), stride=(2, 2),
+                       pool_type="max")
+    x = mx.sym.FullyConnected(x, name="fc1", num_hidden=4)
+    return mx.sym.SoftmaxOutput(x, name="softmax")
+
+
+def _conv_batches(steps=STEPS):
+    rng = np.random.RandomState(7)
+    return [DataBatch(
+        data=[mx.nd.array(rng.randn(*CONV_DATA).astype(np.float32))],
+        label=[mx.nd.array(rng.randint(0, 4, (BATCH,)).astype(np.float32))])
+        for _ in range(steps)]
+
+
+def _bind_shapes(mod, **kw):
+    mod.bind(data_shapes=[("data", CONV_DATA)],
+             label_shapes=[("softmax_label", (BATCH,))], **kw)
+
+
+def _conv_module(per_leaf, optimizer, opt_params):
+    """The small convnet, trained packed, or per leaf: a second Module bound
+    to share its arrays (as bucketing binds them) keeps one array a leaf."""
+    mod = mod_mod.Module(_conv_sym())
+    _bind_shapes(mod)
+    if per_leaf:
+        _bind_shapes(mod_mod.Module(_conv_sym()), shared_module=mod)
+    rng = np.random.RandomState(3)
+    mod.init_params(arg_params={
+        n: mx.nd.array(rng.randn(*mod._exec.arg_dict[n].shape)
+                       .astype(np.float32) * 0.1)
+        for n in sorted(mod._param_names)})
+    mod.init_optimizer(optimizer=optimizer, optimizer_params=dict(opt_params))
+    return mod
+
+
+def _snapshot(mod):
+    """Every array the step carries or returns, read through the Module's
+    own surfaces: the executor's dicts, the Updater's slots, the heads."""
+    ex = mod._exec
+    out = {"arg:" + n: ex.arg_dict[n].asnumpy() for n in mod._param_names}
+    out.update({"grad:" + n: ex.grad_dict[n].asnumpy()
+                for n in mod._param_names})
+    out.update({"aux:" + n: a.asnumpy() for n, a in ex.aux_dict.items()})
+    for i, st in mod._updater.states.items():
+        for j, leaf in enumerate(fused_step._state_arrays(st)):
+            out["state:%d.%d" % (i, j)] = leaf.asnumpy()
+    out["head"] = mod.get_outputs()[0].asnumpy()
+    return out
+
+
+def _read_params(mod):
+    args, auxs = mod.get_params()
+    return {n: v.asnumpy() for n, v in {**args, **auxs}.items()}
+
+
+def _read_states(mod, tmp_path):
+    import pickle
+
+    fname = str(tmp_path / ("s%d" % id(mod)))
+    mod.save_optimizer_states(fname)
+    with open(fname, "rb") as f:
+        states = pickle.load(f)
+    return {"%d.%d" % (i, j): leaf for i, st in states.items()
+            for j, leaf in enumerate([] if st is None else
+                                     [st] if isinstance(st, np.ndarray)
+                                     else st)}
+
+
+def _train_both(monkeypatch, optimizer, opt_params, between=None):
+    """The same steps packed and per leaf; ``between(mod, step)`` after each
+    update on both sides -> (packed module, per-leaf module, the readings
+    ``between`` returned, a pair a step)."""
+    monkeypatch.setenv("MXNET_MODULE_FUSED_STEP", "1")
+    mods, readings = [], []
+    for per_leaf in (False, True):
+        mx.random.seed(11)
+        mod = _conv_module(per_leaf, optimizer, opt_params)
+        seen = []
+        for i, b in enumerate(_conv_batches()):
+            mod.forward_backward(b)
+            mod.update()
+            assert mod._fused._packed is not per_leaf
+            if between is not None:
+                seen.append(between(mod, i))
+        mods.append(mod)
+        readings.append(seen)
+    return mods[0], mods[1], list(zip(*readings))
+
+
+OPTIMIZERS = pytest.mark.parametrize("optimizer,opt_params", [
+    ("sgd", {"learning_rate": 0.1, "momentum": 0.9, "wd": 1e-3}),
+    ("adam", {"learning_rate": 0.01}),
+], ids=["sgd_mom", "adam"])
+
+
+@OPTIMIZERS
+def test_packed_and_per_leaf_steps_are_bit_equal(monkeypatch, optimizer,
+                                                 opt_params):
+    """Only the boundary changes: params, gradients, optimizer slots,
+    BatchNorm statistics and heads after 5 steps are the per-leaf step's,
+    bit for bit, on the CPU."""
+    packed, leaf, _ = _train_both(monkeypatch, optimizer, opt_params)
+    got, want = _snapshot(packed), _snapshot(leaf)
+    assert got.keys() == want.keys()
+    assert any(k.startswith("state:") for k in got)
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+
+
+@pytest.mark.parametrize("reader", ["get_params", "executor_dicts",
+                                    "save_optimizer_states"])
+def test_reads_between_steps_see_the_current_step(monkeypatch, tmp_path,
+                                                  reader):
+    """Whatever surface reads the state between two packed steps sees the
+    step just taken, and reading does not disturb the steps after it."""
+    read = {"get_params": _read_params,
+            "executor_dicts": _snapshot,
+            "save_optimizer_states": lambda m: _read_states(m, tmp_path),
+            }[reader]
+    packed, leaf, pairs = _train_both(
+        monkeypatch, "sgd", {"learning_rate": 0.1, "momentum": 0.9},
+        between=lambda mod, i: read(mod))
+    assert len(pairs) == STEPS
+    for step, (got, want) in enumerate(pairs):
+        assert got.keys() == want.keys() and got
+        for k in want:
+            np.testing.assert_array_equal(got[k], want[k],
+                                          err_msg="step %d %s" % (step, k))
+    assert not packed._fused._newer  # the last read brought them back
+
+
+def _write_set_params(mod, i):
+    args, auxs = mod.get_params()
+    args = dict(args, conv1_weight=args["conv1_weight"] * 0.5)
+    mod.set_params(args, auxs)
+
+
+def _write_init_params(mod, i):
+    mod.init_params(arg_params={"fc1_bias": mx.nd.array(
+        np.full((4,), 0.1 * (i + 1), np.float32))},
+        allow_missing=True, force_init=True)
+
+
+def _write_in_place(mod, i):
+    w = mod._exec.arg_dict["fc1_weight"]
+    w += 0.01
+    mod._exec.aux_dict["bn1_moving_var"][:] = 2.0
+
+
+def _write_held(mod, i):
+    # arrays handed out before the steps, written after one without a
+    # read in between: the write is newer than the packed step's value
+    if i == 0:
+        mod._held = (mod._exec.arg_dict["conv1_weight"],
+                     mod._updater.states[0])
+    w, mom = mod._held
+    w[:] = 0.05
+    mom[:] = 0.0
+
+
+@pytest.mark.parametrize("write", [_write_set_params, _write_init_params,
+                                   _write_in_place, _write_held],
+                         ids=["set_params", "init_params_force",
+                              "in_place", "held_array"])
+def test_a_write_between_steps_is_what_the_next_step_trains_from(
+        monkeypatch, write):
+    packed, leaf, _ = _train_both(
+        monkeypatch, "sgd", {"learning_rate": 0.1, "momentum": 0.9}, write)
+    got, want = _snapshot(packed), _snapshot(leaf)
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+
+
+def test_packed_step_still_reports_nonfinite_and_health(monkeypatch):
+    """MXNET_NANCHECK and MXNET_TRAINHEALTH ride the packed step: the
+    health stats of each step come out, and a non-finite step raises
+    before the next one."""
+    from mxnet_tpu.base import MXNetError
+    from mxnet_tpu.telemetry import trainhealth
+
+    monkeypatch.setenv("MXNET_TRAINHEALTH", "1")
+    trainhealth._reset_for_tests()
+    try:
+        mod = _nancheck_module(monkeypatch, fused=True)
+        mod.forward_backward(_batches(1)[0])
+        mod.update()
+        assert mod._fused._packed and mod._fused._nancheck
+        stepno, stats = mod._fused.pop_health()
+        assert stepno == 1
+        assert np.isfinite(float(stats["global_grad_norm"]))
+        assert float(stats["global_grad_norm"]) > 0
+        mod.forward_backward(_nan_batch())
+        mod.update()
+        mod.forward_backward(_batches(1)[0])
+        with pytest.raises(MXNetError, match="step 2"):
+            mod.update()
+    finally:
+        trainhealth._reset_for_tests()
+
+
+@pytest.mark.parametrize("layout,buffers", [
+    # 1 param + 1 grad buffer, data, label, key, lr, wd in; params, head,
+    # grads out
+    ("packed", 7 + 3),
+    # 4 params + 4 grads in and out, the same five others, the head
+    ("mesh", 13 + 9),
+    ("shared", 13 + 9),
+])
+def test_buffers_counter_reads_what_a_launch_moves(monkeypatch, layout,
+                                                   buffers):
+    from mxnet_tpu import parallel
+    from mxnet_tpu.telemetry import tracing
+
+    monkeypatch.setenv("MXNET_MODULE_FUSED_STEP", "1")
+    monkeypatch.setenv("MXNET_TRACE", "1")
+    tracing._reset_for_tests()
+    try:
+        mesh = parallel.make_mesh({"dp": 8}) if layout == "mesh" else None
+        mod = _make_module(_sym(bn=False, dropout=False), mesh=mesh)
+        if layout == "shared":
+            mod_mod.Module(_sym(bn=False, dropout=False)).bind(
+                data_shapes=[("data", (BATCH, 8))],
+                label_shapes=[("softmax_label", (BATCH,))],
+                shared_module=mod)
+        mod.init_optimizer(optimizer="sgd",
+                           optimizer_params={"learning_rate": 0.1})
+        for b in _batches(2):
+            with tracing.start_trace("step"):
+                mod.forward_backward(b)
+                mod.update()
+        assert mod._fused._packed is (layout == "packed")
+        counts = [s["attrs"]["buffers"] for s in tracing.snapshot()
+                  if s["name"] == "fused.dispatch"]
+        assert counts == [buffers, buffers]
+    finally:
+        tracing._reset_for_tests()
