@@ -28,9 +28,12 @@ proves in ``gluon.functional.make_train_step``:
 boundary packed: one flat buffer per role and dtype (parameters, gradients,
 optimizer-state slots, auxiliary states), each leaf a segment starting on a
 whole (8, 128) tile, so a ResNet-50 launch moves 14 buffers where one array
-per parameter moved 1152.  Inside, the step slices the
-leaves out at static offsets, runs the per-leaf step unchanged (same
-operations, same order) and concatenates what it returns.  The stepper owns
+per parameter moved 1152.  Inside, the forward pass reads the
+parameters sliced out at static offsets, and the optimizer writes the new
+parameters and state into the donated buffers in place: one elementwise
+update over each buffer whose leaves share an update kind, a
+``dynamic_update_slice`` a leaf elsewhere (the per-leaf step's operations,
+in its order, on every element).  The stepper owns
 the packed buffers from step to step; the Module's per-name NDArrays go
 stale and are brought up to date (one jitted unpack, then ``_rebind``) only
 when something reads them: ``Executor.arg_dict`` / ``grad_dict`` /
@@ -214,20 +217,31 @@ def _commit_state(state, new_leaves):
         s._rebind(v)
 
 
-def _build_step_fn(graph_fn, arg_names, diff_names, const_names, kind, hp,
-                   nancheck=False, health_groups=None):
-    """The pure fused step: one vjp over the executor graph + the in-graph
-    optimizer fold.  Closed over only static structure (names, kind, static
-    hyperparams, the nancheck/health flags) so one jitted instance survives
-    re-binds of the same symbol and re-traces only on new shape signatures.
+def _step_parts(graph_fn, arg_names, diff_names, const_names, kind, hp,
+                nancheck=False, health_groups=None):
+    """The pure fused step in three parts, which the per-leaf step
+    (``_build_step_fn``) and the packed one (``_packed_step_fn``) put
+    together each in its own way.  Closed over only static structure
+    (names, kind, static hyperparams, the nancheck/health flags) so one
+    jitted instance survives re-binds of the same symbol and re-traces only
+    on new shape signatures:
 
-    With ``nancheck`` the step also returns a scalar ``finite`` flag —
+    - ``forward(diff_vals, aux_vals, const_vals, key) -> (heads, grads,
+      new_aux)``: one vjp over the executor graph;
+    - ``update(w, g, state, lr, wd) -> (w', state')``: the in-graph
+      optimizer fold.  Every kernel is elementwise, so ``w`` may be one leaf
+      with scalar ``lr`` / ``wd`` or a flat buffer of leaves of one kind
+      with per-element vectors;
+    - ``extras(heads, grads, params, new_params)``: what the step appends
+      to its outputs.
+
+    With ``nancheck`` the extras hold a scalar ``finite`` flag —
     ``all(isfinite(heads)) & all(isfinite(grads))`` reduced INSIDE the same
     donated jit, so the check adds no dispatch and no sync (the caller reads
     the flag one step later, when it has materialized for free).
 
     With ``health_groups`` (ISSUE 12, ``MXNET_TRAINHEALTH`` or an in-graph
-    monitor) the step additionally returns the trainhealth stats pytree —
+    monitor) the extras also hold the trainhealth stats pytree —
     global/per-group grad norms, param norms, update-to-weight ratios and
     per-group non-finite flags, reduced by
     ``telemetry.trainhealth.compute_step_stats`` inside the same donated
@@ -239,12 +253,7 @@ def _build_step_fn(graph_fn, arg_names, diff_names, const_names, kind, hp,
 
     from ..ops.optimizer_ops import fused_update
 
-    def step(diff_vals, grads_in, opt_state, aux_vals, const_vals, key,
-             lr_vec, wd_vec):
-        # grads_in is donated purely so XLA can recycle the standing grad
-        # buffers for the returned gradients
-        del grads_in
-
+    def forward(diff_vals, aux_vals, const_vals, key):
         def f(dvals):
             env = dict(zip(const_names, const_vals))
             env.update(zip(diff_names, dvals))
@@ -252,20 +261,17 @@ def _build_step_fn(graph_fn, arg_names, diff_names, const_names, kind, hp,
 
         heads, vjp_fn, new_aux = jax.vjp(f, diff_vals, has_aux=True)
         (grads,) = vjp_fn([jnp.ones_like(h) for h in heads])
-        new_params, new_state = [], []
-        with jax.named_scope("optimizer"):
-            for i, (w, g) in enumerate(zip(diff_vals, grads)):
-                st = tuple(opt_state[i])
-                # like sgd_rule: a parameter updates with momentum iff it
-                # HAS a momentum slot (created when the optimizer's momentum
-                # was set), so mid-run momentum edits behave exactly like
-                # the legacy path
-                k = ("sgd_mom" if st else "sgd") if kind == "sgd" else kind
-                new_w, new_st = fused_update(k, w, g, st, lr=lr_vec[i],
-                                             wd=wd_vec[i], **hp)
-                new_params.append(new_w)
-                new_state.append(list(new_st))
-        out = (new_params, new_state, new_aux, heads, grads)
+        return heads, grads, new_aux
+
+    def update(w, g, state, lr, wd):
+        # like sgd_rule: a parameter updates with momentum iff it HAS a
+        # momentum slot (created when the optimizer's momentum was set), so
+        # mid-run momentum edits behave exactly like the legacy path
+        k = ("sgd_mom" if state else "sgd") if kind == "sgd" else kind
+        return fused_update(k, w, g, state, lr=lr, wd=wd, **hp)
+
+    def extras(heads, grads, params, new_params):
+        out = ()
         if nancheck:
             finite = jnp.bool_(True)
             for h in heads:
@@ -277,8 +283,34 @@ def _build_step_fn(graph_fn, arg_names, diff_names, const_names, kind, hp,
             from ..telemetry import trainhealth
 
             out = out + (trainhealth.compute_step_stats(
-                heads, grads, diff_vals, new_params, health_groups),)
+                heads, grads, params, new_params, health_groups),)
         return out
+
+    return forward, update, extras
+
+
+def _build_step_fn(parts):
+    """The per-leaf fused step over ``_step_parts``: one array per leaf in
+    and out, each leaf updated on its own."""
+    import jax
+
+    forward, update, extras = parts
+
+    def step(diff_vals, grads_in, opt_state, aux_vals, const_vals, key,
+             lr_vec, wd_vec):
+        # grads_in is donated purely so XLA can recycle the standing grad
+        # buffers for the returned gradients
+        del grads_in
+        heads, grads, new_aux = forward(diff_vals, aux_vals, const_vals, key)
+        new_params, new_state = [], []
+        with jax.named_scope("optimizer"):
+            for i, (w, g) in enumerate(zip(diff_vals, grads)):
+                new_w, new_st = update(w, g, tuple(opt_state[i]), lr_vec[i],
+                                       wd_vec[i])
+                new_params.append(new_w)
+                new_state.append(list(new_st))
+        return (new_params, new_state, new_aux, heads, grads) + extras(
+            heads, grads, diff_vals, new_params)
 
     return step
 
@@ -296,87 +328,181 @@ def _major_to_minor(v):
 
 class _Layout:
     """Where each leaf of one role lives in that role's flat buffers: one
-    buffer per dtype (in order of first appearance), each leaf a segment
-    padded to a multiple of ``_ALIGN`` elements and holding the leaf's
-    elements in the order the device lays its axes out (a 3x3 convolution's
-    filter on a TPU: taps outermost), so slicing a leaf out and putting it
-    back move dense rows and never a padded tile.  Static: offsets and
-    axis orders are Python ints the compiler sees through."""
+    buffer per group and dtype (in order of first appearance), each leaf a
+    segment padded to a multiple of ``_ALIGN`` elements and holding the
+    leaf's elements in the order the device lays its axes out (a 3x3
+    convolution's filter on a TPU: taps outermost), so slicing a leaf out
+    and putting it back move dense rows and never a padded tile.  The
+    optimizer state groups its leaves by slot, so where every parameter of
+    a buffer has the slot, that slot's buffer is laid out segment for
+    segment as the parameters' is.  Static: offsets and axis orders are
+    Python ints the compiler sees through."""
 
-    def __init__(self, leaves):
+    def __init__(self, leaves, groups=None):
         leaves = list(leaves)
+        if groups is None:
+            groups = [0] * len(leaves)
         self.avals = tuple((tuple(v.shape), str(v.dtype), _major_to_minor(v))
                            for v in leaves)
+        keys = []
         self.dtypes = []
         self.sizes = []
         self.slots = []  # (buffer index, offset, size, shape, axis order)
-        for shape, dt, order in self.avals:
-            if dt not in self.dtypes:
+        for (shape, dt, order), g in zip(self.avals, groups):
+            if (g, dt) not in keys:
+                keys.append((g, dt))
                 self.dtypes.append(dt)
                 self.sizes.append(0)
-            b = self.dtypes.index(dt)
+            b = keys.index((g, dt))
             n = int(np.prod(shape, dtype=np.int64))
             self.slots.append((b, self.sizes[b], n, shape, order))
             self.sizes[b] += -(-n // _ALIGN) * _ALIGN
 
-    def unpack(self, bufs):
+    def leaf(self, bufs, k):
+        """Leaf ``k`` sliced out of its buffer."""
         from jax import lax
 
-        out = []
-        for b, o, n, shape, order in self.slots:
-            seg = lax.slice(bufs[b], (o,), (o + n,))
-            seg = seg.reshape(tuple(shape[d] for d in order))
-            out.append(seg.transpose(np.argsort(order)) if order != tuple(
-                range(len(order))) else seg)
-        return out
+        b, o, n, shape, order = self.slots[k]
+        seg = lax.slice(bufs[b], (o,), (o + n,))
+        seg = seg.reshape(tuple(shape[d] for d in order))
+        if order != tuple(range(len(order))):
+            seg = seg.transpose(np.argsort(order))
+        return seg
 
-    def pack(self, leaves):
-        """Leaves -> flat buffers; the padding is zeros, a leaf of another
+    def unpack(self, bufs):
+        return [self.leaf(bufs, k) for k in range(len(self.slots))]
+
+    def _flat(self, k, v):
+        """Leaf ``k``'s value as its segment holds it; a value of another
         dtype is cast as ``NDArray._rebind`` would cast it."""
         import jax.numpy as jnp
 
+        b, _o, _n, _shape, order = self.slots[k]
+        if order != tuple(range(len(order))):
+            v = v.transpose(order)
+        return jnp.ravel(v).astype(self.dtypes[b])
+
+    def pack(self, leaves):
+        """Leaves -> flat buffers; the padding is zeros."""
+        import jax.numpy as jnp
+
         parts = [[] for _ in self.dtypes]
-        for (b, _o, n, _shape, order), v in zip(self.slots, leaves):
-            if order != tuple(range(len(order))):
-                v = v.transpose(order)
-            parts[b].append(jnp.ravel(v).astype(self.dtypes[b]))
+        for k, v in enumerate(leaves):
+            b, _o, n, _shape, _order = self.slots[k]
+            parts[b].append(self._flat(k, v))
             if n % _ALIGN:
                 parts[b].append(jnp.zeros((-n % _ALIGN,), self.dtypes[b]))
         return [jnp.concatenate(p) for p in parts]
 
+    def put(self, bufs, k, v):
+        """``bufs`` with leaf ``k`` written over its segment: a
+        ``dynamic_update_slice`` at a static offset, in place in a donated
+        buffer."""
+        from jax import lax
 
-def _packed_step_fn(step, lay_params, lay_state, lay_aux, state_counts):
-    """The packed twin of ``step``: the carried state enters and leaves as
-    flat buffers (``_Layout``); inside, the leaves are sliced out and
-    ``step`` runs on them unchanged, so the operations and their order are
-    the per-leaf step's.  ``grad_bufs`` are the previous step's gradients,
-    donated so the new ones are written into them."""
+        b, o = self.slots[k][:2]
+        bufs = list(bufs)
+        bufs[b] = lax.dynamic_update_slice(bufs[b], self._flat(k, v), (o,))
+        return bufs
+
+    def spread(self, b, vec):
+        """Buffer ``b``'s elements each given its leaf's entry of ``vec``
+        (one scalar a leaf, padding included).  A segment is whole rows of
+        ``_ALIGN`` elements, so the entries are gathered one a row, by a
+        constant index, and broadcast over (8, 128) tiles: a flat buffer is
+        stored as such tiles, so the compiler fuses the broadcast into the
+        elementwise op that reads it.  Broadcast over rows of 1024, or
+        concatenated a segment at a time, the spread is built in memory."""
+        import jax.numpy as jnp
+
+        rows = np.concatenate([
+            np.full(-(-n // _ALIGN), k, np.int32)
+            for k, (bb, _o, n, _shape, _order) in enumerate(self.slots)
+            if bb == b])
+        return jnp.broadcast_to(jnp.take(vec, rows)[:, None, None],
+                                (len(rows), 8, _ALIGN // 8)).reshape(-1)
+
+
+def _flat_plan(lay_params, lay_state, state_counts):
+    """``{parameter buffer: [state buffer of each slot]}`` for every
+    parameter buffer that can be updated whole: its leaves share one update
+    kind (the same number of state slots), and each slot's buffer holds
+    exactly those leaves' slot, at the parameters' offsets, in their dtype.
+    The other parameters update leaf by leaf."""
+    owners = [i for i, c in enumerate(state_counts) for _ in range(c)]
+    first = np.cumsum([0] + list(state_counts))
+    by_buf = {}
+    for i, (b, *seg) in zip(owners, lay_state.slots):
+        by_buf.setdefault(b, []).append((i, *seg))
+    plan = {}
+    for b, dt in enumerate(lay_params.dtypes):
+        mine = [(i, *seg) for i, (bb, *seg) in enumerate(lay_params.slots)
+                if bb == b]
+        counts = {state_counts[i] for i, *_ in mine}
+        if len(counts) != 1:
+            continue
+        sbs = [lay_state.slots[first[mine[0][0]] + j][0]
+               for j in range(counts.pop())]
+        if all(by_buf[s] == mine and lay_state.dtypes[s] == dt for s in sbs):
+            plan[b] = sbs
+    return plan
+
+
+def _packed_step_fn(parts, lay_params, lay_state, lay_aux, state_counts):
+    """The packed twin of the per-leaf step, and the number of leaves it
+    updates on whole buffers.  The carried state enters and leaves as flat
+    buffers (``_Layout``).  The forward pass reads the
+    parameters sliced out behind an ``optimization_barrier``, which makes
+    each a buffer of its own, as an argument was (a convolution whose
+    weights are a view into a 100 MB buffer cannot prefetch them alone and
+    runs dearer); the auxiliary states are sliced out as they are.  The
+    gradients are packed, since the executor materializes them on read.
+    The update then writes the donated buffers in place: once over each
+    parameter buffer of one kind (``_flat_plan``), with ``lr`` / ``wd``
+    spread over its elements, and leaf by leaf, at each leaf's offset, over
+    the rest.  The optimizer state never leaves its buffers, and no
+    parameter or state buffer is concatenated anew.  The operations on each
+    element and their order are the per-leaf step's.  ``grad_bufs`` are
+    the previous step's gradients, donated so the new ones are written into
+    them."""
+    forward, update, extras = parts
+    plan = _flat_plan(lay_params, lay_state, state_counts)
+    first = np.cumsum([0] + list(state_counts))
 
     def packed(param_bufs, grad_bufs, state_bufs, aux_bufs, const_vals,
                key, lr_vec, wd_vec):
         import jax
 
         del grad_bufs
-        # the barrier makes each leaf a buffer of its own, as an argument
-        # was: a leaf left a view into the flat buffer cannot be prefetched
-        # on its own, and the TPU compiler prices the whole step 8 % higher
-        # for it (ResNet-50's, compiled for a v5e)
-        params, flat, aux = jax.lax.optimization_barrier(
-            (lay_params.unpack(param_bufs), lay_state.unpack(state_bufs),
-             lay_aux.unpack(aux_bufs)))
-        opt_state, i = [], 0
-        for c in state_counts:
-            opt_state.append(flat[i:i + c])
-            i += c
-        out = step(params, None, opt_state, aux, const_vals, key, lr_vec,
-                   wd_vec)
-        new_params, new_state, new_aux, heads, grads = out[:5]
-        return (lay_params.pack(new_params),
-                lay_state.pack([v for st in new_state for v in st]),
-                lay_aux.pack(new_aux), heads,
-                lay_params.pack(grads)) + tuple(out[5:])
+        params = jax.lax.optimization_barrier(lay_params.unpack(param_bufs))
+        heads, grads, new_aux = forward(params, lay_aux.unpack(aux_bufs),
+                                        const_vals, key)
+        grad_bufs = lay_params.pack(grads)
+        new_params, new_state = list(param_bufs), list(state_bufs)
+        with jax.named_scope("optimizer"):
+            for b, sbs in plan.items():
+                w, st = update(param_bufs[b], grad_bufs[b],
+                               tuple(state_bufs[s] for s in sbs),
+                               lay_params.spread(b, lr_vec),
+                               lay_params.spread(b, wd_vec))
+                new_params[b] = w.astype(param_bufs[b].dtype)
+                for s, v in zip(sbs, st):
+                    new_state[s] = v.astype(state_bufs[s].dtype)
+            for i, slot in enumerate(lay_params.slots):
+                if slot[0] in plan:
+                    continue
+                ks = range(first[i], first[i + 1])
+                w, st = update(params[i], grads[i],
+                               tuple(lay_state.leaf(state_bufs, k)
+                                     for k in ks), lr_vec[i], wd_vec[i])
+                new_params = lay_params.put(new_params, i, w)
+                for k, v in zip(ks, st):
+                    new_state = lay_state.put(new_state, k, v)
+        return (new_params, new_state, lay_aux.pack(new_aux), heads,
+                grad_bufs) + extras(heads, grads, params,
+                                    lay_params.unpack(new_params))
 
-    return packed
+    return packed, sum(b in plan for b, *_ in lay_params.slots)
 
 
 class _Hold:
@@ -490,23 +616,27 @@ class FusedStepper:
         self._symbol_ref = module._symbol
         self._nsteps = 0
         self._pending_flag = None  # (finite device scalar, step number)
-        self._fn = _build_step_fn(exec_._graph_fn(True), self._arg_names,
+        self._parts = _step_parts(exec_._graph_fn(True), self._arg_names,
                                   self._diff_names, self._const_names,
                                   self._kind, hp, nancheck=self._nancheck,
                                   health_groups=self._health_groups)
+        self._fn = _build_step_fn(self._parts)
         self._jit = None
         self._step = None
         # packed path: the buffers the stepper owns between steps (params,
         # grads, state slots, aux: a list of flat buffers each), whether
         # they are newer than the Module's NDArrays, what they were last
         # synced with (_Hold), the jitted (step, pack, unpack) of each
-        # layout, and the buffers a launch moves (the ``buffers`` counter)
+        # layout, the buffers a launch moves (the ``buffers`` counter) and
+        # the leaves its step updates on whole buffers
+        # (``flat_update_leaves``)
         self._bufs = None
         self._newer = False
         self._hold = None
         self._fns = None
         self._layouts = {}
         self._nbuf = None
+        self._nflat = 0
         # mesh-path sharding cache, filled on first run (needs the state
         # leaf structure): (repl, [grad/param spec]*P, [[state leaf spec]])
         # — static for the stepper's lifetime (param shapes survive
@@ -760,9 +890,13 @@ class FusedStepper:
         grads = [exec_._grad_dict[n] for n in self._diff_names]
         data = [[a._data for a in arrs] for arrs in (params, grads, states,
                                                      aux)]
+        counts = tuple(len(lv) for lv in per_param)
+        # a state leaf's group is its slot (momentum 0; adam's mean 0 and
+        # variance 1), so each slot's buffer is laid out as the parameters'
         self._fns = self._packed_fns(
-            _Layout(data[0]), _Layout(data[2]), _Layout(data[3]),
-            tuple(len(lv) for lv in per_param))
+            _Layout(data[0]),
+            _Layout(data[2], [j for c in counts for j in range(c)]),
+            _Layout(data[3]), counts)
         self._bufs = self._fns[1](*data)
         tracing.count("dispatch")
         self._hold = _Hold(exec_, updater, params, grads, slots, states, aux)
@@ -772,17 +906,18 @@ class FusedStepper:
         updater._owner = self
 
     def _packed_fns(self, lay_params, lay_state, lay_aux, state_counts):
-        """(step, pack, unpack, the step's jit) of one layout, built once.
-        Pack and unpack move every role in one program each: the Module's
-        arrays into buffers, and buffers back into arrays."""
+        """(step, pack, unpack, the step's jit, the leaves the step updates
+        on whole buffers) of one layout, built once.  Pack and unpack move
+        every role in one program each: the Module's arrays into buffers,
+        and buffers back into arrays."""
         import jax
 
         key = (lay_params.avals, lay_state.avals, lay_aux.avals, state_counts)
         fns = self._layouts.get(key)
         if fns is None:
-            step = self._wrap(
-                _packed_step_fn(self._fn, lay_params, lay_state, lay_aux,
-                                state_counts), (("layout",) + key,))
+            fn, nflat = _packed_step_fn(self._parts, lay_params, lay_state,
+                                        lay_aux, state_counts)
+            step = self._wrap(fn, (("layout",) + key,))
 
             def pack(params, grads, states, aux):
                 return (lay_params.pack(params), lay_params.pack(grads),
@@ -793,8 +928,8 @@ class FusedStepper:
                         lay_state.unpack(states), lay_aux.unpack(aux))
 
             fns = self._layouts[key] = (step, jax.jit(pack), jax.jit(unpack),
-                                        self._jit)
-        self._step, self._jit = fns[0], fns[3]
+                                        self._jit, nflat)
+        self._step, self._jit, self._nflat = fns[0], fns[3], fns[4]
         return fns
 
     def materialize(self):
@@ -903,9 +1038,10 @@ class FusedStepper:
         Three child spans of the caller's ``update`` split the host's time
         (telemetry/tracing.py; a profiler session or ``MXNET_TRACE``):
         ``fused.prepare`` everything before the launch, ``fused.dispatch``
-        the jitted call alone until it returns (its counter ``buffers``:
-        the arrays the call takes and returns), ``fused.commit`` taking in
-        what came back."""
+        the jitted call alone until it returns (its counters ``buffers``:
+        the arrays the call takes and returns, and ``flat_update_leaves``:
+        the leaves its step updates on whole buffers, 0 per leaf),
+        ``fused.commit`` taking in what came back."""
         from .. import random as _rnd
 
         with tracing.span("fused.prepare"):
@@ -930,6 +1066,7 @@ class FusedStepper:
                 self._nbuf = len(jax.tree_util.tree_leaves(
                     (state_args, const_vals, key, lrs, wds, out)))
             tracing.count("buffers", self._nbuf)
+            tracing.count("flat_update_leaves", self._nflat)
         with tracing.span("fused.commit"):
             new_params, new_state, new_aux, heads, grads = out[:5]
             extra = list(out[5:])

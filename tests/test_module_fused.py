@@ -9,6 +9,8 @@ Coverage demanded by the issue:
 - acceptance: one training step on the fused path issues exactly ONE
   compiled device dispatch (jit cache entries + telemetry counters).
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -429,7 +431,11 @@ def _bind_shapes(mod, **kw):
 
 def _conv_module(per_leaf, optimizer, opt_params):
     """The small convnet, trained packed, or per leaf: a second Module bound
-    to share its arrays (as bucketing binds them) keeps one array a leaf."""
+    to share its arrays (as bucketing binds them) keeps one array a leaf.
+    ``opt_params["mults"]``, if given, is the (lr_mult, wd_mult) pair of
+    per-parameter dicts set on the optimizer."""
+    opt_params = dict(opt_params)
+    mults = opt_params.pop("mults", None)
     mod = mod_mod.Module(_conv_sym())
     _bind_shapes(mod)
     if per_leaf:
@@ -439,7 +445,10 @@ def _conv_module(per_leaf, optimizer, opt_params):
         n: mx.nd.array(rng.randn(*mod._exec.arg_dict[n].shape)
                        .astype(np.float32) * 0.1)
         for n in sorted(mod._param_names)})
-    mod.init_optimizer(optimizer=optimizer, optimizer_params=dict(opt_params))
+    mod.init_optimizer(optimizer=optimizer, optimizer_params=opt_params)
+    if mults is not None:
+        mod._optimizer.set_lr_mult(mults[0])
+        mod._optimizer.set_wd_mult(mults[1])
     return mod
 
 
@@ -500,7 +509,14 @@ def _train_both(monkeypatch, optimizer, opt_params, between=None):
 OPTIMIZERS = pytest.mark.parametrize("optimizer,opt_params", [
     ("sgd", {"learning_rate": 0.1, "momentum": 0.9, "wd": 1e-3}),
     ("adam", {"learning_rate": 0.01}),
-], ids=["sgd_mom", "adam"])
+    # lr and wd differ leaf by leaf: the packed step spreads them over the
+    # flat buffer's elements
+    ("sgd", {"learning_rate": 0.1, "momentum": 0.9, "wd": 1e-3,
+             "mults": ({"conv1_weight": 0.5, "fc1_bias": 2.0},
+                       {"fc1_weight": 3.0, "bn1_gamma": 0.0})}),
+    # no momentum: no optimizer state, so no state buffer
+    ("sgd", {"learning_rate": 0.1, "wd": 1e-3}),
+], ids=["sgd_mom", "adam", "sgd_mom_mults", "sgd"])
 
 
 @OPTIMIZERS
@@ -512,7 +528,8 @@ def test_packed_and_per_leaf_steps_are_bit_equal(monkeypatch, optimizer,
     packed, leaf, _ = _train_both(monkeypatch, optimizer, opt_params)
     got, want = _snapshot(packed), _snapshot(leaf)
     assert got.keys() == want.keys()
-    assert any(k.startswith("state:") for k in got)
+    has_state = optimizer == "adam" or "momentum" in opt_params
+    assert any(k.startswith("state:") for k in got) is has_state
     for k in want:
         np.testing.assert_array_equal(got[k], want[k], err_msg=k)
 
@@ -644,3 +661,68 @@ def test_buffers_counter_reads_what_a_launch_moves(monkeypatch, layout,
         assert counts == [buffers, buffers]
     finally:
         tracing._reset_for_tests()
+
+
+def _drop_a_momentum(mod, i):
+    # from the second step on, one parameter updates without momentum
+    # beside others that keep theirs: a layout that mixes update kinds
+    if i == 0:
+        mod._updater.states[mod._param_names.index("fc1_bias")] = None
+
+
+def test_a_layout_of_mixed_kinds_updates_leaf_by_leaf(monkeypatch):
+    """Where a buffer's leaves do not share one update kind, each leaf's
+    update goes into its segment of the donated buffers on its own: bit
+    for bit the per-leaf step's."""
+    packed, leaf, _ = _train_both(
+        monkeypatch, "sgd", {"learning_rate": 0.1, "momentum": 0.9,
+                             "wd": 1e-3}, _drop_a_momentum)
+    assert packed._fused._nflat == 0
+    got, want = _snapshot(packed), _snapshot(leaf)
+    assert got.keys() == want.keys()
+    assert len([k for k in got if k.startswith("state:")]) == 4
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+
+
+@pytest.mark.parametrize("layout,flat_leaves", [("uniform", 5), ("mixed", 0)])
+def test_packed_step_updates_its_buffers_in_place(monkeypatch, layout,
+                                                  flat_leaves):
+    """The lowered packed step concatenates no parameter or optimizer-state
+    buffer anew: of the concatenations of a parameter or state buffer's
+    size, only the gradients' pack is left.  ``flat_update_leaves`` counts
+    the leaves updated on whole buffers: every learnable leaf where they
+    share one kind, none where one parameter has no momentum slot (each
+    leaf then goes into its segment by a ``dynamic_update_slice``)."""
+    from mxnet_tpu.telemetry import tracing
+
+    monkeypatch.setenv("MXNET_MODULE_FUSED_STEP", "1")
+    monkeypatch.setenv("MXNET_TRACE", "1")
+    tracing._reset_for_tests()
+    try:
+        mod = _conv_module(False, "sgd", {"learning_rate": 0.1,
+                                          "momentum": 0.9})
+        if layout == "mixed":
+            mod._updater.states[mod._param_names.index("fc1_bias")] = None
+        with tracing.start_trace("step"):
+            mod.forward_backward(_conv_batches(1)[0])
+            mod.update()
+        counts = [s["attrs"]["flat_update_leaves"] for s in tracing.snapshot()
+                  if s["name"] == "fused.dispatch"]
+    finally:
+        tracing._reset_for_tests()
+    assert counts == [flat_leaves]
+    f = mod._fused
+    params, grads, states, aux = f._bufs
+    nparam = len(mod._param_names)
+    text = f._jit.lower(
+        params, grads, states, aux,
+        [mod._exec.arg_dict[n]._data for n in f._const_names],
+        mx.random.next_key(), np.zeros(nparam, np.float32),
+        np.zeros(nparam, np.float32)).as_text()
+    sizes = {b.shape[0] for b in params + states}
+    built = [int(n) for n in re.findall(
+        r"stablehlo\.concatenate .*-> tensor<(\d+)xf32>", text)]
+    assert sorted(n for n in built if n in sizes) == [
+        b.shape[0] for b in params]
+    assert ("stablehlo.dynamic_update_slice" in text) is (layout == "mixed")
